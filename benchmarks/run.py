@@ -20,6 +20,8 @@ import sys
 import time
 import traceback
 
+from repro.compile_cache import configure_compile_cache
+
 from . import (
     bulk_scale, fig3a_routing_comparison, fig3bc_flow_distributions,
     fig4_thread_scaling, fig5_connection_strategies, goodput, hetero_demand,
@@ -83,6 +85,7 @@ def main() -> None:
     unknown = [n for n in names if n not in BENCHES]
     if unknown:
         raise SystemExit(f"unknown bench(es): {unknown}; have {list(BENCHES)}")
+    configure_compile_cache()
     print("name,us_per_call,derived")
     errors: dict[str, str] = {}
     for name in names:

@@ -16,11 +16,10 @@ environment instead:
   arrays preserve their dtype and are not flagged.
 * ``jnp.zeros/ones/empty/full/arange/linspace`` without ``dtype=``
   inside the jax engine — jax's default dtype *changes with the x64
-  mode* (float32/int32 bare, float64/int64 under
-  ``jax.experimental.enable_x64``).  Code that relies on running inside
-  the engine's scoped x64 context works, but the dependence is
-  invisible at the call site; either pin the dtype or baseline the
-  finding with that justification.
+  mode* (float32/int32 bare, float64/int64 inside the engine's scoped
+  ``jax.enable_x64(True)`` context).  Code that relies on running inside
+  that context works, but the dependence is invisible at the call site;
+  pin the dtype.
 
 Positional dtypes count (``np.zeros(n, bool)``; ``np.full(shape, v,
 np.int32)``), so the codebase's existing pinned calls stay clean.
@@ -117,10 +116,8 @@ def _check_module(sf: SourceFile, police_jnp: bool) -> list[Finding]:
                     rule=RULE_JNP, file=sf.rel, line=node.lineno,
                     message=(f"jnp.{fname} without explicit dtype in "
                              f"`{where}` (`{_snippet(node)}`)"),
-                    hint="jax's default dtype flips with the x64 mode; "
-                         "pin dtype=, or baseline with the justification "
-                         "that the call always runs inside the engine's "
-                         "scoped enable_x64 context"))
+                    hint="jax's default dtype flips with the x64 mode "
+                         "(jax.enable_x64); pin dtype= at the call"))
     return findings
 
 

@@ -2,35 +2,33 @@
 
 The numpy engine (``vector_sim`` / ``vector_throughput`` / ``reordering``)
 is the differential reference; this module re-expresses the same hot path
-as jitted jax so a pod-scale sweep (100k flows x 10k seeds) runs on the
-accelerator with no host round-trips between stages:
+as jitted jax so a Monte-Carlo sweep runs on the accelerator with no host
+round-trips between stages:
 
 * the per-hop ECMP/flowlet walk is a ``lax.while_loop`` over the (N, S)
   current-device grid — bit-identical to ``vector_sim.ecmp_walk`` under
   the exact splitmix64 backend (uint64 wraparound is exact under x64);
-* link counts ride one ``segment_sum`` over the link-id tensor, and the
+* link counts are one masked reduction per (seed, link) cell, and the
   per-layer FIM (MAPE vs per-layer ideal) is a handful of masked
   reductions per layer;
 * the weighted progressive max-min fill keeps the numpy engine's
-  parallel local-bottleneck formulation, as a ``lax.while_loop`` whose
-  body is segment ops over (seed, link) cells — frozen columns park
-  their cells on the sentinel slot instead of compacting, which keeps
-  every shape static under jit;
+  parallel local-bottleneck formulation, as a ``lax.while_loop`` over
+  static-shape (seed, link) tables and an (N, S) active mask;
 * flowlet exposure -> transport efficiency -> goodput fuse on top as
   per-parent segment reductions.
 
 Hash backends: ``"exact"`` is the splitmix64 chain (bit-identical to the
 Python tracer, and to the numpy engine — the differential contract).
-``"murmur"`` is the murmur3 avalanche shared with ``kernels/flowhash``
-(the Pallas ``bulk_hash`` kernel on TPU, the same fold/fmix formulas as
-jnp elsewhere); it is the default for real accelerator backends, where
-64-bit multiplies are slow or unsupported.  ``default_hash_backend``
-encodes that policy.
+``"murmur"`` is the murmur3 avalanche of ``kernels/flowhash`` (the same
+``murmur_fold``/``murmur_fmix`` formulas, evaluated here as jnp inside
+the walk; the Pallas ``bulk_hash`` kernel is a separate entry point); it
+is the default for real accelerator backends, where 64-bit multiplies
+are emulated.  ``default_hash_backend`` encodes that policy.
 
-Everything here enters through ``jax.experimental.enable_x64`` as a
-*scoped* context (never the global flag): the exact backend needs uint64
-and the fill needs float64, but flipping x64 globally would change
-default dtypes for every other jax user in the process.
+Everything here enters through ``jax.enable_x64`` as a *scoped* context
+(never the global flag): the exact backend needs uint64 and the fill
+needs float64, but flipping x64 globally would change default dtypes
+for every other jax user in the process.
 """
 
 from __future__ import annotations
@@ -61,11 +59,28 @@ __all__ = [
 ENGINE_NUMPY = "numpy"
 ENGINE_JAX = "jax"
 
-# Seeds per device pass in the fused front ends: caps the transient
-# (max_hops, N, Sc) int32 walk tensor at ~0.5 GB for 100k-flow sweeps
-# (16 * 100k * 8192 * 4B).  Chunking re-enters the same jitted functions
-# (shapes repeat), so it costs one dispatch per chunk, not a recompile.
-_FUSED_SEED_CHUNK_CELLS = 100_000 * 8192
+# Seeds per device pass in the fused front ends (``seed_chunk``), sized
+# for one TPU v5e (16 GB HBM).  A pass over N flows x Sc seeds holds the
+# walk's (max_hops, N, Sc) int32 link-id tensor, 4 B per hop, and the
+# fill's working set over its (H, N, Sc) cells.  XLA:TPU's memory
+# analysis of the compiled fill gives 27-31 B per cell (arguments,
+# (S, L) tables, (N, S) masks and gathered shares) at 4 x 4096 x 1024,
+# 4 x 7168 x 1024 and 4 x 100000 x 128; _FILL_BYTES_PER_CELL budgets 48.
+# Taking H <= max_hops, one seed costs N * max_hops * (4 + 48) B: 832 B
+# per flow at max_hops=16, so the 4 GiB budget (half of the 8 GB kept
+# free of the 16 GB, the rest left to tables, outputs and the
+# allocator) holds 4096 flows x 1260 seeds.  Sc is a multiple of the
+# 128-lane tile: the walk's (N, Sc) arrays put seeds on the lanes, and a
+# ragged Sc compiles ~10x slower for v5e (39 s at 100000 x 20 seeds,
+# 4.6 s at 100000 x 128).  One lane tile is the floor, so above
+# 4 GiB / (128 * 832 B) ~ 40k flows a pass holds 128 seeds and the
+# budget no longer bounds it; a pass then costs N * 128 * (4 * max_hops
+# + 48 * H) B with H the walk's real hop count: 3.3 GB at 100k flows on
+# the paper testbed (H = 4), and the 8 GB line at ~250k flows.
+_CHUNK_BYTES = 4 << 30
+_WALK_BYTES_PER_HOP = 4
+_FILL_BYTES_PER_CELL = 48
+_SEED_LANES = 128
 
 
 def _jx():
@@ -78,15 +93,17 @@ def _jx():
 
 
 def _x64():
-    from jax.experimental import enable_x64
-    return enable_x64()
+    """Scoped 64-bit mode for one engine call (uint64 hashes, float64
+    rates); the process-wide default stays as it was."""
+    import jax
+    return jax.enable_x64(True)
 
 
 def default_hash_backend(engine: str = ENGINE_JAX) -> str:
     """Backend policy when the caller doesn't pin one: the numpy engine
     (and jax-on-CPU, where CI differential tests run) keep the exact
     tracer-identical splitmix64; real accelerator backends default to the
-    TPU-native murmur kernel path."""
+    uint32 murmur hash."""
     if engine != ENGINE_JAX:
         return EXACT
     import jax
@@ -311,7 +328,7 @@ def _wave_walk_jit():
         C = cand.shape[-1]
         flat = loads_q.reshape(-1)
         row_off = jnp.arange(S, dtype=jnp.int64) * loads_q.shape[1]
-        col_idx = jnp.arange(C)
+        col_idx = jnp.arange(C, dtype=jnp.int32)
         state0 = jnp.broadcast_to(
             src_dev[:, None].astype(jnp.int32), (N, S))
         done0 = jnp.zeros((N, S), bool)
@@ -414,8 +431,23 @@ def jax_wave_walk(
 
 
 # ---------------------------------------------------------------------------
-# Stage 2: link counts + FIM (segment_sum + per-layer MAPE)
+# Stage 2: link counts + FIM (per-cell reduction + per-layer MAPE)
 # ---------------------------------------------------------------------------
+
+
+def _cell_reduce(ids, v, L: int, reduce, empty):
+    """Reduce an (N, S) per-flow value over each (seed, link) cell:
+    ``out[s, l] = reduce(v[n, s] for every (h, n) with ids[h, n, s] == l)``,
+    ``empty`` where no flow crosses the cell.  Traced inside the jitted
+    stages.  The compare against ``arange(L)`` sits inside the reduction,
+    where XLA fuses it, so no (H, N, S, L) tensor is built.  It replaces a
+    scatter and a sort on purpose: on a TPU v5e a float64 ``segment_sum``
+    over the 4 x 4096 x 1024 cells of a paper-testbed sweep took 1.75 s
+    against 0.034 s for this reduction, and a sort over the flattened
+    cells compiles for minutes."""
+    _, jnp, _ = _jx()
+    hit = ids[..., None] == jnp.arange(L, dtype=ids.dtype)
+    return reduce(jnp.where(hit, v[None, :, :, None], empty), axis=(0, 1))
 
 
 def _counts_jit():
@@ -423,15 +455,9 @@ def _counts_jit():
 
     @functools.partial(jax.jit, static_argnames=("L",))
     def counts_fn(ids, weights, *, L: int):
-        # ids: (H, Nf, S) device link ids; weights: (Nf,) or None-ones
-        H, Nf, S = ids.shape
-        offs = jnp.arange(S, dtype=jnp.int32) * jnp.int32(L)
-        flat = jnp.where(ids >= 0, ids + offs[None, None, :], S * L)
-        w = jnp.broadcast_to(weights[None, :, None], ids.shape)
-        w = jnp.where(ids >= 0, w, 0.0)
-        c = jax.ops.segment_sum(w.ravel(), flat.ravel(),
-                                num_segments=S * L + 1)
-        return c[: S * L].reshape(S, L)
+        # ids: (H, Nf, S) device link ids; weights: (Nf,) demand weights
+        v = jnp.broadcast_to(weights[:, None], ids.shape[1:])
+        return _cell_reduce(ids, v, L, jnp.sum, 0.0)
 
     return counts_fn
 
@@ -471,8 +497,8 @@ def _fim_jit():
         else:
             leaf_mask = jnp.ones((S, L), bool)
 
-        num = jnp.zeros(S)
-        den = jnp.zeros(S)
+        num = jnp.zeros(S, jnp.float64)
+        den = jnp.zeros(S, jnp.float64)
         mapes = []
         for li in range(layer_sel.shape[0]):
             lm = layer_sel[li][None, :]            # (1, L)
@@ -535,69 +561,64 @@ def jax_fim_from_counts(
 
 
 # ---------------------------------------------------------------------------
-# Stage 3: weighted progressive max-min fill (lax.while_loop + segment ops)
+# Stage 3: weighted progressive max-min fill (lax.while_loop over cells)
 # ---------------------------------------------------------------------------
 
 
 def _fill_jit():
     jax, jnp, lax = _jx()
 
-    @functools.partial(jax.jit, static_argnames=("SL",))
-    def fill(cells, w, cap, *, SL: int):
-        """cells: (H, C) int32 cell ids in [0, SL] (SL = sentinel),
-        w: (C,) float64 positive weights, cap: (SL,) float64 capacity.
-        Returns (C,) max-min rates; all-sentinel columns get inf.
+    @jax.jit
+    def fill(ids, w, cap):
+        """ids: (H, N, S) int32 link ids (-1 past the path's end),
+        w: (N,) float64 positive weights, cap: (L,) float64 capacity.
+        Returns (N, S) max-min rates; a flow crossing no link gets inf.
 
         Same parallel local-bottleneck formulation as the numpy
         ``_fill_block_weighted``: freeze every flow crossing a cell whose
-        fair share equals the min share on every member's path, drain,
-        repeat.  The loop body is deliberately scatter-free: XLA's CPU
-        scatter (behind ``jax.ops.segment_*``) is orders of magnitude
-        slower than a gather, so the cell ids are sorted ONCE up front
-        and every per-round segment reduction becomes cumsum-at-static-
-        boundaries; frozen-ness lives in per-column masks instead of
-        rewriting ids, keeping every id-derived index static.  The
-        bottleneck test ``segment_min(fm) == share`` is replaced by the
-        equivalent ``count(fm < share) == 0`` (``fm <= share`` always
-        holds, since the cell's own share enters the min), which is a
-        sum — and therefore cumsum-able.
+        fair share equals the min share over its members' bottlenecks,
+        drain, repeat.  A cell is one (seed, link) pair, held in
+        ``(S, L)`` tables; frozen-ness lives in an ``(N, S)`` mask, so
+        every shape stays static.  Per-cell sums and mins go through
+        ``_cell_reduce``; per-flow reads gather from the ``(S, L)`` table.
+        The bottleneck test compares the min member bottleneck with the
+        cell's share: every member's bottleneck is at most the share (the
+        cell's own share enters its min), so equality means no member is
+        held lower elsewhere.
         """
-        H, C = cells.shape
-        flat = cells.ravel()                       # static per call
-        order = jnp.argsort(flat)
-        scol = order % C                           # column of sorted cell
-        sflat = flat[order]
-        bounds = jnp.searchsorted(sflat, jnp.arange(SL + 2))
-        valid_s = sflat < SL                       # real-link cells
-        wB_s = w[scol]
+        H, N, S = ids.shape
+        L = cap.shape[0]
+        flat = jnp.where(ids >= 0, ids, 0) + jnp.arange(
+            S, dtype=ids.dtype) * L                 # (H, N, S) into (S*L,)
+        real = ids >= 0
 
-        def segsum(v_s):                           # (H*C,) sorted -> (SL+1,)
-            c = jnp.concatenate([jnp.zeros(1), jnp.cumsum(v_s)])
-            return c[bounds[1:]] - c[bounds[:-1]]
+        def per_cell(v, reduce=jnp.sum, empty=0.0):  # (N, S) -> (S, L)
+            return _cell_reduce(ids, v, L, reduce, empty)
 
-        residual0 = jnp.concatenate([cap, jnp.zeros(1)])
-        haslink = (cells < SL).any(axis=0)
+        def per_flow(table, empty):                 # (S, L) -> (H, N, S)
+            return jnp.where(real, table.ravel()[flat], empty)
+
+        residual0 = jnp.broadcast_to(cap, (S, L))
+        haslink = real.any(axis=0)
         rates0 = jnp.where(haslink, 0.0, jnp.inf)
+        w = w[:, None]
 
         def cond(c):
             return c[0].any()
 
         def body(c):
             active, residual, rates = c
-            act_s = active[scol] & valid_s
-            wsum = segsum(jnp.where(act_s, wB_s, 0.0))
-            share = jnp.where(wsum > 0,
-                              residual / jnp.maximum(wsum, 1e-300), jnp.inf)
-            share = share.at[SL].set(jnp.inf)
-            fm = share[cells].min(axis=0)          # per-flow bottleneck
-            less = segsum(jnp.where(
-                act_s & (fm[scol] < share[sflat]), 1.0, 0.0))
-            freezable = (less == 0) & (wsum > 0)
-            freezable = freezable.at[SL].set(False)
-            fz = freezable[cells].any(axis=0) & active
+            wsum = per_cell(jnp.where(active, w, 0.0))
+            live = wsum > 0
+            share = jnp.where(live, residual / jnp.where(live, wsum, 1.0),
+                              jnp.inf)
+            fm = per_flow(share, jnp.inf).min(axis=0)   # flow bottleneck
+            nbr = per_cell(jnp.where(active, fm, jnp.inf), jnp.min,
+                           jnp.inf)
+            freezable = (nbr == share) & live
+            fz = per_flow(freezable, False).any(axis=0) & active
             rates = jnp.where(fz, w * fm, rates)
-            drained = segsum(jnp.where(
-                fz[scol] & valid_s, wB_s * fm[scol], 0.0))
+            drained = per_cell(jnp.where(fz, w * fm, 0.0))
             return active & ~fz, residual - drained, rates
 
         out = lax.while_loop(cond, body, (haslink, residual0, rates0))
@@ -611,19 +632,13 @@ def _fill_fn():
     return _fill_jit()
 
 
-def _fill_device(ids, link_gbps, weights, *, L: int):
+def _fill_device(ids, link_gbps, weights):
     """Run the fill on a device (H, N, S) link-id tensor; returns the
     device (N, S) rate grid."""
     _, jnp, _ = _jx()
-    H, N, S = ids.shape
-    SL = S * L
-    offs = jnp.arange(S, dtype=jnp.int32) * jnp.int32(L)
-    cells = jnp.where(ids >= 0, ids + offs[None, None, :], SL)
-    cells = cells.transpose(0, 2, 1).reshape(H, S * N)   # seed-major cols
-    w = jnp.tile(jnp.asarray(np.asarray(weights, np.float64)), S)
-    cap = jnp.tile(jnp.asarray(np.asarray(link_gbps, np.float64)), S)
-    rates = _fill_fn()(cells, w, cap, SL=SL)
-    return rates.reshape(S, N).T                         # (N, S)
+    return _fill_fn()(jnp.asarray(ids),
+                      jnp.asarray(np.asarray(weights, np.float64)),
+                      jnp.asarray(np.asarray(link_gbps, np.float64)))
 
 
 def jax_batched_max_min(
@@ -658,11 +673,8 @@ def jax_batched_max_min(
         out[:] = np.inf if H == 0 else 0.0
         return out
     with _x64():
-        _, jnp, _ = _jx()
-        rates = _fill_device(jnp.asarray(link_ids),
-                             np.asarray(link_gbps, np.float64),
-                             weights, L=len(link_gbps))
-        return np.asarray(rates)
+        return np.asarray(_fill_device(link_ids.astype(np.int32),
+                                       link_gbps, weights))
 
 
 # ---------------------------------------------------------------------------
@@ -735,34 +747,60 @@ def _column_weights_or_none(result: VectorTraceResult):
 # ---------------------------------------------------------------------------
 
 
-def _seed_chunks(n_flows: int, max_hops: int, S: int):
-    per = max(1, _FUSED_SEED_CHUNK_CELLS // max(1, n_flows))
-    for s0 in range(0, S, per):
-        yield s0, min(s0 + per, S)
+def seed_chunk(n_flows: int, max_hops: int, S: int) -> int:
+    """Seeds per device pass of the fused front ends.
+
+    The largest multiple of ``_SEED_LANES`` whose working set fits
+    ``_CHUNK_BYTES`` (at least one lane tile), then shrunk to split
+    ``S`` into equal chunks; ``S`` itself when it fits in one pass."""
+    per_seed = n_flows * max_hops * (_WALK_BYTES_PER_HOP
+                                     + _FILL_BYTES_PER_CELL)
+    per = max(_SEED_LANES,
+              _CHUNK_BYTES // per_seed // _SEED_LANES * _SEED_LANES)
+    if S <= per:
+        return S
+    even = -(-S // -(-S // per))               # ceil(S / n_chunks)
+    return -(-even // _SEED_LANES) * _SEED_LANES
 
 
-def _fused_walk_counts(comp, flows, seeds_u64, *, fields, hash_backend,
-                       max_hops, field_matrix, flow_demand):
-    """One device pass per seed chunk: walk + demand-weighted counts.
-    Returns the host (S, L) count matrix (small: seeds x links)."""
-    _, jnp, _ = _jx()
-    field_mat = (field_matrix if field_matrix is not None
-                 else flow_fields_matrix(flows, fields))
+def _walked_chunks(comp, flows, field_mat, seeds_u64, *, hash_backend,
+                   max_hops):
+    """Walk the seeds in equal device passes; yields ``(s0, s1, ids)``
+    with ``ids`` the device ``(hops, N, Sc)`` link ids of seeds
+    ``s0:s1``.  The last chunk is padded to the chunk size with repeats
+    of its own seeds, so every pass has one shape and compiles once;
+    callers keep the first ``s1 - s0`` seed columns."""
     src_dev, dst_dev, src_key, dst_key = comp.flow_endpoint_ids(flows)
-    L = comp.num_links
-    out = np.empty((len(seeds_u64), L))
-    for s0, s1 in _seed_chunks(len(flows), max_hops, len(seeds_u64)):
+    S = len(seeds_u64)
+    Sc = seed_chunk(len(flows), max_hops, S)
+    for s0 in range(0, S, Sc):
+        s1 = min(s0 + Sc, S)
+        chunk = np.resize(seeds_u64[s0:s1], Sc)
         ids, state, done, t = _jax_walk_device(
-            comp, src_dev, src_key, dst_key, field_mat, seeds_u64[s0:s1],
+            comp, src_dev, src_key, dst_key, field_mat, chunk,
             hash_backend=hash_backend, max_hops=max_hops)
         if not bool(done.all()):
             raise RuntimeError(
                 f"some flows did not terminate in {max_hops} hops")
         _check_walk(comp, state, dst_dev,
                     lambda n: f"flow {flows[n].flow_id}")
-        ids = ids[: int(t)]
+        ids = ids[: int(t)]            # frees the max_hops-deep tensor
+        yield s0, s1, ids
+
+
+def _fused_walk_counts(comp, flows, seeds_u64, *, fields, hash_backend,
+                       max_hops, field_matrix, flow_demand):
+    """One device pass per seed chunk: walk + demand-weighted counts.
+    Returns the host (S, L) count matrix (small: seeds x links)."""
+    field_mat = (field_matrix if field_matrix is not None
+                 else flow_fields_matrix(flows, fields))
+    L = comp.num_links
+    out = np.empty((len(seeds_u64), L))
+    for s0, s1, ids in _walked_chunks(comp, flows, field_mat, seeds_u64,
+                                      hash_backend=hash_backend,
+                                      max_hops=max_hops):
         out[s0:s1] = np.asarray(
-            jax_link_flow_counts(ids, flow_demand, L))
+            jax_link_flow_counts(ids, flow_demand, L))[: s1 - s0]
     return out
 
 
@@ -831,24 +869,13 @@ def fused_monte_carlo_throughput(
     profile = resolve_transport(transport)
     field_mat = (field_matrix if field_matrix is not None
                  else flow_fields_matrix(flows, fields))
-    src_dev, dst_dev, src_key, dst_key = comp.flow_endpoint_ids(flows)
-    N, S, L = len(flows), len(seeds_u64), comp.num_links
-    rates = np.empty((N, S))
+    rates = np.empty((len(flows), len(seeds_u64)))
     with _x64():
-        for s0, s1 in _seed_chunks(N, max_hops, S):
-            ids, state, done, t = _jax_walk_device(
-                comp, src_dev, src_key, dst_key, field_mat,
-                seeds_u64[s0:s1], hash_backend=hash_backend,
-                max_hops=max_hops)
-            if not bool(done.all()):
-                raise RuntimeError(
-                    f"some flows did not terminate in {max_hops} hops")
-            _check_walk(comp, state, dst_dev,
-                        lambda n: f"flow {flows[n].flow_id}")
-            ids = ids[: int(t)]
+        for s0, s1, ids in _walked_chunks(comp, flows, field_mat, seeds_u64,
+                                          hash_backend=hash_backend,
+                                          max_hops=max_hops):
             rates[:, s0:s1] = np.asarray(_fill_device(
-                ids, np.asarray(comp.link_gbps, np.float64),
-                flow_demand, L=L))
+                ids, comp.link_gbps, flow_demand))[:, : s1 - s0]
     pairs, per_pair = pair_rate_matrix(flows, rates)
     return MonteCarloThroughput(
         seeds=seeds_u64, flows=flows, rates=rates, pairs=pairs,

@@ -26,6 +26,7 @@ The tracer is deliberately jax-free so worker processes stay lightweight.
 from __future__ import annotations
 
 import dataclasses
+import multiprocessing
 import threading
 import time
 from concurrent.futures import ProcessPoolExecutor, ThreadPoolExecutor
@@ -267,7 +268,13 @@ class FlowTracer:
         else:
             shards = [pairs[i :: self.num_processes] for i in range(self.num_processes)]
             shards = [s for s in shards if s]
-            with ProcessPoolExecutor(max_workers=len(shards)) as ex:
+            # forkserver, not fork: a process that has run the jax engine
+            # holds the accelerator client and its threads, and a forked
+            # child would inherit both.
+            with ProcessPoolExecutor(
+                    max_workers=len(shards),
+                    mp_context=multiprocessing.get_context("forkserver"),
+            ) as ex:
                 results = list(
                     ex.map(
                         _process_entry,

@@ -34,6 +34,16 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
+def _check_kernel_mode(force_kernel: bool, interpret: bool) -> None:
+    """The compiled kernel exists only on the TPU; elsewhere the kernel
+    runs only when the caller asks for the interpreter by name."""
+    if force_kernel and not interpret and not _on_tpu():
+        raise ValueError(
+            "force_kernel=True off the TPU needs interpret=True: the "
+            f"compiled Pallas kernel targets the TPU, the backend here "
+            f"is {jax.default_backend()!r}")
+
+
 def bulk_hash(fields, seed, *, force_kernel: bool = False,
               interpret: bool = False, block: int = 4096):
     """fields: (N, F) uint32 -> (N,) uint32.  seed: any int (wrapped u32).
@@ -43,6 +53,7 @@ def bulk_hash(fields, seed, *, force_kernel: bool = False,
     grids (``vector_sim._murmur_hash_grid``, ``jax_engine``) evaluate
     per (flow, seed) cell, and ``bulk_hash_seeded`` evaluates per row.
     """
+    _check_kernel_mode(force_kernel, interpret)
     seed = np.uint32(int(seed) & 0xFFFFFFFF)
     return _bulk_hash_impl(fields, seed, force_kernel=force_kernel,
                            interpret=interpret, block=block)
@@ -57,7 +68,7 @@ def _bulk_hash_impl(fields, seed, *, force_kernel: bool = False,
         fields = jnp.pad(fields, ((0, pad), (0, 0)))
     if force_kernel or _on_tpu():
         out = bulk_hash_kernel(fields, jnp.uint32(seed),
-                               block=block, interpret=interpret or not _on_tpu())
+                               block=block, interpret=interpret)
     else:
         out = bulk_hash_ref(fields, jnp.uint32(seed))
     return out[:N, 0]
@@ -70,6 +81,7 @@ def bulk_hash_seeded(fields, seeds, *, force_kernel: bool = False,
     chain); ``bulk_hash(fields, s) == bulk_hash_seeded(fields, full(N, s))``
     bit-for-bit, which is what pins all murmur consumers to one
     definition."""
+    _check_kernel_mode(force_kernel, interpret)
     return _bulk_hash_seeded_impl(
         fields, seeds, force_kernel=force_kernel, interpret=interpret,
         block=block)
@@ -86,7 +98,7 @@ def _bulk_hash_seeded_impl(fields, seeds, *, force_kernel: bool = False,
     seeds = seeds.astype(jnp.uint32).reshape(-1, 1)
     if force_kernel or _on_tpu():
         out = bulk_hash_seeded_kernel(
-            fields, seeds, block=block, interpret=interpret or not _on_tpu())
+            fields, seeds, block=block, interpret=interpret)
     else:
         out = bulk_hash_seeded_ref(fields, seeds)
     return out[:N, 0]

@@ -229,6 +229,7 @@ def test_subset_run_merges_into_existing_payload(tmp_path, monkeypatch):
 
     results_path = tmp_path / "BENCH_results.json"
     monkeypatch.setattr(run, "RESULTS_PATH", str(results_path))
+    monkeypatch.setattr(run, "configure_compile_cache", lambda: None)
     monkeypatch.setattr(
         run, "BENCHES",
         {"alpha": lambda: common.emit("alpha_row", 1.0, "v=1"),
@@ -275,6 +276,7 @@ def test_subset_run_carries_prior_errors(tmp_path, monkeypatch):
 
     results_path = tmp_path / "BENCH_results.json"
     monkeypatch.setattr(run, "RESULTS_PATH", str(results_path))
+    monkeypatch.setattr(run, "configure_compile_cache", lambda: None)
 
     def boom():
         common.emit("beta_partial", 1.0, "v=1")
@@ -322,6 +324,7 @@ def test_stale_bench_rows_not_carried(tmp_path, monkeypatch):
         "errors": {"renamed-away": "RuntimeError: gone"},
     }))
     monkeypatch.setattr(run, "RESULTS_PATH", str(results_path))
+    monkeypatch.setattr(run, "configure_compile_cache", lambda: None)
     monkeypatch.setattr(
         run, "BENCHES", {"alpha": lambda: common.emit("alpha_row", 1.0, "v=1")})
     monkeypatch.setattr(common, "RESULTS", [])

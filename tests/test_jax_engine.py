@@ -3,8 +3,8 @@
 The numpy engine is the reference: under the exact splitmix64 backend
 the jax walk must be **bit-identical** (same uint64 arithmetic, just
 jitted), and every downstream stage — max-min fill, flowlet exposure,
-transport goodput, FIM — must agree within 1e-6 (the fill's cumsum-
-based segment sums round differently than numpy's bincount, nothing
+transport goodput, FIM — must agree within 1e-6 (the device's per-cell
+reductions sum in a different order than numpy's bincount, nothing
 more).  The sweep crosses randomized fabric shapes, all three routing
 strategies, both demand modes, and the fused front-end fast paths; the
 large-scale sweep rides behind the ``slow`` marker and scales via
@@ -25,7 +25,13 @@ from repro.core import (
     monte_carlo_throughput, nic_ip, server_name, simulate_paths,
     simulate_timeline, synthesize_flows, throughput_from_result,
 )
-from repro.core.jax_engine import default_hash_backend, resolve_engine
+from repro.compile_cache import (
+    CACHE_ENV, DEFAULT_CACHE_DIR, configure_compile_cache,
+)
+from repro.core import jax_engine
+from repro.core.jax_engine import (
+    default_hash_backend, resolve_engine, seed_chunk,
+)
 from repro.core.vector_sim import (
     ENGINE_JAX, ENGINE_NUMPY, EXACT, MURMUR, resolve_hash_backend,
 )
@@ -234,6 +240,55 @@ def test_fused_throughput_parity(paper8):
     assert np.abs(a.rates - b.rates).max() < 1e-6
     assert np.abs(a.goodput - b.goodput).max() < 1e-6
     assert np.abs(a.per_pair - b.per_pair).max() < 1e-6
+
+
+def test_seed_chunk_sizes():
+    # one pass while the chunk fits the byte budget ...
+    assert seed_chunk(4096, 16, 1024) == 1024
+    # ... then equal lane-aligned chunks: 3000 seeds in three passes
+    per = seed_chunk(4096, 16, 3000)
+    assert per % jax_engine._SEED_LANES == 0 and -(-3000 // per) == 3
+    # 100k flows: one lane tile per pass, 1024 seeds in equal passes
+    per = seed_chunk(100_000, 16, 1024)
+    assert per == jax_engine._SEED_LANES and 1024 % per == 0
+
+
+def test_fused_padded_last_chunk_parity(paper8, monkeypatch):
+    """A seed count that is not a multiple of the chunk pads the last
+    pass to the chunk size: the numbers match numpy and the walk and
+    fill compile one chunk shape, not two."""
+    comp, flows = paper8
+    per_seed = len(flows) * 16 * (jax_engine._WALK_BYTES_PER_HOP
+                                  + jax_engine._FILL_BYTES_PER_CELL)
+    monkeypatch.setattr(jax_engine, "_CHUNK_BYTES", per_seed * 128)
+    seeds = np.arange(200)                  # chunks of 128 + 72 (padded)
+    assert seed_chunk(len(flows), 16, len(seeds)) == 128
+    walk0 = jax_engine._walk_fn()._cache_size()
+    fill0 = jax_engine._fill_fn()._cache_size()
+    a = monte_carlo_throughput(comp, flows, seeds, demand_mode="bytes")
+    b = monte_carlo_throughput(comp, flows, seeds, demand_mode="bytes",
+                               engine=ENGINE_JAX)
+    assert np.abs(a.rates - b.rates).max() < 1e-6
+    assert jax_engine._walk_fn()._cache_size() - walk0 <= 1
+    assert jax_engine._fill_fn()._cache_size() - fill0 <= 1
+    fa = monte_carlo_fim(comp, flows, seeds)
+    fb = monte_carlo_fim(comp, flows, seeds, engine=ENGINE_JAX)
+    assert np.abs(fa.aggregate - fb.aggregate).max() < 1e-6
+
+
+def test_compile_cache_placement(monkeypatch):
+    import jax
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv(CACHE_ENV, "/elsewhere/cache")
+        assert configure_compile_cache() == "/elsewhere/cache"
+        assert jax.config.jax_compilation_cache_dir == was   # untouched
+        monkeypatch.delenv(CACHE_ENV)
+        assert configure_compile_cache() == str(DEFAULT_CACHE_DIR)
+        assert jax.config.jax_compilation_cache_dir == str(DEFAULT_CACHE_DIR)
+        assert DEFAULT_CACHE_DIR.name == ".jax_cache"
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
 
 
 def test_fused_path_only_for_plain_ecmp(paper8):

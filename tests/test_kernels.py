@@ -77,6 +77,19 @@ def test_flowhash_kernel_equals_ref():
     assert (hk == hr).all()
 
 
+def test_flowhash_force_kernel_off_tpu_needs_interpret():
+    """Off the TPU the kernel runs only in the interpreter, and only
+    when the caller asks for it: no silent switch."""
+    if jax.default_backend() == "tpu":
+        pytest.skip("the compiled kernel runs here")
+    fields = jnp.zeros((8, 5), jnp.uint32)
+    with pytest.raises(ValueError, match="interpret=True"):
+        bulk_hash(fields, 7, force_kernel=True)
+    with pytest.raises(ValueError, match="interpret=True"):
+        bulk_hash_seeded(fields, jnp.zeros((8,), jnp.uint32),
+                         force_kernel=True)
+
+
 @given(st.integers(0, 2**31 - 1))
 @settings(max_examples=20, deadline=None)
 def test_flowhash_deterministic_and_seed_sensitive(seed):
