@@ -12,7 +12,9 @@ the performance contract:
 * ``float()`` / ``bool()`` / ``int()`` / ``.item()`` / ``.tolist()`` on
   a traced array — a device->host sync in the middle of the kernel;
 * ``np.*`` calls on traced arrays — a silent host round-trip (numpy
-  forces concretization) that turns the fused pipeline into ping-pong.
+  forces concretization) that turns the fused pipeline into ping-pong;
+* ``span(...)`` / ``count(...)`` of ``core/spans.py`` — the Python body
+  runs once, at trace time, so the span records nothing per call.
 
 The rule runs a small interprocedural taint analysis: parameters of a
 jit entry that are NOT in ``static_argnames`` are traced; taint
@@ -42,7 +44,8 @@ RULE_LOOP = "FT-JIT-LOOP"
 RULE_BRANCH = "FT-JIT-BRANCH"
 RULE_HOSTSYNC = "FT-JIT-HOSTSYNC"
 RULE_NUMPY = "FT-JIT-NUMPY"
-RULE_IDS = (RULE_LOOP, RULE_BRANCH, RULE_HOSTSYNC, RULE_NUMPY)
+RULE_SPAN = "FT-JIT-SPAN"
+RULE_IDS = (RULE_LOOP, RULE_BRANCH, RULE_HOSTSYNC, RULE_NUMPY, RULE_SPAN)
 
 #: Modules that contain (or build) jitted kernels.
 JIT_MODULES = (
@@ -60,6 +63,9 @@ HOST_CASTS = {"float", "bool", "int", "complex"}
 HOST_METHODS = {"item", "tolist", "numpy"}
 
 NUMPY_ALIASES = {"np", "numpy"}
+
+#: Host spans and counters (``core/spans.py``): trace-time only in jit.
+SPAN_CALLS = {"span", "count", "spans.span", "spans.count"}
 
 
 def _is_jax_jit_expr(node: ast.expr) -> bool:
@@ -256,6 +262,11 @@ class _TaintChecker(ast.NodeVisitor):
                        f"`{callee}` called on traced value in jitted "
                        f"`{self.qualname}` (numpy concretizes the tracer)",
                        "use the jnp twin of the operation inside jit")
+        elif callee in SPAN_CALLS:
+            self._emit(RULE_SPAN, node,
+                       f"`{callee}()` in jitted `{self.qualname}` runs "
+                       f"once at trace time and records nothing per call",
+                       "open the span around the jitted call, on the host")
         elif callee in self.local_funcs and callee != self.qualname:
             taint = frozenset(self._callsite_taint(node, callee))
             self.helper_calls.append((callee, taint))

@@ -43,6 +43,7 @@ from .compile_fabric import CompiledFabric, compile_fabric
 from .ecmp import FIELDS_5TUPLE, HASH_INIT, flow_fields_matrix
 from .fabric import Fabric
 from .flows import Flow, WorkloadDescription
+from .spans import count, enabled, span
 from .vector_sim import (
     DEMAND_UNIFORM, EXACT, MURMUR, MonteCarloFim, VectorTraceResult,
     flow_demand_weights, normalize_seeds, resolve_flows,
@@ -526,15 +527,15 @@ def _fim_fn():
 
 
 def jax_fim_from_counts(
-    counts,
+    counts: np.ndarray,
     comp: CompiledFabric,
     *,
     layers: Sequence[str] | None = None,
     only_used_leaves: bool = False,
 ) -> tuple[np.ndarray, dict[str, np.ndarray]]:
-    """Twin of ``vector_sim.fim_from_counts`` on a device (S, L) count
-    matrix; returns host arrays with the same layer-dropping semantics."""
-    _, jnp, _ = _jx()
+    """Twin of ``vector_sim.fim_from_counts`` on the device: takes the
+    (S, L) count matrix, returns host arrays with the same layer-dropping
+    semantics."""
     layer_list = list(layers) if layers else comp.layer_names
     names, sels = [], []
     for layer in layer_list:
@@ -549,15 +550,26 @@ def jax_fim_from_counts(
     if not names:
         S = int(counts.shape[0])
         return np.zeros(S), {}
-    agg, mapes, lives = _fim_fn()(
-        counts, jnp.asarray(np.stack(sels)),
-        jnp.asarray(comp.link_src), jnp.asarray(comp.link_dst),
-        only_used_leaves=only_used_leaves, num_devices=comp.num_devices)
-    per_layer: dict[str, np.ndarray] = {}
-    for name, mape, live in zip(names, mapes, lives):
-        if bool(np.asarray(live).any()):   # all-dead layers are dropped
-            per_layer[name] = np.asarray(mape)
-    return np.asarray(agg), per_layer
+    jax, jnp, _ = _jx()
+    host = (counts, np.stack(sels), comp.link_src, comp.link_dst)
+    with span("fim.to_device", bytes=sum(
+            a.nbytes for a in host if isinstance(a, np.ndarray))):
+        args = jax.block_until_ready([jnp.asarray(a) for a in host])
+    with span("fim.run"):
+        agg, mapes, lives = jax.block_until_ready(_fim_fn()(
+            *args, only_used_leaves=only_used_leaves,
+            num_devices=comp.num_devices))
+    with span("fim.to_host"):
+        per_layer: dict[str, np.ndarray] = {}
+        pulled = agg.nbytes
+        for name, mape, live in zip(names, mapes, lives):
+            pulled += live.nbytes
+            if bool(np.asarray(live).any()):   # all-dead layers are dropped
+                per_layer[name] = np.asarray(mape)
+                pulled += mape.nbytes
+        agg = np.asarray(agg)
+        count("bytes", pulled)
+    return agg, per_layer
 
 
 # ---------------------------------------------------------------------------
@@ -572,7 +584,8 @@ def _fill_jit():
     def fill(ids, w, cap):
         """ids: (H, N, S) int32 link ids (-1 past the path's end),
         w: (N,) float64 positive weights, cap: (L,) float64 capacity.
-        Returns (N, S) max-min rates; a flow crossing no link gets inf.
+        Returns (N, S) max-min rates, a flow crossing no link getting
+        inf, and the int32 count of freeze rounds the loop ran.
 
         Same parallel local-bottleneck formulation as the numpy
         ``_fill_block_weighted``: freeze every flow crossing a cell whose
@@ -607,7 +620,7 @@ def _fill_jit():
             return c[0].any()
 
         def body(c):
-            active, residual, rates = c
+            active, residual, rates, rounds = c
             wsum = per_cell(jnp.where(active, w, 0.0))
             live = wsum > 0
             share = jnp.where(live, residual / jnp.where(live, wsum, 1.0),
@@ -619,10 +632,11 @@ def _fill_jit():
             fz = per_flow(freezable, False).any(axis=0) & active
             rates = jnp.where(fz, w * fm, rates)
             drained = per_cell(jnp.where(fz, w * fm, 0.0))
-            return active & ~fz, residual - drained, rates
+            return active & ~fz, residual - drained, rates, rounds + 1
 
-        out = lax.while_loop(cond, body, (haslink, residual0, rates0))
-        return out[2]
+        out = lax.while_loop(cond, body, (haslink, residual0, rates0,
+                                          jnp.int32(0)))
+        return out[2], out[3]
 
     return fill
 
@@ -634,7 +648,7 @@ def _fill_fn():
 
 def _fill_device(ids, link_gbps, weights):
     """Run the fill on a device (H, N, S) link-id tensor; returns the
-    device (N, S) rate grid."""
+    device (N, S) rate grid and the device count of freeze rounds."""
     _, jnp, _ = _jx()
     return _fill_fn()(jnp.asarray(ids),
                       jnp.asarray(np.asarray(weights, np.float64)),
@@ -673,8 +687,9 @@ def jax_batched_max_min(
         out[:] = np.inf if H == 0 else 0.0
         return out
     with _x64():
-        return np.asarray(_fill_device(link_ids.astype(np.int32),
-                                       link_gbps, weights))
+        rates, _ = _fill_device(link_ids.astype(np.int32), link_gbps,
+                                weights)
+        return np.asarray(rates)
 
 
 # ---------------------------------------------------------------------------
@@ -763,45 +778,79 @@ def seed_chunk(n_flows: int, max_hops: int, S: int) -> int:
     return -(-even // _SEED_LANES) * _SEED_LANES
 
 
-def _walked_chunks(comp, flows, field_mat, seeds_u64, *, hash_backend,
-                   max_hops):
+def _walked_chunks(comp, flows, endpoints, field_mat, seeds_u64, *,
+                   hash_backend, max_hops):
     """Walk the seeds in equal device passes; yields ``(s0, s1, ids)``
     with ``ids`` the device ``(hops, N, Sc)`` link ids of seeds
     ``s0:s1``.  The last chunk is padded to the chunk size with repeats
     of its own seeds, so every pass has one shape and compiles once;
-    callers keep the first ``s1 - s0`` seed columns."""
-    src_dev, dst_dev, src_key, dst_key = comp.flow_endpoint_ids(flows)
+    callers keep the first ``s1 - s0`` seed columns.
+
+    Each pass is three spans: ``walk.to_device`` (its inputs),
+    ``walk.run`` (the walk, its ``done`` flags and hop count) and
+    ``walk.to_host`` (the arrival check's (N, Sc) state)."""
+    jax, jnp, _ = _jx()
+    src_dev, dst_dev, src_key, dst_key = endpoints
     S = len(seeds_u64)
     Sc = seed_chunk(len(flows), max_hops, S)
     for s0 in range(0, S, Sc):
         s1 = min(s0 + Sc, S)
-        chunk = np.resize(seeds_u64[s0:s1], Sc)
-        ids, state, done, t = _jax_walk_device(
-            comp, src_dev, src_key, dst_key, field_mat, chunk,
-            hash_backend=hash_backend, max_hops=max_hops)
-        if not bool(done.all()):
-            raise RuntimeError(
-                f"some flows did not terminate in {max_hops} hops")
-        _check_walk(comp, state, dst_dev,
-                    lambda n: f"flow {flows[n].flow_id}")
-        ids = ids[: int(t)]            # frees the max_hops-deep tensor
+        host = (src_dev, src_key, dst_key, field_mat,
+                np.resize(seeds_u64[s0:s1], Sc))
+        with span("walk.to_device", bytes=sum(a.nbytes for a in host)):
+            args = jax.block_until_ready([jnp.asarray(a) for a in host])
+        with span("walk.run"):
+            ids, state, done, t = _jax_walk_device(
+                comp, *args, hash_backend=hash_backend, max_hops=max_hops)
+            # the reduction is queued behind the walk before the wait, so
+            # the device does not idle while it is dispatched
+            ids, state, all_done, t = jax.block_until_ready(
+                (ids, state, done.all(), t))
+            if not bool(all_done):
+                raise RuntimeError(
+                    f"some flows did not terminate in {max_hops} hops")
+            ids = ids[: int(t)]            # frees the max_hops-deep tensor
+        with span("walk.to_host", bytes=state.nbytes):
+            _check_walk(comp, state, dst_dev,
+                        lambda n: f"flow {flows[n].flow_id}")
         yield s0, s1, ids
 
 
-def _fused_walk_counts(comp, flows, seeds_u64, *, fields, hash_backend,
-                       max_hops, field_matrix, flow_demand):
+def _fused_walk_counts(comp, flows, endpoints, field_mat, seeds_u64, *,
+                       hash_backend, max_hops, flow_demand):
     """One device pass per seed chunk: walk + demand-weighted counts.
     Returns the host (S, L) count matrix (small: seeds x links)."""
-    field_mat = (field_matrix if field_matrix is not None
-                 else flow_fields_matrix(flows, fields))
+    jax = _jx()[0]
     L = comp.num_links
     out = np.empty((len(seeds_u64), L))
-    for s0, s1, ids in _walked_chunks(comp, flows, field_mat, seeds_u64,
-                                      hash_backend=hash_backend,
+    for s0, s1, ids in _walked_chunks(comp, flows, endpoints, field_mat,
+                                      seeds_u64, hash_backend=hash_backend,
                                       max_hops=max_hops):
-        out[s0:s1] = np.asarray(
-            jax_link_flow_counts(ids, flow_demand, L))[: s1 - s0]
+        with span("counts.run"):
+            counts = jax.block_until_ready(
+                jax_link_flow_counts(ids, flow_demand, L))
+        with span("counts.to_host", bytes=counts.nbytes):
+            out[s0:s1] = np.asarray(counts)[: s1 - s0]
     return out
+
+
+def _fused_prep(fabric, workload, seeds, fields, field_matrix, demand_mode):
+    """The host inputs of a fused sweep, in the ``prep`` span: compiled
+    fabric, flows, uint64 seeds, demand weights, hash fields and flow
+    endpoints."""
+    with span("prep"):
+        comp = (fabric if isinstance(fabric, CompiledFabric)
+                else compile_fabric(fabric))
+        flows = resolve_flows(comp, workload)
+        seeds_u64 = normalize_seeds(seeds)
+        if len(flows) == 0:
+            raise ValueError("simulate_paths needs at least one flow")
+        flow_demand = flow_demand_weights(flows, demand_mode)
+        field_mat = (field_matrix if field_matrix is not None
+                     else flow_fields_matrix(flows, fields))
+        endpoints = comp.flow_endpoint_ids(flows)
+    count("flows", len(flows))
+    return comp, flows, seeds_u64, flow_demand, field_mat, endpoints
 
 
 def fused_monte_carlo_fim(
@@ -818,24 +867,18 @@ def fused_monte_carlo_fim(
     field_matrix: np.ndarray | None = None,
 ) -> MonteCarloFim:
     """Plain-ECMP Monte-Carlo FIM with walk + counts + FIM on device."""
-    comp = (fabric if isinstance(fabric, CompiledFabric)
-            else compile_fabric(fabric))
-    flows = resolve_flows(comp, workload)
-    seeds_u64 = normalize_seeds(seeds)
-    if len(flows) == 0:
-        raise ValueError("simulate_paths needs at least one flow")
-    flow_demand = flow_demand_weights(flows, demand_mode)
+    comp, flows, seeds_u64, flow_demand, field_mat, endpoints = _fused_prep(
+        fabric, workload, seeds, fields, field_matrix, demand_mode)
     with _x64():
-        _, jnp, _ = _jx()
         counts = _fused_walk_counts(
-            comp, flows, seeds_u64, fields=fields,
+            comp, flows, endpoints, field_mat, seeds_u64,
             hash_backend=hash_backend, max_hops=max_hops,
-            field_matrix=field_matrix, flow_demand=flow_demand)
+            flow_demand=flow_demand)
         agg, per_layer = jax_fim_from_counts(
-            jnp.asarray(counts), comp, layers=layers,
-            only_used_leaves=only_used_leaves)
-    return MonteCarloFim(seeds=seeds_u64, aggregate=agg,
-                         per_layer=per_layer)
+            counts, comp, layers=layers, only_used_leaves=only_used_leaves)
+    with span("assemble"):
+        return MonteCarloFim(seeds=seeds_u64, aggregate=agg,
+                             per_layer=per_layer)
 
 
 def fused_monte_carlo_throughput(
@@ -859,24 +902,24 @@ def fused_monte_carlo_throughput(
     """
     from .reordering import resolve_transport
     from .vector_throughput import MonteCarloThroughput, pair_rate_matrix
-    comp = (fabric if isinstance(fabric, CompiledFabric)
-            else compile_fabric(fabric))
-    flows = resolve_flows(comp, workload)
-    seeds_u64 = normalize_seeds(seeds)
-    if len(flows) == 0:
-        raise ValueError("simulate_paths needs at least one flow")
-    flow_demand = flow_demand_weights(flows, demand_mode)
+    comp, flows, seeds_u64, flow_demand, field_mat, endpoints = _fused_prep(
+        fabric, workload, seeds, fields, field_matrix, demand_mode)
     profile = resolve_transport(transport)
-    field_mat = (field_matrix if field_matrix is not None
-                 else flow_fields_matrix(flows, fields))
     rates = np.empty((len(flows), len(seeds_u64)))
     with _x64():
-        for s0, s1, ids in _walked_chunks(comp, flows, field_mat, seeds_u64,
-                                          hash_backend=hash_backend,
-                                          max_hops=max_hops):
-            rates[:, s0:s1] = np.asarray(_fill_device(
-                ids, comp.link_gbps, flow_demand))[:, : s1 - s0]
-    pairs, per_pair = pair_rate_matrix(flows, rates)
-    return MonteCarloThroughput(
-        seeds=seeds_u64, flows=flows, rates=rates, pairs=pairs,
-        per_pair=per_pair, transport=profile.name)
+        jax = _jx()[0]
+        for s0, s1, ids in _walked_chunks(
+                comp, flows, endpoints, field_mat, seeds_u64,
+                hash_backend=hash_backend, max_hops=max_hops):
+            with span("fill.run"):
+                chunk, rounds = jax.block_until_ready(
+                    _fill_device(ids, comp.link_gbps, flow_demand))
+            with span("fill.to_host", bytes=chunk.nbytes):
+                rates[:, s0:s1] = np.asarray(chunk)[:, : s1 - s0]
+                if enabled():          # the untraced path pulls no count
+                    count("rounds", int(rounds))
+    with span("assemble"):
+        pairs, per_pair = pair_rate_matrix(flows, rates)
+        return MonteCarloThroughput(
+            seeds=seeds_u64, flows=flows, rates=rates, pairs=pairs,
+            per_pair=per_pair, transport=profile.name)

@@ -82,6 +82,7 @@ import numpy as np
 from .compile_fabric import CompiledFabric, compile_fabric
 from .fabric import Fabric
 from .flows import Flow, WorkloadDescription
+from .spans import span
 from .strategies import AdaptiveSpraying
 from .vector_sim import (
     MonteCarloFim, SimSpec, TIMING_EVENT, TIMING_STATIC, _UNSET,
@@ -408,9 +409,10 @@ def _score_step(comp, sub, seeds, s, layers, only_used_leaves):
     merged front ends run, with the flowlet fill shared between the
     throughput snapshot and (under event timing) the departure drain."""
     res = simulate_paths(comp, sub, seeds, spec=s)
-    agg, per_layer = fim_from_counts(
-        res.link_flow_counts(), comp,
-        layers=layers, only_used_leaves=only_used_leaves)
+    with span("timeline.fim"):
+        agg, per_layer = fim_from_counts(
+            res.link_flow_counts(), comp,
+            layers=layers, only_used_leaves=only_used_leaves)
     flowlet_rates = max_min_rates(res, engine=s.engine)
     tp = throughput_from_result(res, transport=s.transport,
                                 engine=s.engine,

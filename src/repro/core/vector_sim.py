@@ -44,6 +44,7 @@ from .ecmp import (
 from .fabric import Fabric
 from .flows import Flow, WorkloadDescription, synthesize_flows
 from .fim import Path
+from .spans import count, span
 
 
 def resolve_flows(
@@ -744,24 +745,28 @@ def monte_carlo_fim(
     (walk + counts + FIM in one pass, ``jax_engine``); other strategies
     route on the jax walk and aggregate on host.
     """
-    s = resolve_spec(spec, dict(
-        fields=fields, hash_backend=hash_backend, strategy=strategy,
-        demand_mode=demand_mode, engine=engine, max_hops=max_hops))
-    comp = fabric if isinstance(fabric, CompiledFabric) else compile_fabric(fabric)
-    if s.engine != ENGINE_NUMPY and _is_plain_ecmp(s.strategy):
-        from .jax_engine import fused_monte_carlo_fim, resolve_engine
-        resolve_engine(s.engine)
-        return fused_monte_carlo_fim(
-            comp, workload, seeds, fields=s.fields,
-            hash_backend=s.hash_backend,
-            layers=layers, only_used_leaves=only_used_leaves,
-            demand_mode=s.demand_mode, max_hops=s.max_hops)
-    flows = resolve_flows(comp, workload)
-    res = simulate_paths(comp, flows, seeds, spec=s)
-    agg, per_layer = fim_from_counts(
-        res.link_flow_counts(), comp,
-        layers=layers, only_used_leaves=only_used_leaves)
-    return MonteCarloFim(seeds=res.seeds, aggregate=agg, per_layer=per_layer)
+    with span("monte_carlo_fim", seeds=len(seeds)):
+        s = resolve_spec(spec, dict(
+            fields=fields, hash_backend=hash_backend, strategy=strategy,
+            demand_mode=demand_mode, engine=engine, max_hops=max_hops))
+        comp = (fabric if isinstance(fabric, CompiledFabric)
+                else compile_fabric(fabric))
+        if s.engine != ENGINE_NUMPY and _is_plain_ecmp(s.strategy):
+            from .jax_engine import fused_monte_carlo_fim, resolve_engine
+            resolve_engine(s.engine)
+            return fused_monte_carlo_fim(
+                comp, workload, seeds, fields=s.fields,
+                hash_backend=s.hash_backend,
+                layers=layers, only_used_leaves=only_used_leaves,
+                demand_mode=s.demand_mode, max_hops=s.max_hops)
+        flows = resolve_flows(comp, workload)
+        count("flows", len(flows))
+        res = simulate_paths(comp, flows, seeds, spec=s)
+        agg, per_layer = fim_from_counts(
+            res.link_flow_counts(), comp,
+            layers=layers, only_used_leaves=only_used_leaves)
+        return MonteCarloFim(seeds=res.seeds, aggregate=agg,
+                             per_layer=per_layer)
 
 
 def _is_plain_ecmp(strategy) -> bool:

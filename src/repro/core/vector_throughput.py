@@ -46,6 +46,7 @@ from .compile_fabric import CompiledFabric, compile_fabric
 from .contracts import check_throughput, contracts_enabled
 from .fabric import Fabric
 from .flows import Flow, WorkloadDescription
+from .spans import count, span
 from .vector_sim import (
     ENGINE_NUMPY, SimSpec, VectorTraceResult, _UNSET,
     _is_plain_ecmp, resolve_flows, resolve_spec,
@@ -77,11 +78,12 @@ def dedup_link_ids(link_ids: np.ndarray) -> np.ndarray:
     rejected: numpy's axis sorts cost 3-5x these compares at every
     realistic shape (still 1.5x slower at H=128, far past any walk).
     """
-    ids = np.array(link_ids, copy=True)
-    for h in range(1, ids.shape[0]):
-        dup = (ids[h] == ids[:h]).any(axis=0)
-        np.copyto(ids[h], -1, where=dup & (ids[h] >= 0))
-    return ids
+    with span("dedup"):
+        ids = np.array(link_ids, copy=True)
+        for h in range(1, ids.shape[0]):
+            dup = (ids[h] == ids[:h]).any(axis=0)
+            np.copyto(ids[h], -1, where=dup & (ids[h] >= 0))
+        return ids
 
 
 def _fill_block(sub: np.ndarray, sentinel: int, cap: np.ndarray,
@@ -440,91 +442,93 @@ def departure_fill(
     walk that produced ``link_ids`` may of course come from either
     engine; the drain is bit-identical downstream of it.
     """
-    if engine != ENGINE_NUMPY:
-        from .jax_engine import resolve_engine
-        resolve_engine(engine)
-    link_ids = np.asarray(link_ids)
-    if link_ids.ndim != 3:
-        raise ValueError(f"link_ids must be (H, N, S), got {link_ids.shape}")
-    if not assume_unique:
-        link_ids = dedup_link_ids(link_ids)
-    H, N, S = link_ids.shape
-    gb = np.asarray(col_gbits, np.float64)
-    if gb.shape != (N,):
-        raise ValueError(
-            f"col_gbits must be ({N},) to match link_ids columns, "
-            f"got {gb.shape}")
-    if (gb < 0).any() or not np.isfinite(gb).all():
-        raise ValueError("col_gbits must be finite and >= 0")
-    if efficiency is None:
-        eff = np.ones((N, S))
-    else:
-        eff = np.asarray(efficiency, np.float64)
-        if eff.shape != (N, S):
+    with span("departure_fill"):
+        if engine != ENGINE_NUMPY:
+            from .jax_engine import resolve_engine
+            resolve_engine(engine)
+        link_ids = np.asarray(link_ids)
+        if link_ids.ndim != 3:
+            raise ValueError(f"link_ids must be (H, N, S), got {link_ids.shape}")
+        if not assume_unique:
+            link_ids = dedup_link_ids(link_ids)
+        H, N, S = link_ids.shape
+        gb = np.asarray(col_gbits, np.float64)
+        if gb.shape != (N,):
             raise ValueError(
-                f"efficiency must be ({N}, {S}), got {eff.shape}")
-        if not ((eff > 0) & np.isfinite(eff)).all():
-            raise ValueError("efficiency must be finite and > 0")
-    completion = np.zeros((N, S))
-    if N == 0 or S == 0 or H == 0:
+                f"col_gbits must be ({N},) to match link_ids columns, "
+                f"got {gb.shape}")
+        if (gb < 0).any() or not np.isfinite(gb).all():
+            raise ValueError("col_gbits must be finite and >= 0")
+        if efficiency is None:
+            eff = np.ones((N, S))
+        else:
+            eff = np.asarray(efficiency, np.float64)
+            if eff.shape != (N, S):
+                raise ValueError(
+                    f"efficiency must be ({N}, {S}), got {eff.shape}")
+            if not ((eff > 0) & np.isfinite(eff)).all():
+                raise ValueError("efficiency must be finite and > 0")
+        completion = np.zeros((N, S))
+        if N == 0 or S == 0 or H == 0:
+            return DepartureFill(completion=completion,
+                                 duration=completion.max(axis=0, initial=0.0),
+                                 rounds=0)
+        t = np.zeros(S)
+        rem = np.broadcast_to(gb[:, None], (N, S)).copy()
+        active = rem > 0.0
+        ids = link_ids.copy()
+        ids[:, ~active] = -1                   # zero-gigabit cells never contend
+        rounds = 0
+        while True:
+            alive = active.any(axis=1)         # column compaction
+            if not alive.any():
+                break
+            rounds += 1
+            if rounds > N + 1:                 # >= 1 cell departs per round per
+                raise RuntimeError(            # active seed, so N+1 is unreachable
+                    "departure_fill failed to converge (rate degeneracy?)")
+            sel = np.flatnonzero(alive)
+            sub_ids = ids[:, sel]
+            if rounds == 1 and initial_rates is not None and alive.all():
+                rates = np.asarray(initial_rates, np.float64)
+                if rates.shape != (N, S):
+                    raise ValueError(
+                        f"initial_rates must be ({N}, {S}), got {rates.shape}")
+            else:
+                rates = batched_max_min(
+                    sub_ids, link_gbps, assume_unique=True,
+                    seed_block=seed_block,
+                    weights=None if weights is None else
+                    np.asarray(weights, np.float64)[sel])
+            act = active[sel]
+            good = rates * eff[sel]
+            with np.errstate(divide="ignore", invalid="ignore"):
+                fin = np.where(act, rem[sel] / good, np.inf)
+            fin = np.where(np.isnan(fin), np.inf, fin)
+            dt = fin.min(axis=0)               # (S,) next departure horizon
+            seed_active = act.any(axis=0)
+            if (seed_active & ~np.isfinite(dt)).any():
+                raise RuntimeError(
+                    "departure_fill: active flow with zero goodput can never "
+                    "finish (zero-capacity bottleneck link?)")
+            dt0 = np.where(seed_active, dt, 0.0)
+            # everything within float tolerance of the horizon departs together
+            depart = act & (fin <= dt[None, :] * (1.0 + 1e-12))
+            comp_sel = completion[sel]
+            comp_sel[depart] = (t[None, :] + fin)[depart]
+            completion[sel] = comp_sel
+            drain = np.where(act & np.isfinite(good), good, 0.0) * dt0[None, :]
+            rem_sel = np.maximum(rem[sel] - drain, 0.0)
+            rem_sel[depart] = 0.0
+            rem[sel] = rem_sel
+            t += dt0
+            active[sel] = act & ~depart
+            sub_ids[:, depart] = -1            # departed cells leave the wire
+            ids[:, sel] = sub_ids
+        count("rounds", rounds)
         return DepartureFill(completion=completion,
                              duration=completion.max(axis=0, initial=0.0),
-                             rounds=0)
-    t = np.zeros(S)
-    rem = np.broadcast_to(gb[:, None], (N, S)).copy()
-    active = rem > 0.0
-    ids = link_ids.copy()
-    ids[:, ~active] = -1                   # zero-gigabit cells never contend
-    rounds = 0
-    while True:
-        alive = active.any(axis=1)         # column compaction
-        if not alive.any():
-            break
-        rounds += 1
-        if rounds > N + 1:                 # >= 1 cell departs per round per
-            raise RuntimeError(            # active seed, so N+1 is unreachable
-                "departure_fill failed to converge (rate degeneracy?)")
-        sel = np.flatnonzero(alive)
-        sub_ids = ids[:, sel]
-        if rounds == 1 and initial_rates is not None and alive.all():
-            rates = np.asarray(initial_rates, np.float64)
-            if rates.shape != (N, S):
-                raise ValueError(
-                    f"initial_rates must be ({N}, {S}), got {rates.shape}")
-        else:
-            rates = batched_max_min(
-                sub_ids, link_gbps, assume_unique=True,
-                seed_block=seed_block,
-                weights=None if weights is None else
-                np.asarray(weights, np.float64)[sel])
-        act = active[sel]
-        good = rates * eff[sel]
-        with np.errstate(divide="ignore", invalid="ignore"):
-            fin = np.where(act, rem[sel] / good, np.inf)
-        fin = np.where(np.isnan(fin), np.inf, fin)
-        dt = fin.min(axis=0)               # (S,) next departure horizon
-        seed_active = act.any(axis=0)
-        if (seed_active & ~np.isfinite(dt)).any():
-            raise RuntimeError(
-                "departure_fill: active flow with zero goodput can never "
-                "finish (zero-capacity bottleneck link?)")
-        dt0 = np.where(seed_active, dt, 0.0)
-        # everything within float tolerance of the horizon departs together
-        depart = act & (fin <= dt[None, :] * (1.0 + 1e-12))
-        comp_sel = completion[sel]
-        comp_sel[depart] = (t[None, :] + fin)[depart]
-        completion[sel] = comp_sel
-        drain = np.where(act & np.isfinite(good), good, 0.0) * dt0[None, :]
-        rem_sel = np.maximum(rem[sel] - drain, 0.0)
-        rem_sel[depart] = 0.0
-        rem[sel] = rem_sel
-        t += dt0
-        active[sel] = act & ~depart
-        sub_ids[:, depart] = -1            # departed cells leave the wire
-        ids[:, sel] = sub_ids
-    return DepartureFill(completion=completion,
-                         duration=completion.max(axis=0, initial=0.0),
-                         rounds=rounds)
+                             rounds=rounds)
 
 
 @dataclasses.dataclass
@@ -714,20 +718,25 @@ def monte_carlo_throughput(
     strategies route on the jax walk and fill/expose on device with
     host glue in between.
     """
-    s = resolve_spec(spec, dict(
-        fields=fields, hash_backend=hash_backend, strategy=strategy,
-        demand_mode=demand_mode, transport=transport, engine=engine,
-        max_hops=max_hops))
-    comp = fabric if isinstance(fabric, CompiledFabric) else compile_fabric(fabric)
-    if s.engine != ENGINE_NUMPY and _is_plain_ecmp(s.strategy):
-        from .jax_engine import fused_monte_carlo_throughput, resolve_engine
-        resolve_engine(s.engine)
-        return fused_monte_carlo_throughput(
-            comp, workload, seeds, fields=s.fields,
-            hash_backend=s.hash_backend,
-            demand_mode=s.demand_mode, transport=s.transport,
-            field_matrix=field_matrix, max_hops=s.max_hops)
-    flows = resolve_flows(comp, workload)
-    res = simulate_paths(comp, flows, seeds, spec=s,
-                         field_matrix=field_matrix)
-    return throughput_from_result(res, transport=s.transport, engine=s.engine)
+    with span("monte_carlo_throughput", seeds=len(seeds)):
+        s = resolve_spec(spec, dict(
+            fields=fields, hash_backend=hash_backend, strategy=strategy,
+            demand_mode=demand_mode, transport=transport, engine=engine,
+            max_hops=max_hops))
+        comp = (fabric if isinstance(fabric, CompiledFabric)
+                else compile_fabric(fabric))
+        if s.engine != ENGINE_NUMPY and _is_plain_ecmp(s.strategy):
+            from .jax_engine import (
+                fused_monte_carlo_throughput, resolve_engine)
+            resolve_engine(s.engine)
+            return fused_monte_carlo_throughput(
+                comp, workload, seeds, fields=s.fields,
+                hash_backend=s.hash_backend,
+                demand_mode=s.demand_mode, transport=s.transport,
+                field_matrix=field_matrix, max_hops=s.max_hops)
+        flows = resolve_flows(comp, workload)
+        count("flows", len(flows))
+        res = simulate_paths(comp, flows, seeds, spec=s,
+                             field_matrix=field_matrix)
+        return throughput_from_result(res, transport=s.transport,
+                                      engine=s.engine)

@@ -118,6 +118,34 @@ def test_jit_taint_reaches_same_module_helpers(tmp_path):
     assert rules_of(root).count("FT-JIT-BRANCH") == 1
 
 
+def test_jit_span_flags_spans_inside_traced_code_only(tmp_path):
+    # a span or counter inside jit records once, at trace time; around
+    # the jitted call on the host it is the intended use
+    root = make_repo(tmp_path, {"src/repro/core/jax_engine.py": """\
+        import jax
+        from jax import lax
+
+        from .spans import count, span
+
+
+        @jax.jit
+        def fill(x):
+            with span("fill.inner"):         # FT-JIT-SPAN
+                def body(c):
+                    count("rounds", 1)       # FT-JIT-SPAN
+                    return c - 1
+                return lax.while_loop(lambda c: c > 0, body, x)
+
+
+        def host(x):
+            with span("fill.run"):
+                out = fill(x)
+            count("rounds", 1)
+            return out
+        """})
+    assert rules_of(root).count("FT-JIT-SPAN") == 2
+
+
 # ---------------------------------------------------------------------------
 # FT-DT: dtype drift
 # ---------------------------------------------------------------------------
