@@ -271,18 +271,19 @@ def _jax_walk_device(comp, src_dev, src_key, dst_key, field_mat, seeds_u64,
         n_fields=int(field_mat.shape[1]))
 
 
-def _check_walk(comp, state, dst_dev, describe):
+def _check_walk(comp, state, dst_dev, describe, first_seed=0):
     """The numpy engine's arrival contract (termination is checked on
     the ``done`` scalar before this runs); state is (N, S)-small, so the
-    host pull costs nothing next to the link-id tensor it replaces."""
+    host pull costs nothing next to the link-id tensor it replaces.
+    ``first_seed`` is the seed index of the state's first column."""
     state = np.asarray(state)
     arrived = state == np.broadcast_to(
         np.asarray(dst_dev)[:, None], state.shape)
     if not arrived.all():
         bad = np.argwhere(~arrived)[0]
         raise RuntimeError(
-            f"{describe(bad[0])} (seed index {bad[1]}) terminated "
-            f"at {comp.device_names[state[bad[0], bad[1]]]}")
+            f"{describe(bad[0])} (seed index {first_seed + bad[1]}) "
+            f"terminated at {comp.device_names[state[bad[0], bad[1]]]}")
 
 
 def jax_ecmp_walk(
@@ -481,9 +482,12 @@ def _fim_jit():
 
     @functools.partial(jax.jit,
                        static_argnames=("only_used_leaves", "num_devices"))
-    def fim_fn(counts, layer_sel, link_src, link_dst,
+    def fim_fn(counts, layer_sel, link_src, link_dst, n_real,
                *, only_used_leaves: bool, num_devices: int):
-        # counts: (S, L) float; layer_sel: (NL, L) bool one-hot per layer
+        # counts: (S, L) float; layer_sel: (NL, L) bool one-hot per layer;
+        # the first n_real seed rows are real, the rest chunk padding.
+        # Returns the (1 + NL, S) aggregate and per-layer MAPEs, and the
+        # (NL,) flags of layers live under some real seed.
         S, L = counts.shape
         if only_used_leaves:
             present = counts > 0
@@ -516,7 +520,9 @@ def _fim_jit():
             num = num + jnp.where(live, mape * n_links, 0.0)
             den = den + jnp.where(live, n_links, 0.0)
         agg = jnp.where(den > 0, num / jnp.maximum(den, 1.0), 0.0)
-        return agg, [m for m, _ in mapes], [lv for _, lv in mapes]
+        real = jnp.arange(S, dtype=jnp.int32) < n_real
+        return (jnp.stack([agg] + [m for m, _ in mapes]),
+                jnp.stack([(lv & real).any() for _, lv in mapes]))
 
     return fim_fn
 
@@ -526,50 +532,80 @@ def _fim_fn():
     return _fim_jit()
 
 
+_FIM_TABLE_CACHE: dict[tuple, tuple[object, tuple]] = {}
+
+
+def _fim_tables(comp: CompiledFabric, names: tuple[str, ...]):
+    """Device ``(NL, L)`` layer selection, ``link_src`` and ``link_dst``
+    of the FIM over ``names``, uploaded once per compiled fabric and
+    layer list (cached as ``device_tables``); the upload is the
+    ``fim.to_device`` span."""
+    key = (id(comp), names)
+    hit = _FIM_TABLE_CACHE.get(key)
+    if hit is not None and hit[0] is comp:
+        return hit[1]
+    jax, jnp, _ = _jx()
+    host = (np.stack([comp.link_layer == comp.layer_names.index(n)
+                      for n in names]), comp.link_src, comp.link_dst)
+    with span("fim.to_device", bytes=sum(a.nbytes for a in host)):
+        tabs = tuple(jax.block_until_ready([jnp.asarray(a) for a in host]))
+    if len(_FIM_TABLE_CACHE) > 16:
+        _FIM_TABLE_CACHE.clear()
+    _FIM_TABLE_CACHE[key] = (comp, tabs)
+    return tabs
+
+
+def _fim_chunks(comp, chunks, *, layers, only_used_leaves):
+    """FIM of device ``(Sc, L)`` count chunks, each given as
+    ``(n_real, counts)`` with its first ``n_real`` seed rows real: one
+    ``fim_fn`` per chunk at its own shape, then every chunk's answers in
+    one pull.  Returns host arrays with ``fim_from_counts``'s
+    layer-dropping semantics."""
+    jax = _jx()[0]
+    layer_list = list(layers) if layers else comp.layer_names
+    names = tuple(layer for layer in layer_list
+                  if layer in comp.layer_names
+                  and (comp.link_layer
+                       == comp.layer_names.index(layer)).any())
+    if not names:          # the chunks still run: the walk checks arrival
+        return np.zeros(sum(n_real for n_real, _ in chunks)), {}
+    tabs = _fim_tables(comp, names)
+    sizes, outs = [], []
+    for n_real, counts in chunks:
+        with span("fim.run"):
+            outs.append(jax.block_until_ready(_fim_fn()(
+                counts, *tabs, n_real, only_used_leaves=only_used_leaves,
+                num_devices=comp.num_devices)))
+        sizes.append(n_real)
+    with span("fim.to_host", bytes=sum(a.nbytes for o in outs for a in o)):
+        outs = jax.device_get(outs)
+    fim = np.concatenate([m[:, :n] for (m, _), n in zip(outs, sizes)], 1)
+    live = np.logical_or.reduce([lv for _, lv in outs])
+    # all-dead layers are dropped
+    per_layer = {name: fim[1 + i] for i, name in enumerate(names)
+                 if live[i]}
+    return fim[0], per_layer
+
+
 def jax_fim_from_counts(
-    counts: np.ndarray,
+    counts,
     comp: CompiledFabric,
     *,
     layers: Sequence[str] | None = None,
     only_used_leaves: bool = False,
 ) -> tuple[np.ndarray, dict[str, np.ndarray]]:
     """Twin of ``vector_sim.fim_from_counts`` on the device: takes the
-    (S, L) count matrix, returns host arrays with the same layer-dropping
-    semantics."""
-    layer_list = list(layers) if layers else comp.layer_names
-    names, sels = [], []
-    for layer in layer_list:
-        if layer not in comp.layer_names:
-            continue
-        lid = comp.layer_names.index(layer)
-        sel = comp.link_layer == lid
-        if not sel.any():
-            continue
-        names.append(layer)
-        sels.append(sel)
-    if not names:
-        S = int(counts.shape[0])
-        return np.zeros(S), {}
+    (S, L) count matrix, a device array used where it is or a host array
+    uploaded (``fim.to_device``), and returns host arrays with the same
+    layer-dropping semantics."""
     jax, jnp, _ = _jx()
-    host = (counts, np.stack(sels), comp.link_src, comp.link_dst)
-    with span("fim.to_device", bytes=sum(
-            a.nbytes for a in host if isinstance(a, np.ndarray))):
-        args = jax.block_until_ready([jnp.asarray(a) for a in host])
-    with span("fim.run"):
-        agg, mapes, lives = jax.block_until_ready(_fim_fn()(
-            *args, only_used_leaves=only_used_leaves,
-            num_devices=comp.num_devices))
-    with span("fim.to_host"):
-        per_layer: dict[str, np.ndarray] = {}
-        pulled = agg.nbytes
-        for name, mape, live in zip(names, mapes, lives):
-            pulled += live.nbytes
-            if bool(np.asarray(live).any()):   # all-dead layers are dropped
-                per_layer[name] = np.asarray(mape)
-                pulled += mape.nbytes
-        agg = np.asarray(agg)
-        count("bytes", pulled)
-    return agg, per_layer
+    with _x64():
+        if not isinstance(counts, jax.Array):
+            counts = np.asarray(counts)
+            with span("fim.to_device", bytes=counts.nbytes):
+                counts = jax.block_until_ready(jnp.asarray(counts))
+        return _fim_chunks(comp, [(int(counts.shape[0]), counts)],
+                           layers=layers, only_used_leaves=only_used_leaves)
 
 
 # ---------------------------------------------------------------------------
@@ -787,8 +823,9 @@ def _walked_chunks(comp, flows, endpoints, field_mat, seeds_u64, *,
     callers keep the first ``s1 - s0`` seed columns.
 
     Each pass is three spans: ``walk.to_device`` (its inputs),
-    ``walk.run`` (the walk, its ``done`` flags and hop count) and
-    ``walk.to_host`` (the arrival check's (N, Sc) state)."""
+    ``walk.run`` (the walk, its ``done`` flags, arrival check and hop
+    count) and ``walk.to_host`` (the arrival check's one bool; the
+    (N, s1 - s0) state too where a flow did not arrive)."""
     jax, jnp, _ = _jx()
     src_dev, dst_dev, src_key, dst_key = endpoints
     S = len(seeds_u64)
@@ -796,42 +833,44 @@ def _walked_chunks(comp, flows, endpoints, field_mat, seeds_u64, *,
     for s0 in range(0, S, Sc):
         s1 = min(s0 + Sc, S)
         host = (src_dev, src_key, dst_key, field_mat,
-                np.resize(seeds_u64[s0:s1], Sc))
+                np.resize(seeds_u64[s0:s1], Sc), dst_dev)
         with span("walk.to_device", bytes=sum(a.nbytes for a in host)):
-            args = jax.block_until_ready([jnp.asarray(a) for a in host])
+            *args, dst = jax.block_until_ready(
+                [jnp.asarray(a) for a in host])
         with span("walk.run"):
             ids, state, done, t = _jax_walk_device(
                 comp, *args, hash_backend=hash_backend, max_hops=max_hops)
-            # the reduction is queued behind the walk before the wait, so
-            # the device does not idle while it is dispatched
-            ids, state, all_done, t = jax.block_until_ready(
-                (ids, state, done.all(), t))
+            # the reductions are queued behind the walk before the wait,
+            # so the device does not idle while they are dispatched
+            ids, all_done, arrived, t = jax.block_until_ready(
+                (ids, done.all(), (state == dst[:, None]).all(), t))
             if not bool(all_done):
                 raise RuntimeError(
                     f"some flows did not terminate in {max_hops} hops")
             ids = ids[: int(t)]            # frees the max_hops-deep tensor
-        with span("walk.to_host", bytes=state.nbytes):
-            _check_walk(comp, state, dst_dev,
-                        lambda n: f"flow {flows[n].flow_id}")
+        with span("walk.to_host", bytes=arrived.nbytes):
+            if not bool(arrived):
+                state = state[:, : s1 - s0]
+                count("bytes", state.nbytes)
+                _check_walk(comp, state, dst_dev,
+                            lambda n: f"flow {flows[n].flow_id}",
+                            first_seed=s0)
         yield s0, s1, ids
 
 
 def _fused_walk_counts(comp, flows, endpoints, field_mat, seeds_u64, *,
                        hash_backend, max_hops, flow_demand):
     """One device pass per seed chunk: walk + demand-weighted counts.
-    Returns the host (S, L) count matrix (small: seeds x links)."""
+    Yields ``(s1 - s0, counts)`` per chunk, ``counts`` the device
+    ``(Sc, L)`` count matrix whose first ``s1 - s0`` rows are real."""
     jax = _jx()[0]
-    L = comp.num_links
-    out = np.empty((len(seeds_u64), L))
     for s0, s1, ids in _walked_chunks(comp, flows, endpoints, field_mat,
                                       seeds_u64, hash_backend=hash_backend,
                                       max_hops=max_hops):
         with span("counts.run"):
             counts = jax.block_until_ready(
-                jax_link_flow_counts(ids, flow_demand, L))
-        with span("counts.to_host", bytes=counts.nbytes):
-            out[s0:s1] = np.asarray(counts)[: s1 - s0]
-    return out
+                jax_link_flow_counts(ids, flow_demand, comp.num_links))
+        yield s1 - s0, counts
 
 
 def _fused_prep(fabric, workload, seeds, fields, field_matrix, demand_mode):
@@ -870,12 +909,12 @@ def fused_monte_carlo_fim(
     comp, flows, seeds_u64, flow_demand, field_mat, endpoints = _fused_prep(
         fabric, workload, seeds, fields, field_matrix, demand_mode)
     with _x64():
-        counts = _fused_walk_counts(
+        chunks = _fused_walk_counts(
             comp, flows, endpoints, field_mat, seeds_u64,
             hash_backend=hash_backend, max_hops=max_hops,
             flow_demand=flow_demand)
-        agg, per_layer = jax_fim_from_counts(
-            counts, comp, layers=layers, only_used_leaves=only_used_leaves)
+        agg, per_layer = _fim_chunks(
+            comp, chunks, layers=layers, only_used_leaves=only_used_leaves)
     with span("assemble"):
         return MonteCarloFim(seeds=seeds_u64, aggregate=agg,
                              per_layer=per_layer)

@@ -271,9 +271,88 @@ def test_fused_padded_last_chunk_parity(paper8, monkeypatch):
     assert np.abs(a.rates - b.rates).max() < 1e-6
     assert jax_engine._walk_fn()._cache_size() - walk0 <= 1
     assert jax_engine._fill_fn()._cache_size() - fill0 <= 1
-    fa = monte_carlo_fim(comp, flows, seeds)
-    fb = monte_carlo_fim(comp, flows, seeds, engine=ENGINE_JAX)
-    assert np.abs(fa.aggregate - fb.aggregate).max() < 1e-6
+    for kw in ({}, {"demand_mode": "bytes", "only_used_leaves": True}):
+        fim0 = jax_engine._fim_fn()._cache_size()
+        fa = monte_carlo_fim(comp, flows, seeds, **kw)
+        fb = monte_carlo_fim(comp, flows, seeds, engine=ENGINE_JAX, **kw)
+        assert jax_engine._fim_fn()._cache_size() - fim0 <= 1
+        assert np.abs(fa.aggregate - fb.aggregate).max() < 1e-6
+        assert sorted(fa.per_layer) == sorted(fb.per_layer)
+        for layer in fa.per_layer:
+            assert np.abs(fa.per_layer[layer]
+                          - fb.per_layer[layer]).max() < 1e-6
+
+
+def test_fim_keeps_layers_by_real_seeds_only(paper8):
+    """A layer live only in a chunk's padding rows is dropped, and the
+    padding's answers are sliced off; a host count matrix and a device
+    one give the numpy engine's answers."""
+    import jax
+    import jax.numpy as jnp
+    from repro.core.vector_sim import fim_from_counts
+
+    comp, _ = paper8
+    rng = np.random.default_rng(5)
+    counts = rng.integers(0, 4, (8, comp.num_links)).astype(np.float64)
+    dead = comp.layer_names[0]
+    counts[:5, comp.link_layer == 0] = 0.0     # dead in the 5 real seeds
+    with jax.enable_x64(True):
+        agg, per_layer = jax_engine._fim_chunks(
+            comp, [(5, jnp.asarray(counts))], layers=None,
+            only_used_leaves=False)
+    ref_agg, ref = fim_from_counts(counts[:5], comp)
+    assert dead not in ref and sorted(per_layer) == sorted(ref)
+    assert np.abs(agg - ref_agg).max() < 1e-12
+    for layer in ref:
+        assert np.abs(per_layer[layer] - ref[layer]).max() < 1e-12
+
+    ref_agg, ref = fim_from_counts(counts, comp, only_used_leaves=True)
+    for given in (counts, jnp.asarray(counts)):
+        agg, per_layer = jax_engine.jax_fim_from_counts(
+            given, comp, only_used_leaves=True)
+        assert isinstance(agg, np.ndarray) and sorted(per_layer) == sorted(ref)
+        assert np.abs(agg - ref_agg).max() < 1e-12
+
+
+def test_fused_walk_names_the_flow_that_did_not_arrive(monkeypatch):
+    """With spine-0's links to leaf-2 cut, a flow into leaf-2 that hashes
+    onto spine-0 ends there.  The fused front ends check arrival on the
+    device and raise the numpy engine's error: flow id, the seed's index
+    in the whole sweep (here in the padded second chunk) and the device
+    the flow ended at."""
+    from repro.core.fabric import Fabric
+
+    fab = build_paper_testbed()
+    comp = compile_fabric(Fabric(
+        list(fab.devices.values()),
+        [ln for ln in fab.links
+         if (ln.src, ln.dst) != ("spine-0", "leaf-2")]))
+    flows = bipartite_pairs([server_name(0)], [server_name(8)],
+                            flows_per_pair=1)
+    stranded = [s for s in range(64)
+                if _strands(comp, flows, np.array([s]))]
+    assert stranded and len(stranded) < 64
+    ok = next(s for s in range(64) if s not in stranded)
+    seeds = np.full(200, ok)
+    seeds[150] = stranded[0]
+    per_seed = 2 * 16 * (jax_engine._WALK_BYTES_PER_HOP
+                         + jax_engine._FILL_BYTES_PER_CELL)
+    monkeypatch.setattr(jax_engine, "_CHUNK_BYTES", per_seed * 128)
+    want = "flow 0 (seed index 150) terminated at spine-0"
+    assert _strands(comp, flows, seeds) == want
+    for fn in (monte_carlo_fim, monte_carlo_throughput):
+        with pytest.raises(RuntimeError) as err:
+            fn(comp, flows, seeds, engine=ENGINE_JAX)
+        assert str(err.value) == want
+
+
+def _strands(comp, flows, seeds):
+    """The numpy engine's arrival error for ``seeds``, or None."""
+    try:
+        monte_carlo_fim(comp, flows, seeds)
+    except RuntimeError as e:
+        return str(e)
+    return None
 
 
 def test_compile_cache_placement(monkeypatch):

@@ -30,8 +30,7 @@ STAGES = {
         "fill.run", "fill.to_host", "assemble"],
     "monte_carlo_fim": [
         "prep", "walk.to_device", "walk.run", "walk.to_host",
-        "counts.run", "counts.to_host",
-        "fim.to_device", "fim.run", "fim.to_host", "assemble"],
+        "counts.run", "fim.run", "fim.to_host", "assemble"],
 }
 FRONT = {"monte_carlo_throughput": monte_carlo_throughput,
          "monte_carlo_fim": monte_carlo_fim}
@@ -108,26 +107,31 @@ def test_self_times_add_up_to_the_front_end_span(sweep, tmp_path, front):
 
 
 def test_copy_spans_count_the_bytes_of_their_shapes(sweep, tmp_path):
+    """Inputs in, answers out: the walk pulls its arrival check's one
+    bool, the FIM its (1 + NL, S) answers and (NL,) live flags, and the
+    FIM's layer tables go up once per compiled fabric, on its first
+    call; the (S, L) count matrix never crosses."""
     comp, flows = sweep
     N, S, L = len(flows), len(SEEDS), comp.num_links
-    walk = {"walk.to_device": 3 * N * 4 + N * FIELDS * 8 + S * 8,
-            "walk.to_host": N * S * 4}
+    walk = {"walk.to_device": 4 * N * 4 + N * FIELDS * 8 + S * 8,
+            "walk.to_host": 1}
     _, table = traced(tmp_path / "tp", monte_carlo_throughput, comp, flows,
                       SEEDS, engine="jax")
     got = {k: v["bytes"] for k, v in table.items() if "bytes" in v}
     assert got == {**walk, "fill.to_host": N * S * 8}
 
-    layers = [i for i in range(len(comp.layer_names))
-              if (comp.link_layer == i).any()]
-    fim, table = traced(tmp_path / "fim", monte_carlo_fim, comp, flows,
-                        SEEDS, engine="jax")
+    NL = sum((comp.link_layer == i).any()
+             for i in range(len(comp.layer_names)))
+    fim_out = {"fim.to_host": (1 + NL) * S * 8 + NL}
+    fresh = compile_fabric(build_paper_testbed())
+    _, table = traced(tmp_path / "fim0", monte_carlo_fim, fresh, flows,
+                      SEEDS, engine="jax")
     got = {k: v["bytes"] for k, v in table.items() if "bytes" in v}
-    assert got == {
-        **walk,
-        "counts.to_host": S * L * 8,
-        "fim.to_device": S * L * 8 + len(layers) * L + 2 * L * 4,
-        "fim.to_host": S * 8 + len(layers) * S + len(fim.per_layer) * S * 8,
-    }
+    assert got == {**walk, **fim_out, "fim.to_device": NL * L + 2 * L * 4}
+    _, table = traced(tmp_path / "fim1", monte_carlo_fim, fresh, flows,
+                      SEEDS, engine="jax")
+    got = {k: v["bytes"] for k, v in table.items() if "bytes" in v}
+    assert got == {**walk, **fim_out}
 
 
 # one link shared by three flows freezes them all in one round; a flow

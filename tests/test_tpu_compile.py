@@ -102,6 +102,18 @@ def test_counts_compiles_for_v5e(chip, comp):
             chip((FLOWS,), jnp.float64), L=comp.num_links))
 
 
+@pytest.mark.parametrize("only_used_leaves", [False, True])
+def test_fim_compiles_for_v5e(chip, comp, only_used_leaves):
+    seeds = 16384                       # the paper-ecmp-fim chunk
+    L, NL = comp.num_links, len(comp.layer_names)
+    with jax.enable_x64(True):
+        _compiles(je._fim_fn().lower(
+            chip((seeds, L), jnp.float64), chip((NL, L), jnp.bool_),
+            chip((L,), jnp.int32), chip((L,), jnp.int32),
+            chip((), jnp.int64), only_used_leaves=only_used_leaves,
+            num_devices=comp.num_devices))
+
+
 def test_fill_compiles_for_v5e(chip, comp):
     with jax.enable_x64(True):
         compiled = _compiles(je._fill_fn().lower(
