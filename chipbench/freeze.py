@@ -1,20 +1,21 @@
 """Freeze each configuration's inputs into data files.
 
-    PYTHONPATH=src python chipbench/freeze.py [--check]
+    python chipbench/freeze.py [--check]
 
-Writes ``configs/<config>/fabric.json`` and ``flows.json`` from the
-program's own builders, once.  The
-benchmark then rebuilds every input from these files alone, so a later
-change to a builder (``build_paper_testbed``, ``bipartite_pairs``,
-``synthesize_flows``) cannot move the
-yardstick.  ``--check`` rewrites nothing and exits 1 if a committed file
-differs from what the builders give now.
+Every configuration is a directory ``configs/<config>/`` with its
+``config.json`` and a ``build.py`` whose ``fabric()`` and ``flows()``
+call the program's public builders.  This writes ``fabric.json`` and
+``flows.json`` beside them, once.  The benchmark then rebuilds every
+input from these files alone, so a later change to a builder cannot
+move the yardstick.  ``--check`` rewrites nothing and exits 1 if a
+committed file differs from what the builders give now.
 """
 
 from __future__ import annotations
 
 import argparse
 import dataclasses
+import importlib.util
 import json
 import sys
 from pathlib import Path
@@ -44,25 +45,25 @@ def flows_json(flows) -> str:
     return "{" + _lines("flows", rows) + "}\n"
 
 
-def frozen() -> dict[str, dict[str, str]]:
-    """{config name: {file name: text}} from this tree's builders."""
-    from repro.core import (
-        bipartite_pairs, build_paper_testbed, nic_ip, server_name,
-        synthesize_flows,
-    )
+def builder(config_dir: Path):
+    """The configuration's ``build.py`` as a module."""
+    path = config_dir / "build.py"
+    spec = importlib.util.spec_from_file_location(
+        f"chipbench_build_{config_dir.name}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
-    # the paper's Fig. 2b pattern: server i of rack 0 <-> server i of
-    # rack 1, both directions, 16 flows per directed pair
-    wl = bipartite_pairs([server_name(i) for i in range(8)],
-                         [server_name(8 + i) for i in range(8)],
-                         flows_per_pair=16)
-    paper = synthesize_flows(wl, nic_ip=nic_ip, nics_per_server=2)
-    return {
-        "paper-testbed": {
-            "fabric.json": fabric_json(build_paper_testbed()),
-            "flows.json": flows_json(paper),
-        },
-    }
+
+def frozen() -> dict[str, dict[str, str]]:
+    """{config name: {file name: text}} from this tree's builders, for
+    every ``configs/*/build.py``."""
+    out = {}
+    for path in sorted(CONFIGS.glob("*/build.py")):
+        mod = builder(path.parent)
+        out[path.parent.name] = {"fabric.json": fabric_json(mod.fabric()),
+                                 "flows.json": flows_json(mod.flows())}
+    return out
 
 
 def main(argv=None) -> int:
@@ -70,6 +71,9 @@ def main(argv=None) -> int:
     ap.add_argument("--check", action="store_true",
                     help="compare with the committed files, write nothing")
     args = ap.parse_args(argv)
+    src = str(HERE.parent / "src")              # the program's builders
+    if src not in sys.path:
+        sys.path.insert(0, src)
     stale = []
     for config, files in frozen().items():
         for name, text in files.items():

@@ -97,31 +97,17 @@ class Family:
 
     def table_bytes(self) -> int:
         """Bytes of the forwarding tables the walk reads: candidate
-        links and counts per (device, NIC key), per-device hash salt and
-        server flag, per-link destination."""
+        links and counts per (device, NIC), per-device hash salt and
+        server flag, per-link destination.  The candidates are the
+        reference's own, padded to the largest set."""
         topo = self.topo
-        keys = {(ln["src"], ln["src_port"].split("p")[0])
-                for ln in topo.links
-                if topo.kind[ln["src"]] == "server"
-                and ln["src_port"].startswith("nic")}
-        V, K, L = len(topo.names), len(keys), topo.num_links
-        egress: dict[tuple[str, str], int] = {}
-        for ln in topo.links:
-            src, dst = ln["src"], ln["dst"]
-            if topo.kind[src] == "server":       # the ports of one NIC
-                k = (src, ln["src_port"].split("p")[0])
-            elif topo.kind[dst] == "spine":      # all of a leaf's uplinks
-                k = (src, "spine")
-            else:
-                k = (src, dst)
-            egress[k] = egress.get(k, 0) + 1
-        C = max(egress.values())
+        V, K, L = len(topo.names), len(topo.nics), topo.num_links
+        C = topo.largest_fanout()
         return V * K * C * 4 + V * K * 4 + V * 8 + V + L * 4
 
     def hops(self, seeds: np.ndarray) -> int:
         """Real hop count of a call: the longest reference path."""
-        paths = ref.route(self.topo, self.flows_json, seeds[:256])
-        return max(p.shape[0] for p in paths)
+        return ref.route(self.topo, self.flows_json, seeds[:256]).shape[0]
 
 
 class Throughput(Family):

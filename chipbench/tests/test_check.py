@@ -23,7 +23,8 @@ from chipbench import run as R
 
 BENCH = json.loads((R.ROOT / "BENCHMARK.json").read_text())
 CELLS = [w["name"] for w in BENCH["workloads"]]
-SEEDS_PER_CALL = {"paper-ecmp-throughput": 64, "paper-ecmp-fim": 256}
+#: hash seeds per call at CPU size, by front end
+SEEDS_PER_CALL = {"monte_carlo_throughput": 64, "monte_carlo_fim": 256}
 #: float32 for float64 in the fill; the FIM of uniform flows is exact in
 #: float32 (small integer counts, power-of-two layer sizes), so its
 #: control is the next step down, bfloat16
@@ -44,7 +45,7 @@ def small(monkeypatch):
     class Small(R.Cell):
         def __init__(self, bench, name):
             super().__init__(bench, name)
-            n = SEEDS_PER_CALL[name]
+            n = SEEDS_PER_CALL[self.traffic["front_end"]]
             check = dict(self.traffic["check"],
                          seeds=min(n, self.traffic["check"]["seeds"]))
             self.traffic = dict(self.traffic, seeds_per_call=n,

@@ -1,5 +1,5 @@
-"""The frozen inputs: reproduced by this tree's builders byte for byte,
-and enough on their own to build each cell's program inputs."""
+"""The frozen inputs: reproduced by each configuration's builder byte for
+byte, and enough on their own to build each cell's program inputs."""
 
 from __future__ import annotations
 
@@ -21,26 +21,26 @@ def test_freeze_reproduces_committed_files():
                 f"{config}/{name} differs from the builders")
 
 
+@pytest.mark.parametrize("config", [c["name"] for c in BENCH["configs"]])
+def test_every_configuration_is_a_directory_of_its_own(config):
+    entry = next(c for c in BENCH["configs"] if c["name"] == config)
+    path = HERE / "configs" / config
+    assert (HERE.parent / entry["file"]) == path / "config.json"
+    assert {"config.json", "build.py", "fabric.json", "flows.json"} <= {
+        p.name for p in path.iterdir()}
+    assert config in freeze.frozen()
+
+
 @pytest.mark.parametrize("cell", [w["name"] for w in BENCH["workloads"]])
 def test_cell_inputs_build_from_data_alone(cell):
-    from repro.core import (
-        bipartite_pairs, build_paper_testbed, nic_ip, server_name,
-        synthesize_flows,
-    )
-
     entry = next(w for w in BENCH["workloads"] if w["name"] == cell)
     traffic = json.loads(
         (HERE / "traffic" / f"{entry['traffic']}.json").read_text())
-    fam = frontends.family(HERE / "configs" / entry["config"],
-                           traffic).build()
-    assert entry["config"] == "paper-testbed"
-    fabric = build_paper_testbed()
-    flows = synthesize_flows(
-        bipartite_pairs([server_name(i) for i in range(8)],
-                        [server_name(8 + i) for i in range(8)],
-                        flows_per_pair=16),
-        nic_ip=nic_ip, nics_per_server=2)
+    config_dir = HERE / "configs" / entry["config"]
+    fam = frontends.family(config_dir, traffic).build()
+    build = freeze.builder(config_dir)
+    fabric = build.fabric()
     assert fam.comp.links == fabric.links
     assert list(fam.comp.fabric.devices.values()) == list(
         fabric.devices.values())
-    assert fam.flows == flows
+    assert fam.flows == build.flows()

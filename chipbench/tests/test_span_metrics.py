@@ -24,24 +24,23 @@ def listed(metric: str) -> set[str]:
 
 
 def exact_mb(cell: str) -> float:
-    """Bytes copied per call at the test size, from the shapes: flow
-    endpoints (int32), hash fields and seeds (uint64) in; the walk's
-    (N, S) int32 state out; then the (N, S) float64 rates out, or the
-    (S, L) float64 counts out and back in with the layer tables, and the
-    FIM's (S,) float64 aggregate, per-layer (S,) live flags and (S,)
-    float64 MAPE of every live layer out."""
+    """Bytes copied per traced call at the test size, from the shapes:
+    the walk's source devices and source and destination NICs (int32),
+    hash fields and seeds (uint64) and destination devices (int32) in,
+    and its arrival check's one bool out; then the (N, S) float64 rates
+    out, or the FIM's (1 + NL, S) float64 answers and (NL,) live flags
+    out in one pull.  The FIM's layer tables go up on a fabric's first
+    call only, the warm-up call."""
     traffic = R.Cell(BENCH, cell).traffic
     fam = frontends.family(R.Cell(BENCH, cell).config_dir, traffic).build()
     comp = fam.comp
-    N, S, L = len(fam.flows), SEEDS_PER_CALL[cell], comp.num_links
-    walk = 3 * N * 4 + N * FIELDS * 8 + S * 8 + N * S * 4
+    N, S = len(fam.flows), SEEDS_PER_CALL[traffic["front_end"]]
+    walk = 3 * N * 4 + N * FIELDS * 8 + S * 8 + N * 4 + 1
     if traffic["front_end"] == "monte_carlo_throughput":
         return (walk + N * S * 8) / 1e6
     layers = sum((comp.link_layer == i).any()
                  for i in range(len(comp.layer_names)))
-    fim = (S * L * 8 + layers * L + 2 * L * 4
-           + S * 8 + layers * S + layers * S * 8)
-    return (walk + S * L * 8 + fim) / 1e6
+    return (walk + (1 + layers) * S * 8 + layers) / 1e6
 
 
 @pytest.mark.usefixtures("small")
