@@ -10,8 +10,9 @@ front ends) imports jax lazily, only when actually asked to run.
 
 from .fabric import (
     Fabric, Link, Device, build_paper_testbed, build_multipod_fabric,
-    nic_ip, server_name,
+    build_three_tier_clos, nic_ip, server_name,
     HOST_TO_LEAF, LEAF_TO_SPINE, SPINE_TO_LEAF, LEAF_TO_HOST,
+    SPINE_TO_AGG, AGG_TO_SPINE,
 )
 from .flows import (
     Flow, FiveTuple, PairSpec, WorkloadDescription, synthesize_flows,
@@ -82,8 +83,9 @@ from .report import analyze_paths, PathReport
 
 __all__ = [
     "Fabric", "Link", "Device", "build_paper_testbed", "build_multipod_fabric",
-    "nic_ip", "server_name",
+    "build_three_tier_clos", "nic_ip", "server_name",
     "HOST_TO_LEAF", "LEAF_TO_SPINE", "SPINE_TO_LEAF", "LEAF_TO_HOST",
+    "SPINE_TO_AGG", "AGG_TO_SPINE",
     "Flow", "FiveTuple", "PairSpec", "WorkloadDescription", "synthesize_flows",
     "bipartite_pairs", "workload_from_flows",
     "EcmpRouting", "StaticRouting", "RoutingPolicy", "Forwarder", "ecmp_hash",

@@ -12,13 +12,15 @@ with whole arrays:
 * the equal-cost candidate set at ``(device, flow)`` depends only on the
   device and one *NIC key* — the flow's **src** (server, nic) while the
   packet is on the source host, its **dst** (server, nic) everywhere else
-  (Clos forwarding is destination-routed).  So all candidate sets live in
-  one padded ``(V, K, C_max)`` table of link ids, built by calling the
-  real ``Forwarder`` per (device, key) so candidate *order* — which the
+  (forwarding is destination-routed).  So all candidate sets live in
+  one padded ``(V, K, C_max)`` table of link ids, filled from the real
+  ``Forwarder``'s shortest-path sets so candidate *order* — which the
   hash indexes into — is identical to the Python path by construction.
 
-Compilation is O(V*K) and done once per fabric; every simulated flow and
-seed afterwards is pure array indexing.
+The rows above a NIC's own switches are one backward search per set of
+attachment switches, shared by every NIC behind them and written into
+the table for all of those NICs at once; compilation runs in the
+``compile_fabric`` span.
 """
 
 from __future__ import annotations
@@ -29,7 +31,7 @@ import numpy as np
 
 from .ecmp import Forwarder, _crc
 from .fabric import Fabric, Link, SERVER, nic_ip
-from .flows import FiveTuple, Flow
+from .spans import count, span
 
 
 @dataclasses.dataclass(frozen=True)
@@ -83,6 +85,16 @@ class CompiledFabric:
 
 
 def compile_fabric(fabric: Fabric) -> CompiledFabric:
+    with span("compile_fabric"):
+        comp = _compile(fabric)
+        count("devices", comp.num_devices)
+        count("links", comp.num_links)
+        count("keys", len(comp.key_of_ip))
+        count("table_bytes", comp.cand.nbytes + comp.cand_n.nbytes)
+    return comp
+
+
+def _compile(fabric: Fabric) -> CompiledFabric:
     fwd = Forwarder(fabric)
     device_names = list(fabric.devices)
     device_id = {name: i for i, name in enumerate(device_names)}
@@ -100,40 +112,41 @@ def compile_fabric(fabric: Fabric) -> CompiledFabric:
     link_gbps = np.array([ln.gbps for ln in links], np.float64)
 
     # NIC keys, in deterministic (server name, nic index) order.
-    nic_keys = sorted(fwd._server_nic_links)
+    nic_keys = sorted(fwd.nic_links)
     key_of_ip = {nic_ip(srv, nic): k for k, (srv, nic) in enumerate(nic_keys)}
     key_server = np.array(
         [device_id[srv] for srv, _ in nic_keys], np.int32)
 
-    # Candidate table: ask the real Forwarder per (device, key) so both the
-    # membership and the order of every equal-cost set match the tracer.
-    V, K = len(device_names), len(nic_keys)
-    per_cell: list[list[list[int]]] = [[[] for _ in range(K)] for _ in range(V)]
-    c_max = 1
-    for k, (srv, nic) in enumerate(nic_keys):
-        ip = nic_ip(srv, nic)
-        probe = Flow(flow_id=-1, src=srv, dst=srv,
-                     tuple5=FiveTuple(ip, ip, 0, 0))
-        for v, dev in enumerate(device_names):
-            if is_server[v]:
-                # Only the flow's own source host ever forwards on src key.
-                if dev != srv:
-                    continue
-                cands = fwd.candidates(dev, probe)
-            else:
-                cands = fwd.candidates(dev, probe)  # dst-keyed at switches
-            ids = [link_id[c.name] for c in cands]
-            per_cell[v][k] = ids
-            c_max = max(c_max, len(ids))
+    # Candidate table: the source host's row of a key is its NIC's ports;
+    # a switch's row is its shortest-path set toward the key's NIC, taken
+    # from one search per set of attachment switches.
+    def ids(cands: list[Link]) -> list[int]:
+        return [link_id[c.name] for c in cands]
 
+    attach = [fwd.attachment(key) for key in nic_keys]
+    groups: dict[frozenset[str], list[int]] = {}
+    for k, onto in enumerate(attach):
+        groups.setdefault(frozenset(onto), []).append(k)
+    upstream = {first: fwd.upstream(first) for first in groups}
+    c_max = max([1, *map(len, fwd.nic_links.values()), *(
+        len(c) for rows in [*upstream.values(), *attach]
+        for c in rows.values())])
+
+    V, K = len(device_names), len(nic_keys)
     cand = np.full((V, K, c_max), -1, np.int32)
     cand_n = np.zeros((V, K), np.int32)
-    for v in range(V):
-        for k in range(K):
-            ids = per_cell[v][k]
-            cand_n[v, k] = len(ids)
-            if ids:
-                cand[v, k, : len(ids)] = ids
+    for first, keys in groups.items():        # shared by the group's keys
+        rows = np.full((V, c_max), -1, np.int32)
+        n = np.zeros(V, np.int32)
+        for v, c in upstream[first].items():
+            rows[device_id[v], : len(c)] = ids(c)
+            n[device_id[v]] = len(c)
+        cand[:, keys] = rows[:, None]
+        cand_n[:, keys] = n[:, None]
+    for k, key in enumerate(nic_keys):
+        for v, c in [*attach[k].items(), (key[0], fwd.nic_links[key])]:
+            cand[device_id[v], k, : len(c)] = ids(c)
+            cand_n[device_id[v], k] = len(c)
 
     return CompiledFabric(
         fabric=fabric,

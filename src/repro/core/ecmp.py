@@ -16,9 +16,10 @@ from __future__ import annotations
 
 import dataclasses
 import zlib
+from collections import defaultdict
 from collections.abc import Sequence
 
-from .fabric import Fabric, Link, LEAF, SERVER, SPINE
+from .fabric import Fabric, Link, SERVER, host_of_nic_ip, port_nic
 from .flows import Flow
 
 _MASK = (1 << 64) - 1
@@ -96,64 +97,111 @@ def flow_fields_matrix(flows: Sequence[Flow], mode: str):
 
 
 class Forwarder:
-    """Computes the equal-cost candidate egress set at each device.
+    """Computes the equal-cost candidate egress set at each device:
+    shortest-path ECMP over the topology file, for any number of tiers.
 
-    This encodes the L3 Clos forwarding logic shared by both policies:
-      * server:  LAG over the ports of the NIC owning the flow's src ip;
-      * leaf:    if the dst NIC is locally attached -> LAG down to it,
-                 otherwise ECMP over all uplinks (any spine reaches any leaf);
-      * spine:   ECMP over the links to the leaf behind the dst NIC.
+      * a host (kind ``server``) hashes over the ports of the NIC that
+        owns the flow's source address;
+      * any other device hashes over its egress links that lie on a
+        shortest path to the destination NIC: a path ends on one of that
+        NIC's ports (``nic<k>p<m>``) and never passes through a host.
+
+    Every set is ordered by (far device name, egress port name), the
+    order the hash indexes into.  On a two-tier Clos this is the familiar
+    rule (a leaf goes down to an attached destination NIC and otherwise
+    hashes over its spine uplinks; a spine takes its links to the
+    destination's leaf); tiers, planes and failed links follow from it.
+    The shortest paths toward a NIC come from one backward breadth-first
+    search per set of switches the NIC is attached to, made when first
+    needed and kept.
     """
 
     def __init__(self, fabric: Fabric):
         self.fabric = fabric
-        # dst_ip -> (server, nic index) -> attachment leaf + ports.
-        self._ip_attach: dict[str, tuple[str, str, list[Link]]] = {}
-        self._server_nic_links: dict[tuple[str, int], list[Link]] = {}
+        self._server_of_index: dict[int, str] = {}
+        nic_links: dict[tuple[str, int], list[Link]] = {}
+        self._ingress: dict[str, list[Link]] = defaultdict(list)
         for ln in fabric.links:
-            if fabric.kind(ln.src) == SERVER and ln.src_port.startswith("nic"):
-                nic = int(ln.src_port[3 : ln.src_port.index("p")])
-                self._server_nic_links.setdefault((ln.src, nic), []).append(ln)
+            self._ingress[ln.dst].append(ln)
+            nic = port_nic(ln.src_port)
+            if fabric.kind(ln.src) == SERVER and nic is not None:
+                nic_links.setdefault((ln.src, nic), []).append(ln)
+        for name, dev in fabric.devices.items():
+            suffix = name.rsplit("-", 1)[-1]
+            if dev.kind == SERVER and suffix.isdigit():
+                self._server_of_index[int(suffix)] = name
+        #: {(server, nic index): the NIC's egress links, in hash order}
+        self.nic_links = {k: _hash_order(v) for k, v in nic_links.items()}
+        self._toward: dict[tuple[str, int], dict[str, list[Link]]] = {}
+        self._up: dict[frozenset[str], dict[str, list[Link]]] = {}
 
     def _nic_of_ip(self, ip: str) -> tuple[str, int]:
-        # 10.<nic>.<hi>.<lo> (fabric.nic_ip) — server index from last octets.
-        parts = ip.split(".")
-        nic = int(parts[1])
-        idx = int(parts[2]) * 256 + int(parts[3])
-        for prefix in ("srv-", "host-"):
-            name = f"{prefix}{idx}"
-            if name in self.fabric.devices:
-                return name, nic
-        raise KeyError(f"no server for ip {ip}")
+        """(server, nic index) owning ``ip`` (``fabric.nic_ip``'s plan)."""
+        idx, nic = host_of_nic_ip(ip)
+        if idx not in self._server_of_index:
+            raise KeyError(f"no server for ip {ip}")
+        return self._server_of_index[idx], nic
 
-    def attachment_leaf(self, ip: str) -> str:
-        server, nic = self._nic_of_ip(ip)
-        links = self._server_nic_links[(server, nic)]
-        return links[0].dst  # both LAG ports land on the same leaf
+    def attachment(self, nic: tuple[str, int]) -> dict[str, list[Link]]:
+        """{switch: its links onto the ports of ``nic``, in hash order}."""
+        server, k = nic
+        onto: dict[str, list[Link]] = {}
+        for ln in self._ingress[server]:
+            if (port_nic(ln.dst_port) == k
+                    and self.fabric.kind(ln.src) != SERVER):
+                onto.setdefault(ln.src, []).append(ln)
+        return {v: _hash_order(links) for v, links in onto.items()}
+
+    def upstream(self, first: frozenset[str]) -> dict[str, list[Link]]:
+        """{switch: its egress links on a shortest path to any of the
+        switches ``first``, in hash order}, for the switches outside
+        ``first``: a backward breadth-first search over the links into
+        each device that never passes through a host.  Every NIC behind
+        the same switches shares it."""
+        hit = self._up.get(first)
+        if hit is not None:
+            return hit
+        fab = self.fabric
+        dist = dict.fromkeys(first, 0)
+        cands: dict[str, list[Link]] = {}
+        frontier, d = sorted(first), 0
+        while frontier:
+            nxt = []
+            for u in frontier:
+                for ln in self._ingress[u]:
+                    w = ln.src
+                    if fab.kind(w) == SERVER:
+                        continue
+                    if w not in dist:
+                        dist[w] = d + 1
+                        nxt.append(w)
+                    if dist[w] == d + 1:
+                        cands.setdefault(w, []).append(ln)
+            frontier, d = nxt, d + 1
+        out = {v: _hash_order(links) for v, links in cands.items()}
+        self._up[first] = out
+        return out
+
+    def toward(self, nic: tuple[str, int]) -> dict[str, list[Link]]:
+        """{switch: its egress links on a shortest path to ``nic``, in
+        hash order}."""
+        hit = self._toward.get(nic)
+        if hit is None:
+            onto = self.attachment(nic)
+            hit = {**self.upstream(frozenset(onto)), **onto}
+            self._toward[nic] = hit
+        return hit
 
     def candidates(self, device: str, flow: Flow) -> list[Link]:
-        fab = self.fabric
-        kind = fab.kind(device)
-        if kind == SERVER:
+        if self.fabric.kind(device) == SERVER:
             server, nic = self._nic_of_ip(flow.tuple5.src_ip)
             assert server == device, (server, device, "flow must start at src")
-            return sorted(self._server_nic_links[(device, nic)],
-                          key=lambda l: l.src_port)
-        dst_server, dst_nic = self._nic_of_ip(flow.tuple5.dst_ip)
-        dst_leaf = self.attachment_leaf(flow.tuple5.dst_ip)
-        if kind == LEAF:
-            if device == dst_leaf:  # LAG down to the dst NIC's ports
-                down = [
-                    l for l in fab.links_between(device, dst_server)
-                    if l.dst_port.startswith(f"nic{dst_nic}p")
-                ]
-                return sorted(down, key=lambda l: l.src_port)
-            ups = [l for l in fab.egress_links(device) if fab.kind(l.dst) == SPINE]
-            return sorted(ups, key=lambda l: (l.dst, l.src_port))
-        if kind == SPINE:
-            downs = fab.links_between(device, dst_leaf)
-            return sorted(downs, key=lambda l: l.src_port)
-        raise ValueError(f"unknown device kind {kind}")
+            return self.nic_links.get((device, nic), [])
+        return self.toward(self._nic_of_ip(flow.tuple5.dst_ip)).get(device, [])
+
+
+def _hash_order(links: list[Link]) -> list[Link]:
+    return sorted(links, key=lambda ln: (ln.dst, ln.src_port))
 
 
 # ---------------------------------------------------------------------------

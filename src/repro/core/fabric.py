@@ -5,7 +5,7 @@ every device, every interface, and how interfaces connect.  The tracer uses
 it to map the egress interface reported by one device to the ingress
 interface of the next.
 
-Two families of fabrics are modeled:
+Three families of fabrics are modeled:
 
 * ``build_paper_testbed`` — the paper's 2-rack RoCEv2 cluster: 16 servers
   (2 dual-port 100G NICs each, one NIC per ToR), 4 leaf switches
@@ -15,6 +15,12 @@ Two families of fabrics are modeled:
   inter-pod (DCN) traffic crosses an Ethernet leaf-spine Clos with ECMP,
   which is exactly the regime the paper studies.  Intra-pod ICI links are
   modeled separately with deterministic routing (no hash decisions).
+* ``build_three_tier_clos`` — a RoCE training cluster of three switch
+  tiers (Meta's 24K-GPU fabric, arXiv 2407.21783 §3.3.1): racks of
+  multi-NIC servers under one ToR each, cluster switches joining a
+  pod's ToRs, and aggregation switches in one plane per cluster-switch
+  index joining the pods, oversubscribed by the cluster switches'
+  down : up ratio.
 """
 
 from __future__ import annotations
@@ -26,12 +32,15 @@ from collections.abc import Sequence
 SERVER = "server"
 LEAF = "leaf"
 SPINE = "spine"
+AGG = "aggregation"
 
 # Link layers used for FIM grouping (paper Fig. 3(b,c) subplots).
 HOST_TO_LEAF = "host-to-leaf"
 LEAF_TO_SPINE = "leaf-to-spine"
 SPINE_TO_LEAF = "spine-to-leaf"
 LEAF_TO_HOST = "leaf-to-host"
+SPINE_TO_AGG = "spine-to-agg"
+AGG_TO_SPINE = "agg-to-spine"
 
 
 @dataclasses.dataclass(frozen=True, slots=True)
@@ -53,7 +62,7 @@ class Link:
 @dataclasses.dataclass(frozen=True, slots=True)
 class Device:
     name: str
-    kind: str  # server | leaf | spine
+    kind: str  # server | leaf | spine | aggregation
     rack: int | None = None
     pod: int | None = None
 
@@ -247,7 +256,83 @@ def build_multipod_fabric(
     return Fabric(devices, links)
 
 
+# ---------------------------------------------------------------------------
+# Three-tier RoCE Clos (pods of ToRs, cluster switches, aggregation planes)
+# ---------------------------------------------------------------------------
+
+def build_three_tier_clos(
+    *,
+    num_pods: int = 2,
+    racks_per_pod: int = 4,
+    servers_per_rack: int = 2,
+    nics_per_server: int = 8,
+    cluster_switches: int = 4,
+    aggs_per_plane: int = 2,
+    uplinks: int = 2,
+    link_gbps: float = 400.0,
+) -> Fabric:
+    """A three-tier Clos: ToRs (kind ``leaf``), cluster switches (kind
+    ``spine``) and aggregation switches (kind ``aggregation``).
+
+    Each rack's servers (``srv-<i>``, numbered across pods) have
+    ``nics_per_server`` single-port NICs, all on the rack's one ToR
+    ``rtsw-<r>``.  Every ToR of a pod has one link to each of the pod's
+    ``cluster_switches`` (``ctsw-<pod>-<j>``).  Cluster switch ``j`` of
+    every pod has ``uplinks`` links into aggregation plane ``j``, spread
+    evenly over its ``aggs_per_plane`` switches (``atsw-<j>-<a>``), so
+    it is ``racks_per_pod : uplinks`` oversubscribed.  Every link is
+    ``link_gbps``; each cable is a pair of unidirectional links.
+    """
+    if uplinks % aggs_per_plane:
+        raise ValueError(f"{uplinks} uplinks do not spread evenly over "
+                         f"{aggs_per_plane} aggregation switches")
+    per_agg = uplinks // aggs_per_plane
+    devices: list[Device] = []
+    links: list[Link] = []
+
+    def cable(a, a_port, b, b_port, up, down):
+        links.append(Link(a, a_port, b, b_port, link_gbps, up))
+        links.append(Link(b, b_port, a, a_port, link_gbps, down))
+
+    atsws = [[f"atsw-{j}-{a}" for a in range(aggs_per_plane)]
+             for j in range(cluster_switches)]
+    devices += [Device(n, AGG) for plane in atsws for n in plane]
+    for pod in range(num_pods):
+        ctsws = [f"ctsw-{pod}-{j}" for j in range(cluster_switches)]
+        devices += [Device(n, SPINE, pod=pod) for n in ctsws]
+        for rr in range(racks_per_pod):
+            r = pod * racks_per_pod + rr
+            tor = f"rtsw-{r}"
+            devices.append(Device(tor, LEAF, rack=r, pod=pod))
+            for s in range(servers_per_rack):
+                srv = server_name(r * servers_per_rack + s)
+                devices.append(Device(srv, SERVER, rack=r, pod=pod))
+                for nic in range(nics_per_server):
+                    links.append(Link(srv, f"nic{nic}p0", tor,
+                                      f"host-{srv}-{nic}-0", link_gbps,
+                                      HOST_TO_LEAF))
+                    links.append(Link(tor, f"down-{srv}-{nic}-0", srv,
+                                      f"nic{nic}p0", link_gbps,
+                                      LEAF_TO_HOST))
+            for ctsw in ctsws:
+                cable(tor, f"up-{ctsw}", ctsw, f"down-{tor}",
+                      LEAF_TO_SPINE, SPINE_TO_LEAF)
+        for j, ctsw in enumerate(ctsws):
+            for atsw in atsws[j]:
+                for k in range(per_agg):
+                    cable(ctsw, f"up-{atsw}-{k}", atsw, f"down-{ctsw}-{k}",
+                          SPINE_TO_AGG, AGG_TO_SPINE)
+    return Fabric(devices, links)
+
+
 def host_of_nic_ip(ip: str) -> tuple[int, int]:
     """Inverse of nic_ip: ip -> (server index, nic index)."""
     parts = ip.split(".")
     return int(parts[2]) * 256 + int(parts[3]), int(parts[1])
+
+
+def port_nic(port: str) -> int | None:
+    """``k`` of a host port ``nic<k>p<m>``, else None."""
+    if not port.startswith("nic") or "p" not in port[3:]:
+        return None
+    return int(port[3:port.index("p", 3)])

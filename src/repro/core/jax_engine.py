@@ -824,8 +824,9 @@ def _walked_chunks(comp, flows, endpoints, field_mat, seeds_u64, *,
 
     Each pass is three spans: ``walk.to_device`` (its inputs),
     ``walk.run`` (the walk, its ``done`` flags, arrival check and hop
-    count) and ``walk.to_host`` (the arrival check's one bool; the
-    (N, s1 - s0) state too where a flow did not arrive)."""
+    count; counter ``hops``, the walk loop's trip count) and
+    ``walk.to_host`` (the arrival check's one bool; the (N, s1 - s0)
+    state too where a flow did not arrive)."""
     jax, jnp, _ = _jx()
     src_dev, dst_dev, src_key, dst_key = endpoints
     S = len(seeds_u64)
@@ -847,7 +848,9 @@ def _walked_chunks(comp, flows, endpoints, field_mat, seeds_u64, *,
             if not bool(all_done):
                 raise RuntimeError(
                     f"some flows did not terminate in {max_hops} hops")
-            ids = ids[: int(t)]            # frees the max_hops-deep tensor
+            hops = int(t)
+            count("hops", hops)
+            ids = ids[:hops]               # frees the max_hops-deep tensor
         with span("walk.to_host", bytes=arrived.nbytes):
             if not bool(arrived):
                 state = state[:, : s1 - s0]

@@ -315,18 +315,21 @@ def test_fim_keeps_layers_by_real_seeds_only(paper8):
 
 
 def test_fused_walk_names_the_flow_that_did_not_arrive(monkeypatch):
-    """With spine-0's links to leaf-2 cut, a flow into leaf-2 that hashes
-    onto spine-0 ends there.  The fused front ends check arrival on the
-    device and raise the numpy engine's error: flow id, the seed's index
-    in the whole sweep (here in the padded second chunk) and the device
-    the flow ended at."""
-    from repro.core.fabric import Fabric
+    """With spine-0's candidate sets toward every NIC on leaf-2 emptied
+    in the compiled table, a flow into leaf-2 that hashes onto spine-0
+    ends there.  The fused front ends check arrival on the device and
+    raise the numpy engine's error: flow id, the seed's index in the
+    whole sweep (here in the padded second chunk) and the device the
+    flow ended at."""
+    from repro.core.fabric import port_nic
 
-    fab = build_paper_testbed()
-    comp = compile_fabric(Fabric(
-        list(fab.devices.values()),
-        [ln for ln in fab.links
-         if (ln.src, ln.dst) != ("spine-0", "leaf-2")]))
+    comp = compile_fabric(build_paper_testbed())
+    on_leaf2 = [comp.key_of_ip[nic_ip(ln.dst, port_nic(ln.dst_port))]
+                for ln in comp.links
+                if ln.src == "leaf-2" and ln.dst.startswith("srv-")]
+    cand_n = comp.cand_n.copy()
+    cand_n[comp.device_id["spine-0"], on_leaf2] = 0
+    comp = dataclasses.replace(comp, cand_n=cand_n)
     flows = bipartite_pairs([server_name(0)], [server_name(8)],
                             flows_per_pair=1)
     stranded = [s for s in range(64)

@@ -4,9 +4,10 @@ With the profiler off nothing is recorded.  Under ``jax.profiler.trace``
 both fused front ends record every stage span, nested in the front
 end's span on the calling thread's host line; the self times of a call
 add up to its front-end span; each copy span counts the bytes its
-arrays' shapes give; the fill counts its freeze rounds; the table starts
-afresh in the next profiler session; and backend compiles are counted
-in the span that triggered them.
+arrays' shapes give; the fill counts its freeze rounds and the walk its hops; the table
+starts afresh in the next profiler session; backend compiles are
+counted in the span that triggered them; and ``compile_fabric`` counts
+what it built.
 """
 
 import glob
@@ -16,8 +17,9 @@ import numpy as np
 import pytest
 
 from repro.core import (
-    bipartite_pairs, build_paper_testbed, compile_fabric, monte_carlo_fim,
-    monte_carlo_throughput, resolve_flows, server_name, spans,
+    bipartite_pairs, build_paper_testbed, build_three_tier_clos,
+    compile_fabric, monte_carlo_fim, monte_carlo_throughput, nic_ip,
+    resolve_flows, server_name, spans, synthesize_flows,
 )
 from repro.core import jax_engine
 
@@ -189,3 +191,47 @@ def test_compiles_land_in_the_span_that_triggered_them(sweep, tmp_path):
     assert sum(r.get("compiles", 0) for r in table.values()) == len(seen)
     assert sum(r.get("compile_s", 0.0) for r in table.values()) == (
         pytest.approx(sum(seen)))
+
+
+def _three_tier():
+    """Two pods of one rack: each NIC of server i in pod 0 and the same
+    NIC of server i in pod 1 exchange one flow each way (6 hops)."""
+    fab = build_three_tier_clos(num_pods=2, racks_per_pod=1,
+                                servers_per_rack=2, nics_per_server=2,
+                                cluster_switches=2, aggs_per_plane=1,
+                                uplinks=1)
+    wl = bipartite_pairs([server_name(0), server_name(1)],
+                         [server_name(2), server_name(3)], flows_per_pair=2)
+    return fab, synthesize_flows(wl, nic_ip=nic_ip, nics_per_server=2)
+
+
+@pytest.mark.parametrize("shape, hops", [("testbed", 4), ("three-tier", 6)])
+def test_walk_counts_its_hops_per_pass(sweep, tmp_path, monkeypatch, shape,
+                                       hops):
+    """``hops`` of ``walk.run`` is the walk loop's trip count, summed
+    over the call's seed passes (two here)."""
+    if shape == "testbed":
+        comp, flows = sweep
+    else:
+        fab, flows = _three_tier()
+        comp = compile_fabric(fab)
+    per_seed = len(flows) * 16 * (jax_engine._WALK_BYTES_PER_HOP
+                                  + jax_engine._FILL_BYTES_PER_CELL)
+    monkeypatch.setattr(jax_engine, "_CHUNK_BYTES", per_seed * 128)
+    seeds = np.arange(256)
+    monte_carlo_fim(comp, flows, seeds, engine="jax")
+    _, table = traced(tmp_path, monte_carlo_fim, comp, flows, seeds,
+                      engine="jax")
+    assert table["walk.run"]["n"] == 2
+    assert table["walk.run"]["hops"] == 2 * hops
+
+
+def test_compile_fabric_span_counts_what_it_built(tmp_path):
+    fab, _ = _three_tier()
+    comp, table = traced(tmp_path, compile_fabric, fab)
+    V, K, C = comp.cand.shape
+    assert table["compile_fabric"]["n"] == 1
+    assert {k: table["compile_fabric"][k] for k in (
+        "devices", "links", "keys", "table_bytes")} == {
+        "devices": V, "links": len(fab.links), "keys": K,
+        "table_bytes": V * K * C * 4 + V * K * 4}
