@@ -5,9 +5,11 @@ is the differential reference; this module re-expresses the same hot path
 as jitted jax so a Monte-Carlo sweep runs on the accelerator with no host
 round-trips between stages:
 
-* the per-hop ECMP/flowlet walk is a ``lax.while_loop`` over the (N, S)
-  current-device grid — bit-identical to ``vector_sim.ecmp_walk`` under
-  the exact splitmix64 backend (uint64 wraparound is exact under x64);
+* the per-hop ECMP/flowlet walk follows each flow's own route tables,
+  one per hop, and picks every entry it reads with compares and selects
+  over the (N, S) grid, not with gathers from the fabric's tables —
+  bit-identical to ``vector_sim.ecmp_walk`` under both hash backends
+  (uint64 wraparound is exact under x64);
 * link counts are one masked reduction per (seed, link) cell, and the
   per-layer FIM (MAPE vs per-layer ideal) is a handful of masked
   reductions per layer;
@@ -35,6 +37,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import hashlib
 from collections.abc import Sequence
 
 import numpy as np
@@ -62,8 +65,9 @@ ENGINE_JAX = "jax"
 
 # Seeds per device pass in the fused front ends (``seed_chunk``), sized
 # for one TPU v5e (16 GB HBM).  A pass over N flows x Sc seeds holds the
-# walk's (max_hops, N, Sc) int32 link-id tensor, 4 B per hop, and the
-# fill's working set over its (H, N, Sc) cells.  XLA:TPU's memory
+# walk's (H, N, Sc) int32 link-id tensor, 4 B per hop, budgeted here at
+# max_hops >= H hops, and the fill's working set over its (H, N, Sc)
+# cells.  XLA:TPU's memory
 # analysis of the compiled fill gives 27-31 B per cell (arguments,
 # (S, L) tables, (N, S) masks and gathered shares) at 4 x 4096 x 1024,
 # 4 x 7168 x 1024 and 4 x 100000 x 128; _FILL_BYTES_PER_CELL budgets 48.
@@ -131,7 +135,6 @@ class _DeviceTables:
     dev_crc: object
     is_server: object
     link_dst: object
-    link_gbps: object
 
 
 _TABLE_CACHE: dict[int, tuple[object, _DeviceTables]] = {}
@@ -151,7 +154,6 @@ def device_tables(comp: CompiledFabric) -> _DeviceTables:
         dev_crc=jnp.asarray(comp.dev_crc),
         is_server=jnp.asarray(comp.is_server),
         link_dst=jnp.asarray(comp.link_dst),
-        link_gbps=jnp.asarray(np.asarray(comp.link_gbps, np.float64)),
     )
     if len(_TABLE_CACHE) > 16:
         _TABLE_CACHE.clear()
@@ -203,49 +205,197 @@ def _hash_grid_j(fields, dev_seed, hash_backend: str):
 
 
 # ---------------------------------------------------------------------------
-# Stage 1: the walk (lax.while_loop over the (N, S) device grid)
+# Stage 1: the walk (per-flow route tables, chosen by compares and selects)
 # ---------------------------------------------------------------------------
 
 
+@dataclasses.dataclass(frozen=True)
+class _RouteTables:
+    """One row per flow of the walk's route tables, on the device.
+
+    ``hops[t]`` is hop t's ``(dev_crc, n, steps)``: the hash salt
+    (uint32) and candidate count (int32) of each of the ``W_t`` devices
+    the flow can be at, ``(N, W_t)``, and ``(N, W_t * C_t)`` int32 steps
+    ``(link + 1) << shift | code`` for candidate slot c of device w at
+    column ``w * C_t + c``.  ``code`` is the device's index in hop
+    t + 1's row, or ``base + e`` where the walk ends: ``exits[:, e]`` is
+    the device it ends at (the server the link reaches; the device
+    itself where it has no candidate, in slot 0 with link -1)."""
+
+    hops: tuple
+    exits: object
+    widths: tuple[tuple[int, int], ...]     # (W_t, C_t) per hop
+    shift: int
+    base: int
+
+    @property
+    def select_width(self) -> int:
+        """Entries the walk selects among per (flow, seed): sum W_t * C_t."""
+        return sum(w * c for w, c in self.widths)
+
+
+def _route_tables_host(comp: CompiledFabric, src_dev, src_key, dst_key,
+                       max_hops: int):
+    """The walk's route tables per distinct (source device, source key,
+    destination key), enumerated hop by hop: ``(hops, exits, widths,
+    shift, base, inverse)``, ``inverse`` the triple of each flow.
+
+    Hop 0's frontier is the source device; a device's candidates are
+    keyed by the source NIC on a server and by the destination NIC
+    elsewhere (``ecmp_walk``'s rule), and hop t + 1's frontier is every
+    device a candidate reaches that is not a server, in device-id order.
+    The frontier is followed until it empties or for ``max_hops`` hops.
+    """
+    V, K = comp.num_devices, max(1, len(comp.key_of_ip))
+    trip = (np.asarray(src_dev, np.int64) * K
+            + np.asarray(src_key, np.int64)) * K + np.asarray(dst_key,
+                                                            np.int64)
+    uniq, inverse = np.unique(trip, return_inverse=True)
+    u_dev, u_skey, u_dkey = uniq // (K * K), uniq // K % K, uniq % K
+    rows = np.arange(uniq.size, dtype=np.int64)
+
+    def number(codes):
+        """Index of each ``u * V + device`` code within its row's sorted
+        distinct codes; the (U, width) device table of those codes."""
+        distinct = np.unique(codes)
+        u = distinct // V
+        local = (np.arange(distinct.size, dtype=np.int64)
+                 - np.searchsorted(u, rows)[u])
+        table = np.full((rows.size, int(local.max(initial=-1)) + 1), -1,
+                        np.int64)
+        table[u, local] = distinct % V
+        return local[np.searchsorted(distinct, codes)], table
+
+    front, base = u_dev[:, None], 1      # base: the widest next frontier
+    hops = []                            # (crc, n, link, code, ending)
+    for _ in range(max_hops):
+        if front.shape[1] == 0:
+            break
+        live = front >= 0
+        dev = np.maximum(front, 0)
+        key = np.where(comp.is_server[dev], u_skey[:, None],
+                       u_dkey[:, None])
+        n = np.where(live, comp.cand_n[dev, key], 0)
+        fan = max(1, int(n.max(initial=0)))
+        slot = np.arange(fan, dtype=np.int64)
+        link = np.where(slot < n[..., None], comp.cand[dev, key, :fan], -1)
+        nxt = comp.link_dst[np.maximum(link, 0)]
+        walks_on = (link >= 0) & ~comp.is_server[nxt]
+        # a walk ends on the server its link reaches, or where it stands
+        # when it has no candidate (slot 0, link -1)
+        ending = (live[..., None] & (slot < np.maximum(n, 1)[..., None])
+                  & ~walks_on)
+        at = rows[:, None, None] * V + np.where(link >= 0, nxt,
+                                                dev[..., None])
+        code = np.zeros(link.shape, np.int64)
+        code[walks_on], front = number(at[walks_on])
+        code[ending] = at[ending]        # made exit indices below
+        base = max(base, front.shape[1])
+        hops.append((np.where(live, comp.dev_crc[dev], 0).astype(np.uint32),
+                     n.astype(np.int32), link, code, ending))
+    exit_of, exits = number(np.concatenate(
+        [np.zeros(0, np.int64), *(code[ending] for *_, code, ending in hops)]))
+    if exits.shape[1] == 0:
+        exits = np.full((rows.size, 1), -1, np.int64)
+    shift = (base + exits.shape[1] - 1).bit_length()
+    if (comp.num_links + 1) << shift >= 2**31:
+        raise ValueError(f"{comp.num_links} links and {base} devices a hop "
+                         "do not pack into the walk's int32 steps")
+    out, widths, done = [], [], 0
+    for crc, n, link, code, ending in hops:
+        code[ending] = base + exit_of[done: done + ending.sum()]
+        done += int(ending.sum())
+        steps = ((link + 1) << shift) | code
+        width, fan = link.shape[1:]
+        out.append((crc, n,
+                    steps.reshape(len(rows), width * fan).astype(np.int32)))
+        widths.append((width, fan))
+    return (out, exits.astype(np.int32), tuple(widths), shift, base,
+            inverse.reshape(-1))
+
+
+_ROUTE_CACHE: dict[tuple, tuple[object, _RouteTables]] = {}
+
+
+def route_tables(comp: CompiledFabric, src_dev, src_key, dst_key,
+                 max_hops: int) -> _RouteTables:
+    """The walk's per-flow route tables on the device, built and uploaded
+    once per compiled fabric (by identity, as ``device_tables``), flow
+    endpoints (by digest) and ``max_hops``.  A build is the
+    ``walk.tables`` span, counting the distinct endpoint ``triples`` and
+    the ``bytes`` uploaded."""
+    ends = [np.ascontiguousarray(a, np.int32)
+            for a in (src_dev, src_key, dst_key)]
+    digest = hashlib.blake2b(b"".join(a.tobytes() for a in ends),
+                             digest_size=16).digest()
+    key = (id(comp), digest, max_hops)
+    hit = _ROUTE_CACHE.get(key)
+    if hit is not None and hit[0] is comp:
+        return hit[1]
+    jax, jnp, _ = _jx()
+    with span("walk.tables"):
+        hops, exits, widths, shift, base, inverse = _route_tables_host(
+            comp, *ends, max_hops)
+        host = ([tuple(a[inverse] for a in hop) for hop in hops],
+                exits[inverse])
+        count("triples", len(exits))
+        count("bytes", sum(a.nbytes for a in jax.tree.leaves(host)))
+        dev_hops, dev_exits = jax.block_until_ready(
+            jax.tree.map(jnp.asarray, host))
+    tabs = _RouteTables(hops=tuple(map(tuple, dev_hops)), exits=dev_exits,
+                        widths=widths, shift=shift, base=base)
+    if len(_ROUTE_CACHE) > 16:
+        _ROUTE_CACHE.clear()
+    _ROUTE_CACHE[key] = (comp, tabs)
+    return tabs
+
+
+def _select(idx, table):
+    """``table[n, idx[n, s]]`` of an (N, K) per-flow table at an (N, S)
+    column index, without a gather: a compare and select per column,
+    broadcast over the seeds, summed over the K columns (one matches; an
+    index past K reads 0)."""
+    _, jnp, _ = _jx()
+    col = jnp.arange(table.shape[1], dtype=idx.dtype)[:, None, None]
+    return jnp.where(idx[None] == col, table.T[:, :, None],
+                     0).sum(0, dtype=table.dtype)
+
+
 def _walk_jit():
-    jax, jnp, lax = _jx()
+    jax, jnp, _ = _jx()
 
     @functools.partial(
-        jax.jit, static_argnames=("max_hops", "hash_backend", "n_fields"))
-    def walk(cand, cand_n, dev_crc, is_server, link_dst,
-             src_dev, src_key, dst_key, fields, seeds, cell_salt,
-             *, max_hops: int, hash_backend: str, n_fields: int):
-        N, S = src_dev.shape[0], seeds.shape[0]
-        state0 = jnp.broadcast_to(
-            src_dev[:, None].astype(jnp.int32), (N, S))
-        done0 = jnp.zeros((N, S), bool)
-        ids0 = jnp.full((max_hops, N, S), -1, jnp.int32)
-
-        def cond(c):
-            t, state, done, ids = c
-            return (t < max_hops) & ~done.all()
-
-        def body(c):
-            t, state, done, ids = c
-            # src-keyed on the source host (hop 0), dst-keyed at switches
-            key = jnp.where(is_server[state], src_key[:, None],
-                            dst_key[:, None])
-            n = cand_n[state, key]
-            dev_seed = dev_crc[state] ^ seeds[None, :]
-            if cell_salt is not None:
-                dev_seed = dev_seed ^ cell_salt
-            h = _hash_grid_j(fields, dev_seed, hash_backend)
-            safe_n = jnp.maximum(n, 1).astype(jnp.uint64)
-            choice = jnp.where(n > 1, (h % safe_n).astype(jnp.int32), 0)
-            link = cand[state, key, choice]
-            link = jnp.where(done | (n == 0), -1, link)
-            ids = lax.dynamic_update_index_in_dim(ids, link, t, 0)
-            nxt = jnp.where(link >= 0, link_dst[jnp.maximum(link, 0)], state)
-            done = done | (link < 0) | is_server[nxt]
-            return t + 1, nxt, done, ids
-
-        t, state, done, ids = lax.while_loop(
-            cond, body, (jnp.int32(0), state0, done0, ids0))
+        jax.jit, static_argnames=("hash_backend", "shift", "base"))
+    def walk(hops, exits, fields, seeds, cell_salt,
+             *, hash_backend: str, shift: int, base: int):
+        N, S = fields.shape[0], seeds.shape[0]
+        j = jnp.zeros((N, S), jnp.int32)     # device index in hop t's row
+        end = jnp.zeros((N, S), jnp.int32)   # exit index, once done
+        done = jnp.zeros((N, S), bool)
+        t = jnp.int32(0)                     # hops begun by a walking cell
+        ids = []
+        for hop in range(len(hops)):         # unrolled: widths per hop
+            crc_t, n_t, steps_t = hops[hop]
+            t = t + (~done).any()
+            fan = steps_t.shape[1] // crc_t.shape[1]
+            choice = 0
+            if fan > 1:                      # one slot: nothing to hash
+                n = _select(j, n_t)
+                dev_seed = _select(j, crc_t).astype(jnp.uint64) \
+                    ^ seeds[None, :]
+                if cell_salt is not None:
+                    dev_seed = dev_seed ^ cell_salt
+                h = _hash_grid_j(fields, dev_seed, hash_backend)
+                safe_n = jnp.maximum(n, 1).astype(jnp.uint64)
+                choice = jnp.where(n > 1, (h % safe_n).astype(jnp.int32), 0)
+            step = _select(j * fan + choice, steps_t)
+            ids.append(jnp.where(done, -1, (step >> shift) - 1))
+            j = step & ((1 << shift) - 1)
+            ends = ~done & (j >= base)
+            end = jnp.where(ends, j - base, end)
+            done = done | ends
+        state = jnp.where(done, _select(end, exits), -1)
+        ids = jnp.stack(ids) if ids else jnp.zeros((0, N, S), jnp.int32)
         return ids, state, done, t
 
     return walk
@@ -256,19 +406,17 @@ def _walk_fn():
     return _walk_jit()
 
 
-def _jax_walk_device(comp, src_dev, src_key, dst_key, field_mat, seeds_u64,
-                     *, hash_backend, max_hops, cell_salt=None):
-    """Run the walk on device; returns device (max_hops, N, S) link ids,
-    final state, done mask, and the hop-count scalar (all device-side)."""
+def _jax_walk_device(routes: _RouteTables, field_mat, seeds_u64, *,
+                     hash_backend, cell_salt=None):
+    """Run the walk on device; returns device (hops, N, S) link ids (-1
+    past a flow's last hop), the device each walk ended at (-1 where it
+    did not end), the done mask and the hop count (all device-side)."""
     _, jnp, _ = _jx()
-    tabs = device_tables(comp)
     salt = None if cell_salt is None else jnp.asarray(cell_salt)
     return _walk_fn()(
-        tabs.cand, tabs.cand_n, tabs.dev_crc, tabs.is_server, tabs.link_dst,
-        jnp.asarray(src_dev), jnp.asarray(src_key), jnp.asarray(dst_key),
-        jnp.asarray(field_mat), jnp.asarray(seeds_u64), salt,
-        max_hops=max_hops, hash_backend=hash_backend,
-        n_fields=int(field_mat.shape[1]))
+        routes.hops, routes.exits, jnp.asarray(field_mat),
+        jnp.asarray(seeds_u64), salt, hash_backend=hash_backend,
+        shift=routes.shift, base=routes.base)
 
 
 def _check_walk(comp, state, dst_dev, describe, first_seed=0):
@@ -302,11 +450,11 @@ def jax_ecmp_walk(
 ) -> np.ndarray:
     """Drop-in twin of ``vector_sim.ecmp_walk`` on the jax engine:
     same signature, same (hops, N, S) numpy result, same termination
-    errors — bit-identical under ``hash_backend="exact"``."""
+    errors — bit-identical under both hash backends."""
     with _x64():
+        routes = route_tables(comp, src_dev, src_key, dst_key, max_hops)
         ids, state, done, t = _jax_walk_device(
-            comp, src_dev, src_key, dst_key, field_mat, seeds_u64,
-            hash_backend=hash_backend, max_hops=max_hops,
+            routes, field_mat, seeds_u64, hash_backend=hash_backend,
             cell_salt=cell_salt)
         hops = int(t)
         if not bool(done.all()):
@@ -822,25 +970,28 @@ def _walked_chunks(comp, flows, endpoints, field_mat, seeds_u64, *,
     of its own seeds, so every pass has one shape and compiles once;
     callers keep the first ``s1 - s0`` seed columns.
 
-    Each pass is three spans: ``walk.to_device`` (its inputs),
-    ``walk.run`` (the walk, its ``done`` flags, arrival check and hop
-    count; counter ``hops``, the walk loop's trip count) and
-    ``walk.to_host`` (the arrival check's one bool; the (N, s1 - s0)
-    state too where a flow did not arrive)."""
+    The flows' route tables come from ``route_tables`` (the
+    ``walk.tables`` span where they are built).  Each pass is three
+    spans: ``walk.to_device`` (its inputs), ``walk.run`` (the walk, its
+    ``done`` flags, arrival check and hop count; counters ``hops``, the
+    hops begun by a walking cell, and ``select_width``, the route-table
+    entries each (flow, seed) selects among) and ``walk.to_host`` (the
+    arrival check's one bool; the (N, s1 - s0) state too where a flow
+    did not arrive)."""
     jax, jnp, _ = _jx()
     src_dev, dst_dev, src_key, dst_key = endpoints
+    routes = route_tables(comp, src_dev, src_key, dst_key, max_hops)
     S = len(seeds_u64)
     Sc = seed_chunk(len(flows), max_hops, S)
     for s0 in range(0, S, Sc):
         s1 = min(s0 + Sc, S)
-        host = (src_dev, src_key, dst_key, field_mat,
-                np.resize(seeds_u64[s0:s1], Sc), dst_dev)
+        host = (field_mat, np.resize(seeds_u64[s0:s1], Sc), dst_dev)
         with span("walk.to_device", bytes=sum(a.nbytes for a in host)):
-            *args, dst = jax.block_until_ready(
+            fields, seeds, dst = jax.block_until_ready(
                 [jnp.asarray(a) for a in host])
         with span("walk.run"):
             ids, state, done, t = _jax_walk_device(
-                comp, *args, hash_backend=hash_backend, max_hops=max_hops)
+                routes, fields, seeds, hash_backend=hash_backend)
             # the reductions are queued behind the walk before the wait,
             # so the device does not idle while they are dispatched
             ids, all_done, arrived, t = jax.block_until_ready(
@@ -850,7 +1001,8 @@ def _walked_chunks(comp, flows, endpoints, field_mat, seeds_u64, *,
                     f"some flows did not terminate in {max_hops} hops")
             hops = int(t)
             count("hops", hops)
-            ids = ids[:hops]               # frees the max_hops-deep tensor
+            count("select_width", routes.select_width)
+            ids = ids[:hops]               # rows past the last hop are -1
         with span("walk.to_host", bytes=arrived.nbytes):
             if not bool(arrived):
                 state = state[:, : s1 - s0]

@@ -478,10 +478,10 @@ def ecmp_walk(
     flow-level front end; routing strategies (``core/strategies.py``)
     call this directly with expanded per-flowlet arrays.
 
-    ``engine="jax"`` runs the identical walk as a jitted
-    ``lax.while_loop`` on the accelerator (``core/jax_engine.py``) —
-    bit-identical to the numpy walk backend for backend (the
-    differential contract).  ``hash_backend=None`` resolves to the
+    ``engine="jax"`` runs the identical walk on the accelerator
+    (``core/jax_engine.py``), over per-flow route tables built from the
+    same compiled tables — bit-identical to the numpy walk backend for
+    backend (the differential contract).  ``hash_backend=None`` resolves to the
     engine's natural backend (``resolve_hash_backend``).
 
     ``cell_salt`` optionally perturbs the entropy of individual

@@ -20,11 +20,15 @@ from _propcheck import given, settings, strategies as st
 
 from repro.core import (
     AdaptiveSpraying, PrimeSpraying, RoutingStrategy,
-    TimelineStep, batched_max_min, bipartite_pairs, build_paper_testbed,
-    compile_fabric, flowlet_exposure, max_min_rates, monte_carlo_fim,
-    monte_carlo_throughput, nic_ip, server_name, simulate_paths,
-    simulate_timeline, synthesize_flows, throughput_from_result,
+    TimelineStep, batched_max_min, bipartite_pairs, build_multipod_fabric,
+    build_paper_testbed, build_three_tier_clos, compile_fabric,
+    flowlet_exposure, max_min_rates, monte_carlo_fim,
+    monte_carlo_throughput, nic_ip, resolve_flows, server_name,
+    simulate_paths, simulate_timeline, synthesize_flows,
+    throughput_from_result,
 )
+from repro.core.ecmp import FIELDS_5TUPLE, flow_fields_matrix
+from repro.core.fabric import SERVER
 from repro.compile_cache import (
     CACHE_ENV, DEFAULT_CACHE_DIR, configure_compile_cache,
 )
@@ -33,7 +37,8 @@ from repro.core.jax_engine import (
     default_hash_backend, resolve_engine, seed_chunk,
 )
 from repro.core.vector_sim import (
-    ENGINE_JAX, ENGINE_NUMPY, EXACT, MURMUR, resolve_hash_backend,
+    ENGINE_JAX, ENGINE_NUMPY, EXACT, MURMUR, ecmp_walk, normalize_seeds,
+    resolve_hash_backend,
 )
 
 STRATEGIES = {
@@ -164,6 +169,128 @@ def test_randomized_fabric_walk_parity(spines, links_per, seed):
     a = max_min_rates(r_np)
     b = max_min_rates(r_jx, engine=ENGINE_JAX)
     assert np.abs(a - b).max() < 1e-6
+    # the murmur walk under a cell salt too, with its final devices
+    _assert_walks_equal(comp, flows, normalize_seeds(seeds), MURMUR,
+                        salted=True)
+
+
+# ---------------------------------------------------------------------------
+# the walk's per-flow route tables against the numpy walk
+# ---------------------------------------------------------------------------
+
+
+WALK_FABRICS = {
+    "testbed": build_paper_testbed,
+    "three-tier": lambda: build_three_tier_clos(
+        num_pods=2, racks_per_pod=3, servers_per_rack=2, nics_per_server=2,
+        cluster_switches=4, aggs_per_plane=2, uplinks=2),
+    "multipod": build_multipod_fabric,
+}
+
+
+@pytest.fixture(scope="module", params=sorted(WALK_FABRICS))
+def walk_fabric(request):
+    """A fabric and flows of every path length it has: each server to
+    the server across the fabric and to its neighbour."""
+    fab = WALK_FABRICS[request.param]()
+    comp = compile_fabric(fab)
+    srv = sorted(d for d in fab.devices if fab.kind(d) == SERVER)
+    wl = bipartite_pairs(srv[: len(srv) // 2], srv[::-1][: len(srv) // 2],
+                         flows_per_pair=1)
+    near = bipartite_pairs(srv[0::2], srv[1::2], flows_per_pair=1)
+    flows = resolve_flows(comp, wl) + resolve_flows(comp, near)
+    return comp, [dataclasses.replace(f, flow_id=i)
+                  for i, f in enumerate(flows)]
+
+
+def _assert_walks_equal(comp, flows, seeds, backend, *, salted=False,
+                        flowlets=1):
+    """The jax walk's link ids, hop count and final devices equal the
+    numpy walk's; ``flowlets`` columns a flow, each with its own entropy
+    label column, as the spray strategies walk them."""
+    import jax
+
+    ends = comp.flow_endpoint_ids(flows)
+    fm = flow_fields_matrix(flows, FIELDS_5TUPLE)
+    if flowlets > 1:
+        rep = np.repeat(np.arange(len(flows)), flowlets)
+        ends = tuple(a[rep] for a in ends)
+        labels = np.tile(np.arange(flowlets, dtype=np.uint64), len(flows))
+        fm = np.concatenate([fm[rep], labels[:, None]], axis=1)
+    src, dst, skey, dkey = ends
+    salt = None
+    if salted:                        # half the cells keep the plain walk
+        salt = np.random.default_rng(len(src)).integers(
+            0, 2**63, (len(src), len(seeds))).astype(np.uint64)
+        salt[::2] = 0
+    want = ecmp_walk(comp, src, dst, skey, dkey, fm, seeds,
+                     hash_backend=backend, cell_salt=salt)
+    got = jax_engine.jax_ecmp_walk(comp, src, dst, skey, dkey, fm, seeds,
+                                   hash_backend=backend, cell_salt=salt)
+    assert got.dtype == want.dtype and np.array_equal(got, want)
+    with jax.enable_x64(True):
+        routes = jax_engine.route_tables(comp, src, skey, dkey, 16)
+        ids, state, done, t = jax.device_get(jax_engine._jax_walk_device(
+            routes, fm, seeds, hash_backend=backend, cell_salt=salt))
+    assert done.all() and int(t) == want.shape[0]
+    assert np.array_equal(ids[: int(t)], want) and (ids[int(t):] == -1).all()
+    # a walk ends on the far device of its last link
+    last = np.take_along_axis(
+        want, (want >= 0).sum(axis=0, keepdims=True) - 1, axis=0)[0]
+    assert np.array_equal(state, comp.link_dst[last])
+    assert np.array_equal(state, np.broadcast_to(dst[:, None], state.shape))
+
+
+@pytest.mark.parametrize("variant", ["plain", "salted", "flowlets"])
+@pytest.mark.parametrize("backend", [EXACT, MURMUR])
+def test_walk_matches_the_numpy_walk(walk_fabric, backend, variant):
+    comp, flows = walk_fabric
+    seeds = normalize_seeds([0, 3, 2**40 + 17, 2**63 + 5])
+    _assert_walks_equal(comp, flows, seeds, backend,
+                        salted=variant != "plain",
+                        flowlets=3 if variant == "flowlets" else 1)
+
+
+def _frozen_testbed_flows():
+    """The paper-ecmp cells' 256 flows, from the configuration's frozen
+    ``flows.json``."""
+    import json
+    from pathlib import Path
+
+    from repro.core import FiveTuple, Flow
+    path = (Path(__file__).resolve().parents[1] / "chipbench" / "configs"
+            / "paper-testbed" / "flows.json")
+    with open(path) as fh:
+        rows = json.load(fh)["flows"]
+    return [Flow(flow_id=f["flow_id"], src=f["src"], dst=f["dst"],
+                 tuple5=FiveTuple(f["src_ip"], f["dst_ip"], f["src_port"],
+                                  f["dst_port"], f["protocol"]))
+            for f in rows]
+
+
+def test_route_tables_of_the_testbed():
+    """Frontier widths (1, 1, 4, 1) and fan-outs (2, 16, 4, 2) a hop:
+    the source NIC's two ports to its leaf, the leaf's 16 spine links,
+    each spine's 4 links to the far leaf, the far leaf's two ports; one
+    exit, the destination server."""
+    comp = compile_fabric(build_paper_testbed())
+    flows = _frozen_testbed_flows()
+    src, dst, skey, dkey = comp.flow_endpoint_ids(flows)
+    hops, exits, widths, shift, base, inverse = \
+        jax_engine._route_tables_host(comp, src, skey, dkey, 16)
+    assert widths == ((1, 2), (1, 16), (4, 4), (1, 2))
+    assert sum(w * c for w, c in widths) == 36
+    assert len(exits) == 64 and exits.shape[1] == 1
+    assert np.array_equal(exits[inverse, 0], dst)
+    assert base == 4 and shift == 3
+    # a depth cut short of the fabric's leaves the walk unfinished
+    _, _, short, *_ = jax_engine._route_tables_host(comp, src, skey, dkey, 3)
+    assert short == widths[:3]
+    with pytest.raises(RuntimeError, match="did not terminate in 3 hops"):
+        jax_engine.jax_ecmp_walk(
+            comp, src, dst, skey, dkey,
+            flow_fields_matrix(flows, FIELDS_5TUPLE), normalize_seeds([1]),
+            max_hops=3)
 
 
 # ---------------------------------------------------------------------------

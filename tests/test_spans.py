@@ -25,6 +25,10 @@ from repro.core import jax_engine
 
 SEEDS = np.arange(64)
 FIELDS = 5                          # FIELDS_5TUPLE hash columns per flow
+#: route-table bytes a flow of the testbed: hop t's (W_t, C_t) are
+#: (1, 2), (1, 16), (4, 4), (1, 2); a uint32 salt and an int32 count per
+#: device, an int32 step per slot, one int32 exit
+TESTBED_ROUTE_BYTES = 4 * 7 + 4 * 7 + 4 * 36 + 4
 
 STAGES = {
     "monte_carlo_throughput": [
@@ -109,13 +113,14 @@ def test_self_times_add_up_to_the_front_end_span(sweep, tmp_path, front):
 
 
 def test_copy_spans_count_the_bytes_of_their_shapes(sweep, tmp_path):
-    """Inputs in, answers out: the walk pulls its arrival check's one
-    bool, the FIM its (1 + NL, S) answers and (NL,) live flags, and the
-    FIM's layer tables go up once per compiled fabric, on its first
-    call; the (S, L) count matrix never crosses."""
+    """Inputs in, answers out: the walk sends its fields, seeds and
+    destinations and pulls its arrival check's one bool, the FIM its
+    (1 + NL, S) answers and (NL,) live flags, and the walk's route
+    tables and the FIM's layer tables go up once per compiled fabric,
+    on its first call; the (S, L) count matrix never crosses."""
     comp, flows = sweep
     N, S, L = len(flows), len(SEEDS), comp.num_links
-    walk = {"walk.to_device": 4 * N * 4 + N * FIELDS * 8 + S * 8,
+    walk = {"walk.to_device": N * 4 + N * FIELDS * 8 + S * 8,
             "walk.to_host": 1}
     _, table = traced(tmp_path / "tp", monte_carlo_throughput, comp, flows,
                       SEEDS, engine="jax")
@@ -129,7 +134,8 @@ def test_copy_spans_count_the_bytes_of_their_shapes(sweep, tmp_path):
     _, table = traced(tmp_path / "fim0", monte_carlo_fim, fresh, flows,
                       SEEDS, engine="jax")
     got = {k: v["bytes"] for k, v in table.items() if "bytes" in v}
-    assert got == {**walk, **fim_out, "fim.to_device": NL * L + 2 * L * 4}
+    assert got == {**walk, **fim_out, "fim.to_device": NL * L + 2 * L * 4,
+                   "walk.tables": N * TESTBED_ROUTE_BYTES}
     _, table = traced(tmp_path / "fim1", monte_carlo_fim, fresh, flows,
                       SEEDS, engine="jax")
     got = {k: v["bytes"] for k, v in table.items() if "bytes" in v}
@@ -224,6 +230,42 @@ def test_walk_counts_its_hops_per_pass(sweep, tmp_path, monkeypatch, shape,
                       engine="jax")
     assert table["walk.run"]["n"] == 2
     assert table["walk.run"]["hops"] == 2 * hops
+    # select_width: the route-table entries a (flow, seed) selects among
+    ends = comp.flow_endpoint_ids(flows)
+    widths = jax_engine._route_tables_host(comp, ends[0], ends[2], ends[3],
+                                           16)[2]
+    assert table["walk.run"]["select_width"] == 2 * sum(
+        w * c for w, c in widths)
+    if shape == "testbed":
+        assert widths == ((1, 2), (1, 16), (4, 4), (1, 2))
+
+
+def test_route_tables_are_built_once_per_fabric_and_flows(sweep, tmp_path):
+    """The ``walk.tables`` span builds and uploads the walk's route
+    tables on a compiled fabric's first call with these flows only; a
+    replaced fabric, or other flows, builds them again."""
+    import dataclasses
+
+    comp, flows = sweep
+    comp = dataclasses.replace(comp)
+    _, first = traced(tmp_path / "a", monte_carlo_fim, comp, flows, SEEDS,
+                      engine="jax")
+    _, again = traced(tmp_path / "b", monte_carlo_fim, comp, flows, SEEDS,
+                      engine="jax")
+    _, other = traced(tmp_path / "c", monte_carlo_fim, comp, flows[:8],
+                      SEEDS, engine="jax")
+    _, replaced = traced(tmp_path / "d", monte_carlo_fim,
+                         dataclasses.replace(comp), flows, SEEDS,
+                         engine="jax")
+    # 32 flows: 8 server pairs each way, on both NICs of a server
+    assert first["walk.tables"] == {
+        **first["walk.tables"], "n": 1, "triples": 32,
+        "bytes": len(flows) * TESTBED_ROUTE_BYTES}
+    assert "walk.tables" not in again
+    assert other["walk.tables"]["n"] == 1
+    assert replaced["walk.tables"]["n"] == 1
+    for table in (first, again, other, replaced):
+        assert table["walk.run"]["select_width"] == 36
 
 
 def test_compile_fabric_span_counts_what_it_built(tmp_path):

@@ -175,6 +175,24 @@ def test_meta_configuration_is_one_to_seven_with_six_hops_across_pods():
     assert hops.tolist() == [[6] * 3, [4] * 3, [2] * 3]
 
 
+def test_meta_walk_selects_among_226_route_table_entries():
+    """Across the pod boundary of the meta shape a flow can be at 1, 1,
+    16, 32, 16 and 1 devices at its six hops (host, ToR, cluster
+    switches, aggregation planes, far cluster switches, far ToR), with
+    1, 16, 6, 3, 1 and 1 candidates: 226 entries to select among."""
+    from repro.core import jax_engine
+
+    comp = compile_fabric(build_three_tier_clos(**META))
+    flows = pod_boundary_flows(META, 0, per_pair=1)
+    src, dst, skey, dkey = comp.flow_endpoint_ids(flows)
+    hops, exits, widths, shift, base, inverse = \
+        jax_engine._route_tables_host(comp, src, skey, dkey, 16)
+    assert widths == ((1, 1), (1, 16), (16, 6), (32, 3), (16, 1), (1, 1))
+    assert sum(w * c for w, c in widths) == 226
+    assert len(exits) == len(flows) == 1344
+    assert np.array_equal(exits[inverse, 0], dst)
+
+
 def test_uneven_uplinks_are_refused():
     with pytest.raises(ValueError, match="evenly"):
         build_three_tier_clos(**{**SMALL, "uplinks": 3})

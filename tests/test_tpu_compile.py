@@ -16,7 +16,10 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
-from repro.core import build_paper_testbed, compile_fabric
+from repro.core import (
+    bipartite_pairs, build_paper_testbed, compile_fabric, resolve_flows,
+    server_name,
+)
 from repro.core import jax_engine as je
 from repro.kernels.flowhash.kernel import bulk_hash_seeded_kernel
 
@@ -69,15 +72,22 @@ def _compiles(lowered):
 
 
 def test_walk_compiles_for_v5e(chip, comp):
-    tabs = [chip(a.shape, a.dtype) for a in (
-        comp.cand, comp.cand_n, comp.dev_crc, comp.is_server,
-        comp.link_dst)]
-    flow = chip((FLOWS,), jnp.int32)
+    """The walk over the route tables of FLOWS testbed flows."""
+    wl = bipartite_pairs([server_name(i) for i in range(8)],
+                         [server_name(8 + i) for i in range(8)],
+                         flows_per_pair=FLOWS // 16)
+    flows = resolve_flows(comp, wl)
+    src, _, skey, dkey = comp.flow_endpoint_ids(flows)
+    hops, exits, widths, shift, base, inverse = je._route_tables_host(
+        comp, src, skey, dkey, MAX_HOPS)
+    assert len(hops) == HOPS
+    flow_rows = tuple(tuple(chip((FLOWS, *a.shape[1:]), a.dtype)
+                            for a in hop) for hop in hops)
     with jax.enable_x64(True):
         _compiles(je._walk_fn().lower(
-            *tabs, flow, flow, flow, chip((FLOWS, 5), jnp.uint64),
-            chip((SEEDS,), jnp.uint64), None,
-            max_hops=MAX_HOPS, hash_backend="murmur", n_fields=5))
+            flow_rows, chip((FLOWS, exits.shape[1]), jnp.int32),
+            chip((FLOWS, 5), jnp.uint64), chip((SEEDS,), jnp.uint64), None,
+            hash_backend="murmur", shift=shift, base=base))
 
 
 def test_wave_walk_compiles_for_v5e(chip, comp):
