@@ -19,7 +19,7 @@ import time
 import numpy as np
 
 from repro.core import (
-    FIELDS_5TUPLE, CongestionAware, EcmpStrategy, PrimeSpraying,
+    FIELDS_5TUPLE, CongestionAware, EcmpStrategy, PrimeSpraying, SimSpec,
     compile_fabric, fim, fim_from_counts, flow_fields_matrix, max_min_rates,
     per_pair_throughput, simulate_paths, static_route_assignment,
     throughput_from_result,
@@ -48,7 +48,8 @@ def run() -> None:
     goodput = {}
     for tag, strategy in STRATEGY_MATRIX:
         t0 = time.perf_counter()
-        res = simulate_paths(comp, flows, seeds, strategy=strategy,
+        res = simulate_paths(comp, flows, seeds,
+                             spec=SimSpec(strategy=strategy),
                              field_matrix=field_mat)
         fims, _ = fim_from_counts(res.link_flow_counts(), comp)
         sim_elapsed = time.perf_counter() - t0

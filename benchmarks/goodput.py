@@ -15,7 +15,7 @@ from __future__ import annotations
 import numpy as np
 
 from repro.core import (
-    FIELDS_5TUPLE, PrimeSpraying, compile_fabric, flow_fields_matrix,
+    FIELDS_5TUPLE, PrimeSpraying, SimSpec, compile_fabric, flow_fields_matrix,
     flowlet_exposure, max_min_rates, reordering_efficiency, simulate_paths,
     throughput_from_result,
 )
@@ -30,7 +30,7 @@ def run() -> None:
     field_mat = flow_fields_matrix(flows, FIELDS_5TUPLE)
 
     res = simulate_paths(comp, flows, seeds,
-                         strategy=PrimeSpraying(flowlets=8),
+                         spec=SimSpec(strategy=PrimeSpraying(flowlets=8)),
                          field_matrix=field_mat)
     flowlet_rates = max_min_rates(res)
 

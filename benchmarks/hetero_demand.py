@@ -37,11 +37,10 @@ import numpy as np
 
 from repro.core import (
     DEMAND_BYTES, DEMAND_UNIFORM, ELEPHANT_MIN_BYTES, FIELDS_5TUPLE,
-    CongestionAware, EcmpStrategy, PrimeSpraying, WaveCongestionAware,
-    build_multipod_fabric,
-    build_paper_testbed, compile_fabric, fim_from_counts, flow_fields_matrix,
-    multipod_llm_workload, paper_testbed_llm_workload, simulate_paths,
-    throughput_from_result,
+    CongestionAware, EcmpStrategy, PrimeSpraying, SimSpec, WaveCongestionAware,
+    build_multipod_fabric, build_paper_testbed, compile_fabric,
+    fim_from_counts, flow_fields_matrix, multipod_llm_workload,
+    paper_testbed_llm_workload, simulate_paths, throughput_from_result,
 )
 from .common import bench_seeds, emit, timeit
 
@@ -89,9 +88,9 @@ def run() -> None:
 
                 def sim():
                     res = simulate_paths(comp, flows, seeds,
-                                         strategy=factory(),
-                                         field_matrix=field_mat,
-                                         demand_mode=demand_mode)
+                                         spec=SimSpec(strategy=factory(),
+                                                      demand_mode=demand_mode),
+                                         field_matrix=field_mat)
                     state["res"] = res
                     state["fims"] = fim_from_counts(
                         res.link_flow_counts(), comp)[0]

@@ -19,8 +19,8 @@ import dataclasses
 import numpy as np
 
 from repro.core import (
-    ELEPHANT_MIN_BYTES, PrimeSpraying, compile_fabric, flowlet_exposure,
-    max_min_rates, monte_carlo_throughput, simulate_paths,
+    ELEPHANT_MIN_BYTES, PrimeSpraying, SimSpec, compile_fabric,
+    flowlet_exposure, max_min_rates, monte_carlo_throughput, simulate_paths,
 )
 from .common import bench_seeds, emit, paper_setup, timeit
 
@@ -42,13 +42,14 @@ def run() -> None:
     # the exposure inputs are engine-independent (1e-9-identical rates);
     # prep once on the host engine so each engine's row times ONLY its
     # own exposure stage
-    res_s = simulate_paths(comp, flows, seeds, strategy=spray)
+    res_s = simulate_paths(comp, flows, seeds, spec=SimSpec(strategy=spray))
     rates_s = max_min_rates(res_s)
     e2e: dict[str, float] = {}
 
     for engine in ("numpy", "jax"):
         def walk():
-            return simulate_paths(comp, flows, seeds, engine=engine)
+            return simulate_paths(comp, flows, seeds,
+                                  spec=SimSpec(engine=engine))
 
         walk()                                   # warm-up (jit compile)
         t = timeit(walk, repeats=1)
@@ -74,8 +75,8 @@ def run() -> None:
 
         def end_to_end():
             return monte_carlo_throughput(comp, flows, seeds,
-                                          transport="roce-nack",
-                                          engine=engine)
+                                          spec=SimSpec(transport="roce-nack",
+                                                       engine=engine))
 
         end_to_end()
         t = timeit(end_to_end, repeats=1)

@@ -14,9 +14,9 @@ import numpy as np
 
 from repro.core import (
     FIELDS_5TUPLE, FIELDS_IP_PAIR, FIELDS_VXLAN, EcmpRouting, FlowTracer,
-    bipartite_pairs, build_multipod_fabric,
-    compile_fabric, fim, flow_fields_matrix, monte_carlo_fim, nic_ip,
-    simulate_paths, static_route_assignment, synthesize_flows,
+    SimSpec, bipartite_pairs, build_multipod_fabric, compile_fabric, fim,
+    flow_fields_matrix, monte_carlo_fim, nic_ip, simulate_paths,
+    static_route_assignment, synthesize_flows,
 )
 from .common import bench_seeds, emit, paper_setup
 
@@ -30,7 +30,7 @@ def _sweep(tag: str, fab, wl, flows) -> None:
     seeds = np.arange(NUM_SEEDS)
     for name, mode in MODES.items():
         t0 = time.perf_counter()
-        mc = monte_carlo_fim(comp, flows, seeds, fields=mode)
+        mc = monte_carlo_fim(comp, flows, seeds, spec=SimSpec(fields=mode))
         dt = time.perf_counter() - t0
         v = mc.aggregate
         emit(f"mc_{tag}_{name}", dt / NUM_SEEDS * 1e6,

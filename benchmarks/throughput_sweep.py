@@ -21,7 +21,7 @@ import time
 import numpy as np
 
 from repro.core import (
-    FIELDS_5TUPLE, EcmpRouting, FlowTracer, compile_fabric,
+    FIELDS_5TUPLE, EcmpRouting, FlowTracer, SimSpec, compile_fabric,
     flow_fields_matrix, per_pair_throughput, simulate_paths,
     throughput_from_result,
 )
@@ -94,9 +94,9 @@ def run() -> None:
     # regression guard catches it slipping back toward per-hop scatters.
     # Median-of-repeats: the loop is Python-overhead-bound and a single
     # shot swings >2x under scheduler noise at smoke shapes
-    t_cong = timeit(lambda: simulate_paths(
-        comp, flows, seeds, strategy="congestion-aware",
-        field_matrix=fields))
+    cong = SimSpec(strategy="congestion-aware")
+    t_cong = timeit(lambda: simulate_paths(comp, flows, seeds, spec=cong,
+                                           field_matrix=fields))
     emit("tp_congestion_route", t_cong / num_seeds * 1e6,
          f"total_s={t_cong:.3f} per_flow_us={t_cong / len(flows) * 1e6:.0f} "
          f"seeds={num_seeds} flows={len(flows)}")

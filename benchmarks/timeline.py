@@ -37,9 +37,10 @@ def run() -> None:
     _, flows, _, schedule = paper_testbed_llm_schedule()
     comp = compile_fabric(build_paper_testbed())
     state: dict = {}
+    spec = SimSpec(demand_mode="bytes", transport="roce-nack",
+                   strategy="prime-spray-elephant")
     elapsed = timeit(lambda: state.update(tl=simulate_timeline(
-        comp, flows, schedule, seeds, demand_mode="bytes",
-        transport="roce-nack", strategy="prime-spray-elephant")))
+        comp, flows, schedule, seeds, spec=spec)))
     tl = state["tl"]
     emit("timeline_phased_engine", elapsed / num_seeds * 1e6,
          f"fim={tl.fim.mean():.2f} goodput={tl.goodput.mean():.2f} "
@@ -64,9 +65,9 @@ def run() -> None:
     sched = [TimelineStep("grad-all-reduce", (CH_GRAD_AR,)),
              TimelineStep("moe-all-to-all", (CH_MOE_A2A,))]
     phased = simulate_timeline(mcomp, sub, sched, seeds,
-                               demand_mode="bytes")
+                               spec=SimSpec(demand_mode="bytes"))
     merged = simulate_timeline(mcomp, sub, [merged_step(sched)], seeds,
-                               demand_mode="bytes")
+                               spec=SimSpec(demand_mode="bytes"))
     emit("timeline_merged_vs_phased_fim", 0.0,
          f"merged={merged.fim.mean():.2f} phased={phased.fim.mean():.2f} "
          f"overstatement={merged.fim.mean() / phased.fim.mean():.3f}x "
@@ -89,10 +90,12 @@ def run() -> None:
     fab, _, bflows = paper_setup()
     bcomp = compile_fabric(fab)
     static = throughput_from_result(
-        simulate_paths(bcomp, bflows, seeds, strategy=PrimeSpraying(8)),
+        simulate_paths(bcomp, bflows, seeds,
+                       spec=SimSpec(strategy=PrimeSpraying(8))),
         transport="roce-nack")
     adaptive = throughput_from_result(
-        simulate_paths(bcomp, bflows, seeds, strategy=AdaptiveSpraying(8)),
+        simulate_paths(bcomp, bflows, seeds,
+                       spec=SimSpec(strategy=AdaptiveSpraying(8))),
         transport="roce-nack")
     emit("timeline_adaptive_vs_static_goodput", 0.0,
          f"static={static.goodput.mean():.2f} "

@@ -26,7 +26,7 @@ from __future__ import annotations
 import numpy as np
 
 from repro.core import (
-    CongestionAware, WaveCongestionAware, compile_fabric, fim_vector,
+    CongestionAware, SimSpec, WaveCongestionAware, compile_fabric, fim_vector,
     simulate_paths,
 )
 from .common import bench_seeds, emit, paper_setup, timeit
@@ -42,7 +42,8 @@ def run() -> None:
     shape = f"seeds={NUM_SEEDS} flows={len(flows)}"
 
     def seq():
-        return simulate_paths(comp, flows, seeds, strategy=CongestionAware())
+        return simulate_paths(comp, flows, seeds,
+                              spec=SimSpec(strategy=CongestionAware()))
 
     t_seq = timeit(seq)
     fim_seq = fim_vector(seq()).mean()
@@ -53,8 +54,8 @@ def run() -> None:
     for engine in ("numpy", "jax"):
         def wave():
             return simulate_paths(comp, flows, seeds,
-                                  strategy=WaveCongestionAware(),
-                                  engine=engine)
+                                  spec=SimSpec(strategy=WaveCongestionAware(),
+                                               engine=engine))
 
         wave()                                  # warm-up (jit compile)
         t_wave[engine] = timeit(wave)

@@ -2,7 +2,7 @@
 
     python chip_smoke.py [--seed N]
 
-Drives ``engine="jax"`` through the front ends a user calls
+Drives ``SimSpec(engine="jax")`` through the front ends a user calls
 (``monte_carlo_fim``, ``monte_carlo_throughput``, ``simulate_paths`` /
 ``throughput_from_result``, ``simulate_timeline``) and the compiled
 flowhash Pallas kernel, and checks every result against the numpy engine
@@ -142,28 +142,31 @@ def build_phases(seed: int, backend: str):
 
     # -- paper testbed, fused plain-ECMP front ends ----------------------
     def fim_check(out):
-        r = monte_carlo_fim(comp, flows, sub, **ref)
+        r = monte_carlo_fim(comp, flows, sub, spec=SimSpec(**ref))
         d = fim_diff(out, r, SUBSAMPLE)
-        a = simulate_paths(comp, flows, sub, **jx).link_flow_counts()
-        b = simulate_paths(comp, flows, sub, **ref).link_flow_counts()
+        a = simulate_paths(comp, flows, sub,
+                           spec=SimSpec(**jx)).link_flow_counts()
+        b = simulate_paths(comp, flows, sub,
+                           spec=SimSpec(**ref)).link_flow_counts()
         return d if np.array_equal(a, b) else float("inf")
 
     phases.append((
         "paper-fim", f"flows={len(flows)} seeds={NUM_SEEDS}", backend,
         {"walk", "counts_fn", "fim_fn"},
-        lambda: monte_carlo_fim(comp, flows, seeds, **jx), fim_check, 1e-9))
+        lambda: monte_carlo_fim(comp, flows, seeds, spec=SimSpec(**jx)),
+        fim_check, 1e-9))
 
     def tp_check(out):
-        r = monte_carlo_throughput(comp, flows, sub, transport="roce-nack",
-                                   **ref)
+        r = monte_carlo_throughput(
+            comp, flows, sub, spec=SimSpec(transport="roce-nack", **ref))
         return max(max_abs(out.rates[:, :SUBSAMPLE], r.rates),
                    max_abs(out.goodput[:, :SUBSAMPLE], r.goodput))
 
     phases.append((
         "paper-throughput", f"flows={len(flows)} seeds={NUM_SEEDS}", backend,
         {"walk", "fill"},
-        lambda: monte_carlo_throughput(comp, flows, seeds,
-                                       transport="roce-nack", **jx),
+        lambda: monte_carlo_throughput(
+            comp, flows, seeds, spec=SimSpec(transport="roce-nack", **jx)),
         tp_check, 1e-6))
 
     # -- paper testbed, demand-aware spraying (non-fused route) ---------
@@ -174,7 +177,8 @@ def build_phases(seed: int, backend: str):
                  transport="roce-nack")
 
     def spray_check(out):
-        r = monte_carlo_throughput(comp, big, sub, **spray, **ref)
+        r = monte_carlo_throughput(comp, big, sub,
+                                   spec=SimSpec(**spray, **ref))
         return max(max_abs(out.rates[:, :SUBSAMPLE], r.rates),
                    max_abs(out.goodput[:, :SUBSAMPLE], r.goodput))
 
@@ -182,14 +186,14 @@ def build_phases(seed: int, backend: str):
         "paper-spray", f"flows={len(big)} seeds={SPRAY_SEEDS}", backend,
         {"walk", "fill", "exposure_fn"},
         lambda: monte_carlo_throughput(comp, big, seeds[:SPRAY_SEEDS],
-                                       **spray, **jx),
+                                       spec=SimSpec(**spray, **jx)),
         spray_check, 1e-6))
 
     # -- exact splitmix64 walk: emulated uint64 on the chip --------------
     few = flows[:256]
 
     def exact_check(out):
-        r = simulate_paths(comp, few, sub, hash_backend=EXACT)
+        r = simulate_paths(comp, few, sub, spec=SimSpec(hash_backend=EXACT))
         same = out.link_ids.shape == r.link_ids.shape and np.array_equal(
             out.link_ids, r.link_ids)
         return 0.0 if same else float("inf")
@@ -197,14 +201,15 @@ def build_phases(seed: int, backend: str):
     phases.append((
         "exact-walk", f"flows={len(few)} seeds={SUBSAMPLE}", EXACT,
         {"walk"},
-        lambda: simulate_paths(comp, few, sub, hash_backend=EXACT, **jx),
+        lambda: simulate_paths(comp, few, sub,
+                               spec=SimSpec(hash_backend=EXACT, **jx)),
         exact_check, 0.0))
 
     # -- wave placement above its depth cutover: the jax wave walk -------
     wave = dict(strategy="wave-congestion-aware")
 
     def wave_check(out):
-        r = simulate_paths(comp, flows, sub, **wave, **ref)
+        r = simulate_paths(comp, flows, sub, spec=SimSpec(**wave, **ref))
         same = out.link_ids.shape == r.link_ids.shape and np.array_equal(
             out.link_ids, r.link_ids)
         return 0.0 if same else float("inf")
@@ -212,7 +217,7 @@ def build_phases(seed: int, backend: str):
     phases.append((
         "paper-wave", f"flows={len(flows)} seeds={SUBSAMPLE}", backend,
         {"walk", "wave_walk"},
-        lambda: simulate_paths(comp, flows, sub, **wave, **jx),
+        lambda: simulate_paths(comp, flows, sub, spec=SimSpec(**wave, **jx)),
         wave_check, 0.0))
 
     # -- multipod LLM schedule, event-timed ------------------------------
@@ -249,15 +254,17 @@ def build_phases(seed: int, backend: str):
     ssub = seeds[:SCALE_SUBSAMPLE]
 
     def scale_run():
-        return (monte_carlo_fim(comp, scale, seeds, **jx),
+        return (monte_carlo_fim(comp, scale, seeds, spec=SimSpec(**jx)),
                 monte_carlo_throughput(
-                    comp, scale, seeds[:SCALE_THROUGHPUT_SEEDS], **jx))
+                    comp, scale, seeds[:SCALE_THROUGHPUT_SEEDS],
+                    spec=SimSpec(**jx)))
 
     def scale_check(out):
         fim, tp = out
-        d = fim_diff(fim, monte_carlo_fim(comp, scale, ssub, **ref),
+        d = fim_diff(fim, monte_carlo_fim(comp, scale, ssub,
+                                          spec=SimSpec(**ref)),
                      SCALE_SUBSAMPLE)
-        r = monte_carlo_throughput(comp, scale, ssub, **ref)
+        r = monte_carlo_throughput(comp, scale, ssub, spec=SimSpec(**ref))
         return max(d, max_abs(tp.rates[:, :SCALE_SUBSAMPLE], r.rates))
 
     phases.append((

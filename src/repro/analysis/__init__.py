@@ -3,9 +3,8 @@
 The correctness story of this codebase rests on invariants that no
 general-purpose linter knows about: the numpy and jax engines must stay
 bit-identical, jitted code must not retrace or host-sync, registries
-must be populated at import time, the ``SimSpec`` API surface must stay
-consistent across all four Monte-Carlo front ends, and benchmark rows
-must stay in lockstep with the committed smoke baseline.  ``flowcheck``
+must be populated at import time, and benchmark rows must stay in
+lockstep with the committed smoke baseline.  ``flowcheck``
 encodes each of those contracts as an AST-level rule family and fails CI
 on *new* violations (a committed ``flowcheck_baseline.json`` suppresses
 — with justification — the pre-existing ones).

@@ -1,9 +1,7 @@
 """Rule registry: one module per rule family, each exposing
 ``run(ctx) -> list[Finding]`` plus the ``FT-*`` rule ids it can emit."""
 
-from . import (
-    api_surface, bench_coverage, dtype_drift, jit_retrace, registry_hygiene,
-)
+from . import bench_coverage, dtype_drift, jit_retrace, registry_hygiene
 
 #: (family name, module) in report order.  Every module contributes its
 #: rule ids via a module-level ``RULE_IDS`` tuple.
@@ -11,7 +9,6 @@ FAMILIES = (
     ("jit-retrace", jit_retrace),
     ("dtype-drift", dtype_drift),
     ("registry-hygiene", registry_hygiene),
-    ("api-surface", api_surface),
     ("bench-coverage", bench_coverage),
 )
 

@@ -1,6 +1,6 @@
 """FT-REG: registry hygiene for strategies, transports, and channels.
 
-``simulate_paths(strategy="wave-congestion-aware")``,
+``SimSpec(strategy="wave-congestion-aware")``,
 ``transport="roce-nack"``, and schedule validation against the channel
 vocabulary all assume the registries are fully populated the moment the
 module is imported.  Three ways that assumption rots:

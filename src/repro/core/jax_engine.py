@@ -42,14 +42,13 @@ from collections.abc import Sequence
 
 import numpy as np
 
-from .compile_fabric import CompiledFabric, compile_fabric
-from .ecmp import FIELDS_5TUPLE, HASH_INIT, flow_fields_matrix
-from .fabric import Fabric
+from .compile_fabric import CompiledFabric
+from .ecmp import HASH_INIT, flow_fields_matrix
 from .flows import Flow, WorkloadDescription
 from .spans import count, enabled, span
 from .vector_sim import (
-    DEMAND_UNIFORM, EXACT, MURMUR, MonteCarloFim, VectorTraceResult,
-    flow_demand_weights, normalize_seeds, resolve_flows,
+    ENGINE_JAX, ENGINE_NUMPY, EXACT, MURMUR, MonteCarloFim, SimSpec,
+    VectorTraceResult, flow_demand_weights, normalize_seeds, resolve_flows,
 )
 
 __all__ = [
@@ -59,9 +58,6 @@ __all__ = [
     "jax_batched_max_min", "jax_flowlet_exposure",
     "fused_monte_carlo_fim", "fused_monte_carlo_throughput",
 ]
-
-ENGINE_NUMPY = "numpy"
-ENGINE_JAX = "jax"
 
 # Seeds per device pass in the fused front ends (``seed_chunk``), sized
 # for one TPU v5e (16 GB HBM).  A pass over N flows x Sc seeds holds the
@@ -1028,45 +1024,43 @@ def _fused_walk_counts(comp, flows, endpoints, field_mat, seeds_u64, *,
         yield s1 - s0, counts
 
 
-def _fused_prep(fabric, workload, seeds, fields, field_matrix, demand_mode):
-    """The host inputs of a fused sweep, in the ``prep`` span: compiled
-    fabric, flows, uint64 seeds, demand weights, hash fields and flow
-    endpoints."""
+def _fused_prep(comp, workload, seeds, spec, field_matrix):
+    """The host inputs of a fused sweep, in the ``prep`` span: flows,
+    uint64 seeds, demand weights, hash fields and flow endpoints."""
     with span("prep"):
-        comp = (fabric if isinstance(fabric, CompiledFabric)
-                else compile_fabric(fabric))
         flows = resolve_flows(comp, workload)
         seeds_u64 = normalize_seeds(seeds)
         if len(flows) == 0:
             raise ValueError("simulate_paths needs at least one flow")
-        flow_demand = flow_demand_weights(flows, demand_mode)
+        flow_demand = flow_demand_weights(flows, spec.demand_mode)
         field_mat = (field_matrix if field_matrix is not None
-                     else flow_fields_matrix(flows, fields))
+                     else flow_fields_matrix(flows, spec.fields))
         endpoints = comp.flow_endpoint_ids(flows)
     count("flows", len(flows))
-    return comp, flows, seeds_u64, flow_demand, field_mat, endpoints
+    return flows, seeds_u64, flow_demand, field_mat, endpoints
 
 
 def fused_monte_carlo_fim(
-    fabric: Fabric | CompiledFabric,
+    comp: CompiledFabric,
     workload: WorkloadDescription | Sequence[Flow],
     seeds: Sequence[int] | np.ndarray,
+    spec: SimSpec,
     *,
-    fields: str = FIELDS_5TUPLE,
-    hash_backend: str = EXACT,
     layers: Sequence[str] | None = None,
     only_used_leaves: bool = False,
-    demand_mode: str = DEMAND_UNIFORM,
-    max_hops: int = 16,
     field_matrix: np.ndarray | None = None,
 ) -> MonteCarloFim:
-    """Plain-ECMP Monte-Carlo FIM with walk + counts + FIM on device."""
-    comp, flows, seeds_u64, flow_demand, field_mat, endpoints = _fused_prep(
-        fabric, workload, seeds, fields, field_matrix, demand_mode)
+    """Plain-ECMP Monte-Carlo FIM with walk + counts + FIM on device.
+
+    ``spec`` is the resolved ``SimSpec`` of a call that
+    ``vector_sim.runs_fused``; its ``fields``, ``hash_backend``,
+    ``demand_mode`` and ``max_hops`` are read here, whole."""
+    flows, seeds_u64, flow_demand, field_mat, endpoints = _fused_prep(
+        comp, workload, seeds, spec, field_matrix)
     with _x64():
         chunks = _fused_walk_counts(
             comp, flows, endpoints, field_mat, seeds_u64,
-            hash_backend=hash_backend, max_hops=max_hops,
+            hash_backend=spec.hash_backend, max_hops=spec.max_hops,
             flow_demand=flow_demand)
         agg, per_layer = _fim_chunks(
             comp, chunks, layers=layers, only_used_leaves=only_used_leaves)
@@ -1076,35 +1070,34 @@ def fused_monte_carlo_fim(
 
 
 def fused_monte_carlo_throughput(
-    fabric: Fabric | CompiledFabric,
+    comp: CompiledFabric,
     workload: WorkloadDescription | Sequence[Flow],
     seeds: Sequence[int] | np.ndarray,
+    spec: SimSpec,
     *,
-    fields: str = FIELDS_5TUPLE,
-    hash_backend: str = EXACT,
-    demand_mode: str = DEMAND_UNIFORM,
-    transport=None,
-    max_hops: int = 16,
     field_matrix: np.ndarray | None = None,
 ):
     """Plain-ECMP Monte-Carlo throughput with walk + fill on device.
 
-    Single-path ECMP has zero flowlet exposure, so (exactly like the
-    numpy fast path) goodput is the raw rate grid under every transport
-    profile — the exposure/efficiency stages engage through
+    ``spec`` is the resolved ``SimSpec`` of a call that
+    ``vector_sim.runs_fused``; its ``fields``, ``hash_backend``,
+    ``demand_mode``, ``max_hops`` and ``transport`` are read here,
+    whole.  Single-path ECMP has zero flowlet exposure, so (exactly like
+    the numpy fast path) goodput is the raw rate grid under every
+    transport profile — the exposure/efficiency stages engage through
     ``throughput_from_result(engine="jax")`` for multi-path strategies.
     """
     from .reordering import resolve_transport
     from .vector_throughput import MonteCarloThroughput, pair_rate_matrix
-    comp, flows, seeds_u64, flow_demand, field_mat, endpoints = _fused_prep(
-        fabric, workload, seeds, fields, field_matrix, demand_mode)
-    profile = resolve_transport(transport)
+    flows, seeds_u64, flow_demand, field_mat, endpoints = _fused_prep(
+        comp, workload, seeds, spec, field_matrix)
+    profile = resolve_transport(spec.transport)
     rates = np.empty((len(flows), len(seeds_u64)))
     with _x64():
         jax = _jx()[0]
         for s0, s1, ids in _walked_chunks(
                 comp, flows, endpoints, field_mat, seeds_u64,
-                hash_backend=hash_backend, max_hops=max_hops):
+                hash_backend=spec.hash_backend, max_hops=spec.max_hops):
             with span("fill.run"):
                 chunk, rounds = jax.block_until_ready(
                     _fill_device(ids, comp.link_gbps, flow_demand))

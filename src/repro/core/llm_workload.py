@@ -21,8 +21,9 @@ Two committed scenarios anchor benchmarks and tests:
   pods, intra-pod edges ride the deterministic ICI torus and only
   pod-crossing edges enter the Clos fabric.
 
-Feed the flows to ``simulate_paths(..., demand_mode="bytes")`` (or the
-Monte-Carlo front ends) to weight FIM and max-min throughput by volume.
+Feed the flows to ``simulate_paths(..., spec=SimSpec(demand_mode="bytes"))``
+(or the Monte-Carlo front ends) to weight FIM and max-min throughput by
+volume.
 """
 
 from __future__ import annotations

@@ -94,11 +94,12 @@ class EcmpStrategy(RoutingStrategy):
     def route(self, comp, flows, seeds_u64, *, fields=FIELDS_5TUPLE,
               hash_backend=EXACT, max_hops=16, field_matrix=None,
               demand_mode=DEMAND_UNIFORM, engine=ENGINE_NUMPY):
-        from .vector_sim import simulate_paths
-        return simulate_paths(comp, flows, seeds_u64, fields=fields,
-                              hash_backend=hash_backend, max_hops=max_hops,
-                              field_matrix=field_matrix,
-                              demand_mode=demand_mode, engine=engine)
+        from .vector_sim import SimSpec, simulate_paths
+        return simulate_paths(
+            comp, flows, seeds_u64, field_matrix=field_matrix,
+            spec=SimSpec(fields=fields, hash_backend=hash_backend,
+                         max_hops=max_hops, demand_mode=demand_mode,
+                         engine=engine))
 
 
 def _balanced_parts(k: int) -> tuple[int, ...]:

@@ -16,7 +16,7 @@ This module adds the time axis:
 
 * a schedule is a list of ``TimelineStep``s, each naming the collective
   *channels* (``CollectiveOp.channel_id``) active during that step and a
-  relative ``duration`` (``weight`` is the deprecated alias);
+  relative ``duration``;
 * ``simulate_timeline`` partitions one flow list by channel, routes each
   step's active flow set independently over ONE shared
   ``compile_fabric`` pass, and scores each step with the *same* engines
@@ -74,7 +74,6 @@ from __future__ import annotations
 
 import dataclasses
 import re
-import warnings
 from collections.abc import Sequence
 
 import numpy as np
@@ -85,9 +84,8 @@ from .flows import Flow, WorkloadDescription
 from .spans import span
 from .strategies import AdaptiveSpraying
 from .vector_sim import (
-    MonteCarloFim, SimSpec, TIMING_EVENT, TIMING_STATIC, _UNSET,
-    fim_from_counts, resolve_flows, resolve_spec, segment_reduce,
-    simulate_paths,
+    MonteCarloFim, SimSpec, TIMING_EVENT, TIMING_STATIC, fim_from_counts,
+    resolve_flows, resolve_spec, segment_reduce, simulate_paths,
 )
 from .vector_throughput import (
     MonteCarloThroughput, departure_fill, max_min_rates,
@@ -98,8 +96,6 @@ _CHANNEL_RE = re.compile(r"#ch(\d+)$")
 
 #: bytes -> gigabits (the unit ``departure_fill`` drains at Gb/s rates)
 _GBITS_PER_BYTE = 8e-9
-
-_WEIGHT_ALIAS_WARNED = False
 
 
 # ---------------------------------------------------------------------------
@@ -148,7 +144,7 @@ def channel_name(channel_id: int) -> str:
 # ---------------------------------------------------------------------------
 
 
-@dataclasses.dataclass(frozen=True, slots=True, init=False)
+@dataclasses.dataclass(frozen=True, slots=True)
 class TimelineStep:
     """One schedule step: the channels on the wire and how long they hold it.
 
@@ -158,47 +154,21 @@ class TimelineStep:
     spans phases).  ``duration`` is the step's relative duration under
     ``timing="static"`` — see the module docstring for why it is not a
     byte share — and is ignored under ``timing="event"``, where the
-    duration is derived from the routed goodput.  ``weight=`` is
-    accepted as a deprecated alias of ``duration=`` (one warning per
-    process; passing both raises)."""
+    duration is derived from the routed goodput."""
 
     name: str
     channels: tuple[int, ...]
-    duration: float
+    duration: float = 1.0
 
-    def __init__(self, name: str, channels: Sequence[int],
-                 duration: float | None = None, *,
-                 weight: float | None = None):
-        if weight is not None:
-            if duration is not None:
-                raise TypeError(
-                    "pass duration= only (weight= is its deprecated "
-                    "alias), not both")
-            global _WEIGHT_ALIAS_WARNED
-            if not _WEIGHT_ALIAS_WARNED:
-                warnings.warn(
-                    "TimelineStep(weight=...) is deprecated; the field "
-                    "is named duration (identical semantics: relative "
-                    "step length under timing='static')",
-                    DeprecationWarning, stacklevel=2)
-                _WEIGHT_ALIAS_WARNED = True
-            duration = weight
-        object.__setattr__(self, "name", name)
-        object.__setattr__(self, "channels", tuple(channels))
-        object.__setattr__(self, "duration",
-                           1.0 if duration is None else float(duration))
+    def __post_init__(self):
+        object.__setattr__(self, "channels", tuple(self.channels))
+        object.__setattr__(self, "duration", float(self.duration))
         if not self.channels:
             raise ValueError(f"step {self.name!r} has no channels")
         if not self.duration > 0:
             raise ValueError(
                 f"step {self.name!r} duration must be > 0, "
                 f"got {self.duration}")
-
-    @property
-    def weight(self) -> float:
-        """Deprecated alias of ``duration`` (kept so existing readers of
-        the old field name keep working; prefer ``duration``)."""
-        return self.duration
 
 
 def merged_step(schedule: Sequence[TimelineStep],
@@ -457,33 +427,23 @@ def simulate_timeline(
     seeds: Sequence[int] | np.ndarray,
     *,
     spec: SimSpec | None = None,
-    fields=_UNSET,
-    hash_backend=_UNSET,
-    strategy=_UNSET,
-    demand_mode=_UNSET,
-    transport=_UNSET,
     layers: Sequence[str] | None = None,
     only_used_leaves: bool = False,
-    engine=_UNSET,
-    timing=_UNSET,
-    max_hops=_UNSET,
 ) -> TimelineResult:
     """Simulate a phase schedule step by step over one compiled fabric.
 
     Every step routes ONLY its active flows (the others are off the wire
     — that is the fix), through the identical ``simulate_paths`` →
     ``fim_from_counts`` → ``throughput_from_result`` pipeline the merged
-    front ends run, under the same ``SimSpec`` contract — pass one as
-    ``spec=`` or the legacy ``strategy`` / ``demand_mode`` /
-    ``transport`` / ``engine`` / ``timing`` kwargs, not both
-    (``strategy`` accepts a registry name string or instance, resolved
-    once up front and shared by every step; ``engine="jax"`` routes
-    every step through the device engine).  The compiled fabric is
-    shared across steps; a ``CompiledFabric`` passes through unchanged,
-    so sweeps over schedules or strategies pay compilation once.
+    front ends run, under the same ``spec=`` (``spec.strategy`` accepts
+    a registry name string or instance, resolved once up front and
+    shared by every step; ``engine="jax"`` routes every step through
+    the device engine).  The compiled fabric is shared across steps; a
+    ``CompiledFabric`` passes through unchanged, so sweeps over
+    schedules or strategies pay compilation once.
 
-    ``timing="static"`` (default) weights the totals by the exogenous
-    ``TimelineStep.duration`` constants.  ``timing="event"`` derives
+    ``timing="static"`` (the default) weights the totals by the
+    exogenous ``TimelineStep.duration`` constants.  ``timing="event"`` derives
     each step's duration from the routed goodput instead — flows depart
     as their bytes finish (``departure_fill``), the step ends with its
     slowest flow — and fills in the absolute time axis on the result
@@ -501,10 +461,7 @@ def simulate_timeline(
     unlabeled flows, and steps whose channels no flow carries all raise
     — nothing is silently dropped or silently idle.
     """
-    s = resolve_spec(spec, dict(
-        fields=fields, hash_backend=hash_backend, strategy=strategy,
-        demand_mode=demand_mode, transport=transport, engine=engine,
-        timing=timing, max_hops=max_hops))
+    s = resolve_spec(spec)
     comp = (fabric if isinstance(fabric, CompiledFabric)
             else compile_fabric(fabric))
     flows = resolve_flows(comp, workload)

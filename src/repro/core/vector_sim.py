@@ -128,12 +128,6 @@ def flow_demand_weights(flows: Sequence[Flow], demand_mode: str) -> np.ndarray:
 # SimSpec: the one validated description of *how* to simulate
 # ---------------------------------------------------------------------------
 
-# Legacy-kwarg sentinel: front ends default every per-simulation kwarg to
-# this so "not passed" is distinguishable from "passed its default" — a
-# caller who mixes an explicit kwarg with ``spec=`` gets a loud error
-# instead of a silent winner.
-_UNSET = object()
-
 _KNOWN_FIELDS = (FIELDS_5TUPLE, FIELDS_VXLAN, FIELDS_IP_PAIR)
 
 TIMING_STATIC = "static"  # exogenous step durations (TimelineStep.duration)
@@ -149,14 +143,12 @@ class SimSpec:
 
     The four Monte-Carlo front ends (``simulate_paths``,
     ``monte_carlo_fim``, ``monte_carlo_throughput``,
-    ``simulate_timeline``) historically re-declared the same sprawling
-    kwarg set with per-function validation; a ``SimSpec`` carries it
-    once and ``resolve()`` validates and normalizes everything in one
-    place.  Front ends accept ``spec=SimSpec(...)`` *or* the legacy
-    kwargs (which build a SimSpec internally); passing both raises.
+    ``simulate_timeline``) take it as ``spec=`` and nothing else says
+    how they simulate; ``resolve()`` validates and normalizes every
+    field in one place.
 
-    Fields (all optional — the zero-argument ``SimSpec()`` is the
-    historical default everywhere):
+    Fields (all optional — the zero-argument ``SimSpec()``, which is
+    also what ``spec=None`` means, is the default everywhere):
 
     * ``strategy`` — ``None`` (per-flow ECMP), a registry name string
       (``"wave-congestion-aware"``), or a ``RoutingStrategy`` instance;
@@ -179,9 +171,11 @@ class SimSpec:
       finish — core/timeline.py).  Snapshot front ends ignore it.
 
     ``resolve()`` is idempotent, so a resolved spec can be handed from
-    front end to front end without re-validating work: names become
-    registry instances, ``hash_backend=None`` becomes the engine's
-    concrete backend, and every enum-ish field is range-checked.
+    front end to front end, and whole into the fused device pipeline
+    (``jax_engine.fused_monte_carlo_*``), without re-validating work:
+    names become registry instances, ``hash_backend=None`` becomes the
+    engine's concrete backend, and every enum-ish field is
+    range-checked.
     Per-*call* inputs (the fabric, flows, seeds, a precomputed
     ``field_matrix``, FIM layer selections) stay arguments — a spec
     describes the simulation contract, not one invocation's data."""
@@ -228,24 +222,16 @@ class SimSpec:
             max_hops=int(self.max_hops))
 
 
-def resolve_spec(spec: SimSpec | None, kwargs: dict) -> SimSpec:
-    """Front-end glue: the resolved ``SimSpec`` from ``spec=`` OR legacy
-    kwargs (values still ``_UNSET`` are dropped, so dataclass defaults
-    apply).  Mixing both raises — explicitly, naming the kwargs — and a
-    non-SimSpec ``spec`` fails as a type error rather than an attribute
+def resolve_spec(spec: SimSpec | None) -> SimSpec:
+    """Front-end glue: the resolved ``spec=`` (``None`` is ``SimSpec()``).
+    A non-SimSpec ``spec`` fails as a type error rather than an attribute
     error three calls deep."""
-    passed = {k: v for k, v in kwargs.items() if v is not _UNSET}
-    if spec is not None:
-        if passed:
-            raise ValueError(
-                "pass either spec= or the per-simulation kwargs, not both "
-                f"(got spec= together with {sorted(passed)})")
-        if not isinstance(spec, SimSpec):
-            raise TypeError(
-                f"spec must be a SimSpec, got {type(spec).__name__}")
-        s = spec.resolve()
-    else:
-        s = SimSpec(**passed).resolve()
+    if spec is None:
+        spec = SimSpec()
+    elif not isinstance(spec, SimSpec):
+        raise TypeError(
+            f"spec must be a SimSpec, got {type(spec).__name__}")
+    s = spec.resolve()
     if contracts_enabled():
         check_spec(s)
     return s
@@ -543,25 +529,15 @@ def simulate_paths(
     seeds: Sequence[int] | np.ndarray,
     *,
     spec: SimSpec | None = None,
-    fields=_UNSET,
-    hash_backend=_UNSET,
-    max_hops=_UNSET,
     field_matrix: np.ndarray | None = None,
-    strategy=_UNSET,
-    demand_mode=_UNSET,
-    engine=_UNSET,
 ) -> VectorTraceResult:
     """Walk every flow through the fabric under every seed, vectorized.
 
-    How to simulate is described by a ``SimSpec`` — pass one as
-    ``spec=`` or pass the legacy kwargs (``strategy=``,
-    ``demand_mode=``, ``engine=``, ``hash_backend=``, ``fields=``,
-    ``max_hops=``), which build the spec internally; mixing both
-    raises.  See ``SimSpec`` for the field contracts.
-
-    The default is per-flow ECMP, bit-identical to ``EcmpRouting`` +
-    ``FlowTracer``; ``strategy`` (name string or instance) routes the
-    whole simulation through that strategy's vectorized implementation
+    How to simulate is described by ``spec`` (see ``SimSpec`` for the
+    field contracts; ``None`` is ``SimSpec()``).  The default is
+    per-flow ECMP, bit-identical to ``EcmpRouting`` + ``FlowTracer``; a
+    ``spec.strategy`` (name string or instance) routes the whole
+    simulation through that strategy's vectorized implementation
     instead (the result may carry flowlet columns — see
     ``VectorTraceResult``).
 
@@ -570,9 +546,7 @@ def simulate_paths(
     table skip the per-flow CRC pass (per-call data, so it stays an
     argument rather than a spec field).
     """
-    s = resolve_spec(spec, dict(
-        fields=fields, hash_backend=hash_backend, max_hops=max_hops,
-        strategy=strategy, demand_mode=demand_mode, engine=engine))
+    s = resolve_spec(spec)
     comp = fabric if isinstance(fabric, CompiledFabric) else compile_fabric(fabric)
     flows = list(flows)
     seeds_u64 = normalize_seeds(seeds)
@@ -721,44 +695,33 @@ def monte_carlo_fim(
     seeds: Sequence[int] | np.ndarray,
     *,
     spec: SimSpec | None = None,
-    fields=_UNSET,
-    hash_backend=_UNSET,
     layers: Sequence[str] | None = None,
     only_used_leaves: bool = False,
-    strategy=_UNSET,
-    demand_mode=_UNSET,
-    engine=_UNSET,
-    max_hops=_UNSET,
 ) -> MonteCarloFim:
     """FIM distribution of a routing strategy across a hash-seed sweep.
 
     ``workload`` may be a ``WorkloadDescription`` (flows are synthesized
     the standard way, NIC count inferred from the fabric) or an explicit
-    flow list.  How to simulate comes from a ``SimSpec`` — pass one as
-    ``spec=`` or the legacy kwargs, not both (``simulate_paths``
-    contract; default: per-flow ECMP, unit demand;
+    flow list.  How to simulate comes from ``spec`` (the
+    ``simulate_paths`` contract; default: per-flow ECMP, unit demand;
     ``demand_mode="bytes"`` makes the FIM byte-weighted).  ``layers`` /
     ``only_used_leaves`` describe what to *measure*, not how to route,
     so they stay per-call arguments.
 
-    ``engine="jax"`` with plain ECMP takes the fused device pipeline
-    (walk + counts + FIM in one pass, ``jax_engine``); other strategies
-    route on the jax walk and aggregate on host.
+    A spec that ``runs_fused`` (``engine="jax"`` with plain ECMP) takes
+    the fused device pipeline (walk + counts + FIM in one pass,
+    ``jax_engine``); other strategies route on the jax walk and
+    aggregate on host.
     """
     with span("monte_carlo_fim", seeds=len(seeds)):
-        s = resolve_spec(spec, dict(
-            fields=fields, hash_backend=hash_backend, strategy=strategy,
-            demand_mode=demand_mode, engine=engine, max_hops=max_hops))
+        s = resolve_spec(spec)
         comp = (fabric if isinstance(fabric, CompiledFabric)
                 else compile_fabric(fabric))
-        if s.engine != ENGINE_NUMPY and _is_plain_ecmp(s.strategy):
-            from .jax_engine import fused_monte_carlo_fim, resolve_engine
-            resolve_engine(s.engine)
+        if runs_fused(s):
+            from .jax_engine import fused_monte_carlo_fim
             return fused_monte_carlo_fim(
-                comp, workload, seeds, fields=s.fields,
-                hash_backend=s.hash_backend,
-                layers=layers, only_used_leaves=only_used_leaves,
-                demand_mode=s.demand_mode, max_hops=s.max_hops)
+                comp, workload, seeds, s,
+                layers=layers, only_used_leaves=only_used_leaves)
         flows = resolve_flows(comp, workload)
         count("flows", len(flows))
         res = simulate_paths(comp, flows, seeds, spec=s)
@@ -778,3 +741,11 @@ def _is_plain_ecmp(strategy) -> bool:
         return True
     from .strategies import EcmpStrategy
     return type(strategy) is EcmpStrategy
+
+
+def runs_fused(spec: SimSpec) -> bool:
+    """True when the resolved ``spec`` takes the fused device pipeline
+    (``jax_engine.fused_monte_carlo_*``): the jax engine on the plain
+    per-flow ECMP walk.  The aggregate front ends ask this, and only
+    this, to choose it."""
+    return spec.engine == ENGINE_JAX and _is_plain_ecmp(spec.strategy)

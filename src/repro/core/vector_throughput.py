@@ -48,9 +48,8 @@ from .fabric import Fabric
 from .flows import Flow, WorkloadDescription
 from .spans import count, span
 from .vector_sim import (
-    ENGINE_NUMPY, SimSpec, VectorTraceResult, _UNSET,
-    _is_plain_ecmp, resolve_flows, resolve_spec,
-    segment_reduce, simulate_paths,
+    ENGINE_NUMPY, SimSpec, VectorTraceResult, resolve_flows, resolve_spec,
+    runs_fused, segment_reduce, simulate_paths,
 )
 
 # Seeds per cache block: per-cell state is ~5 arrays of seed_block * L
@@ -691,14 +690,7 @@ def monte_carlo_throughput(
     seeds: Sequence[int] | np.ndarray,
     *,
     spec: SimSpec | None = None,
-    fields=_UNSET,
-    hash_backend=_UNSET,
     field_matrix: np.ndarray | None = None,
-    strategy=_UNSET,
-    demand_mode=_UNSET,
-    transport=_UNSET,
-    engine=_UNSET,
-    max_hops=_UNSET,
 ) -> MonteCarloThroughput:
     """Max-min throughput distribution of a routing strategy across a
     seed sweep.
@@ -706,34 +698,26 @@ def monte_carlo_throughput(
     ``workload`` may be a ``WorkloadDescription`` (flows synthesized the
     standard way, NIC count inferred from the fabric) or an explicit flow
     list — the same front-end contract as ``monte_carlo_fim``.  How to
-    simulate comes from a ``SimSpec`` — pass one as ``spec=`` or the
-    legacy kwargs, not both.  ``strategy`` and ``demand_mode`` follow
-    the ``simulate_paths`` contract (default: per-flow ECMP, unit
-    demand; ``demand_mode="bytes"`` allocates weighted max-min shares);
-    ``transport`` the ``throughput_from_result`` contract (reordering
-    cost model for ``goodput``; default ``"ideal"`` = reordering-free).
+    simulate comes from ``spec``: its ``strategy`` and ``demand_mode``
+    follow the ``simulate_paths`` contract (default: per-flow ECMP,
+    unit demand; ``demand_mode="bytes"`` allocates weighted max-min
+    shares), its ``transport`` the ``throughput_from_result`` contract
+    (reordering cost model for ``goodput``; default ``"ideal"`` =
+    reordering-free).
 
-    ``engine="jax"`` with plain ECMP takes the fused device pipeline
-    (walk + fill in one device-resident pass, ``jax_engine``); other
-    strategies route on the jax walk and fill/expose on device with
-    host glue in between.
+    A spec that ``runs_fused`` (``engine="jax"`` with plain ECMP) takes
+    the fused device pipeline (walk + fill in one device-resident pass,
+    ``jax_engine``); other strategies route on the jax walk and
+    fill/expose on device with host glue in between.
     """
     with span("monte_carlo_throughput", seeds=len(seeds)):
-        s = resolve_spec(spec, dict(
-            fields=fields, hash_backend=hash_backend, strategy=strategy,
-            demand_mode=demand_mode, transport=transport, engine=engine,
-            max_hops=max_hops))
+        s = resolve_spec(spec)
         comp = (fabric if isinstance(fabric, CompiledFabric)
                 else compile_fabric(fabric))
-        if s.engine != ENGINE_NUMPY and _is_plain_ecmp(s.strategy):
-            from .jax_engine import (
-                fused_monte_carlo_throughput, resolve_engine)
-            resolve_engine(s.engine)
+        if runs_fused(s):
+            from .jax_engine import fused_monte_carlo_throughput
             return fused_monte_carlo_throughput(
-                comp, workload, seeds, fields=s.fields,
-                hash_backend=s.hash_backend,
-                demand_mode=s.demand_mode, transport=s.transport,
-                field_matrix=field_matrix, max_hops=s.max_hops)
+                comp, workload, seeds, s, field_matrix=field_matrix)
         flows = resolve_flows(comp, workload)
         count("flows", len(flows))
         res = simulate_paths(comp, flows, seeds, spec=s,

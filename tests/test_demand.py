@@ -20,12 +20,12 @@ from _propcheck import given, settings, strategies as st
 from conftest import weighted_max_min_ref
 
 from repro.core import (
-    DEMAND_BYTES, DEMAND_UNIFORM, CongestionAware, Flow, LlmJobSpec,
-    PairSpec, PrimeSpraying, WorkloadDescription, available_strategies,
+    DEMAND_BYTES, DEMAND_UNIFORM, CongestionAware, Flow, LlmJobSpec, PairSpec,
+    PrimeSpraying, SimSpec, WorkloadDescription, available_strategies,
     bipartite_pairs, build_paper_testbed, compile_fabric, fim_vector,
     flow_demand_weights, llm_collective_ops, monte_carlo_fim,
-    monte_carlo_throughput, nic_ip, paper_testbed_llm_workload,
-    server_name, simulate_paths, synthesize_flows, throughput_from_result,
+    monte_carlo_throughput, nic_ip, paper_testbed_llm_workload, server_name,
+    simulate_paths, synthesize_flows, throughput_from_result,
     workload_from_flows,
 )
 from repro.core.vector_sim import resolve_flows
@@ -81,7 +81,8 @@ def test_unknown_demand_mode_raises(paper_compiled, paper_setup):
     with pytest.raises(ValueError, match="demand_mode"):
         flow_demand_weights(flows, "gigabytes")
     with pytest.raises(ValueError, match="demand_mode"):
-        simulate_paths(paper_compiled, flows, [0], demand_mode="gigabytes")
+        simulate_paths(paper_compiled, flows, [0],
+                       spec=SimSpec(demand_mode="gigabytes"))
 
 
 # ---------------------------------------------------------------------------
@@ -103,9 +104,11 @@ def test_equal_bytes_bit_identical_to_uniform(paper_compiled, paper_setup,
     same rates — for every registered strategy."""
     flows = _hetero_flows(paper_setup, [1 << 20])[:64]
     seeds = np.arange(6)
-    base = simulate_paths(paper_compiled, flows, seeds, strategy=strategy)
-    res = simulate_paths(paper_compiled, flows, seeds, strategy=strategy,
-                         demand_mode=DEMAND_BYTES)
+    base = simulate_paths(paper_compiled, flows, seeds,
+                          spec=SimSpec(strategy=strategy))
+    res = simulate_paths(paper_compiled, flows, seeds,
+                         spec=SimSpec(strategy=strategy,
+                                      demand_mode=DEMAND_BYTES))
     np.testing.assert_array_equal(res.link_ids, base.link_ids)
     np.testing.assert_array_equal(res.flow_demand, 1.0)
     np.testing.assert_array_equal(res.column_weights(), base.column_weights())
@@ -127,17 +130,20 @@ def test_legacy_strategy_without_demand_mode_kwarg(paper_compiled,
 
         def route(self, comp, flows, seeds_u64, *, fields, hash_backend,
                   max_hops, field_matrix):
-            return simulate_paths(comp, flows, seeds_u64, fields=fields,
-                                  hash_backend=hash_backend,
-                                  max_hops=max_hops,
+            return simulate_paths(comp, flows, seeds_u64,
+                                  spec=SimSpec(fields=fields,
+                                               hash_backend=hash_backend,
+                                               max_hops=max_hops),
                                   field_matrix=field_matrix)
 
     _, _, flows = paper_setup
-    res = simulate_paths(paper_compiled, flows[:4], [0], strategy=Legacy())
+    res = simulate_paths(paper_compiled, flows[:4], [0],
+                         spec=SimSpec(strategy=Legacy()))
     assert res.num_flows == 4
     with pytest.raises(TypeError, match="demand_mode"):
-        simulate_paths(paper_compiled, flows[:4], [0], strategy=Legacy(),
-                       demand_mode=DEMAND_BYTES)
+        simulate_paths(paper_compiled, flows[:4], [0],
+                       spec=SimSpec(strategy=Legacy(),
+                                    demand_mode=DEMAND_BYTES))
 
 
 def test_monte_carlo_fronts_bit_identical_on_equal_bytes(paper_compiled,
@@ -145,15 +151,17 @@ def test_monte_carlo_fronts_bit_identical_on_equal_bytes(paper_compiled,
     flows = _hetero_flows(paper_setup, [512])[:32]
     seeds = np.arange(4)
     for strategy in (None, "prime-spray", "congestion-aware"):
-        a = monte_carlo_fim(paper_compiled, flows, seeds, strategy=strategy)
-        b = monte_carlo_fim(paper_compiled, flows, seeds, strategy=strategy,
-                            demand_mode=DEMAND_BYTES)
+        a = monte_carlo_fim(paper_compiled, flows, seeds,
+                            spec=SimSpec(strategy=strategy))
+        b = monte_carlo_fim(paper_compiled, flows, seeds,
+                            spec=SimSpec(strategy=strategy,
+                                         demand_mode=DEMAND_BYTES))
         np.testing.assert_array_equal(a.aggregate, b.aggregate)
         ta = monte_carlo_throughput(paper_compiled, flows, seeds,
-                                    strategy=strategy)
+                                    spec=SimSpec(strategy=strategy))
         tb = monte_carlo_throughput(paper_compiled, flows, seeds,
-                                    strategy=strategy,
-                                    demand_mode=DEMAND_BYTES)
+                                    spec=SimSpec(strategy=strategy,
+                                                 demand_mode=DEMAND_BYTES))
         np.testing.assert_array_equal(ta.rates, tb.rates)
 
 
@@ -169,7 +177,7 @@ def test_hetero_bytes_change_fim_and_rates(paper_compiled, paper_setup):
     seeds = np.arange(8)
     uni = simulate_paths(paper_compiled, flows, seeds)
     wtd = simulate_paths(paper_compiled, flows, seeds,
-                         demand_mode=DEMAND_BYTES)
+                         spec=SimSpec(demand_mode=DEMAND_BYTES))
     # identical paths (ECMP ignores demand) ...
     np.testing.assert_array_equal(uni.link_ids, wtd.link_ids)
     # ... but weighted FIM and weighted rates tell a different story
@@ -193,7 +201,8 @@ def test_throughput_aggregation_is_demand_weighted(paper_compiled,
         Flow(0, f0.src, f0.dst, f0.tuple5, bytes=3 * (1 << 20)),
         Flow(1, f1.src, f1.dst, f1.tuple5, bytes=1 << 20),
     ]
-    res = simulate_paths(paper_compiled, pair, [0], demand_mode=DEMAND_BYTES)
+    res = simulate_paths(paper_compiled, pair, [0],
+                         spec=SimSpec(demand_mode=DEMAND_BYTES))
     # force a shared single-link contention: replace walked paths with one
     # common link so the split ratio is exactly the demand ratio
     res.link_ids = np.zeros((1, 2, 1), np.int32)
@@ -207,8 +216,8 @@ def test_spray_composes_flow_demand_with_flowlet_fractions(paper_compiled,
                                                            paper_setup):
     flows = _hetero_flows(paper_setup, [1 << 28, 1 << 12])[:32]
     res = simulate_paths(paper_compiled, flows, [3],
-                         strategy=PrimeSpraying(flowlets=4),
-                         demand_mode=DEMAND_BYTES)
+                         spec=SimSpec(strategy=PrimeSpraying(flowlets=4),
+                                      demand_mode=DEMAND_BYTES))
     w = res.column_weights()
     # each column = parent weight / K; per-flow sums recover flow_demand
     per_flow = np.bincount(res.flow_index, weights=w, minlength=len(flows))
@@ -224,10 +233,10 @@ def test_congestion_aware_places_largest_first(paper_compiled, paper_setup):
     flows = _hetero_flows(paper_setup, [1, 2, 4, 1 << 30])[:64]
     heavy = max(range(len(flows)), key=lambda i: flows[i].bytes)
     res = simulate_paths(paper_compiled, flows, [0],
-                         strategy=CongestionAware(),
-                         demand_mode=DEMAND_BYTES)
+                         spec=SimSpec(strategy=CongestionAware(),
+                                      demand_mode=DEMAND_BYTES))
     alone = simulate_paths(paper_compiled, [flows[heavy]], [0],
-                           strategy=CongestionAware())
+                           spec=SimSpec(strategy=CongestionAware()))
     np.testing.assert_array_equal(
         res.link_ids[:, heavy, :], alone.link_ids[:, 0, :])
 
@@ -239,14 +248,14 @@ def test_congestion_aware_weighted_loads_change_placement(paper_compiled,
     flows = _hetero_flows(paper_setup, [1 << 30, 1 << 10])
     seeds = np.arange(4)
     uni = simulate_paths(paper_compiled, flows, seeds,
-                         strategy=CongestionAware())
+                         spec=SimSpec(strategy=CongestionAware()))
     wtd = simulate_paths(paper_compiled, flows, seeds,
-                         strategy=CongestionAware(),
-                         demand_mode=DEMAND_BYTES)
+                         spec=SimSpec(strategy=CongestionAware(),
+                                      demand_mode=DEMAND_BYTES))
     assert not np.array_equal(uni.link_ids, wtd.link_ids)
     # and the weighted placement spreads bytes better than hashing does
     ecmp = simulate_paths(paper_compiled, flows, seeds,
-                          demand_mode=DEMAND_BYTES)
+                          spec=SimSpec(demand_mode=DEMAND_BYTES))
     assert fim_vector(wtd).mean() < fim_vector(ecmp).mean()
 
 
@@ -271,7 +280,8 @@ def test_bytes_pipeline_matches_scalar_weighted_reference(rngseed):
         for f in synthesize_flows(wl, nic_ip=nic_ip, nics_per_server=2)
     ]
     seeds = [int(rng.integers(0, 2**63)) for _ in range(2)]
-    res = simulate_paths(fab, flows, seeds, demand_mode=DEMAND_BYTES)
+    res = simulate_paths(fab, flows, seeds,
+                         spec=SimSpec(demand_mode=DEMAND_BYTES))
     tp = throughput_from_result(res)
     w = res.flow_demand
     link_index = {link: i for i, link in enumerate(fab.links)}
@@ -328,7 +338,8 @@ def test_workload_description_bytes_reach_demand(paper_compiled):
     assert sum(f.bytes for f in flows) == wl.total_bytes
     seeds = np.arange(4)
     u = monte_carlo_fim(paper_compiled, wl, seeds)
-    w = monte_carlo_fim(paper_compiled, wl, seeds, demand_mode=DEMAND_BYTES)
+    w = monte_carlo_fim(paper_compiled, wl, seeds,
+                        spec=SimSpec(demand_mode=DEMAND_BYTES))
     assert not np.allclose(u.aggregate, w.aggregate)
 
 
@@ -384,9 +395,11 @@ def test_paper_testbed_llm_scenario(paper_compiled):
     # the committed acceptance scenario: weighted FIM != unweighted FIM
     seeds = np.arange(8)
     for strategy in (None, "prime-spray", "congestion-aware"):
-        u = monte_carlo_fim(paper_compiled, flows, seeds, strategy=strategy)
-        w = monte_carlo_fim(paper_compiled, flows, seeds, strategy=strategy,
-                            demand_mode=DEMAND_BYTES)
+        u = monte_carlo_fim(paper_compiled, flows, seeds,
+                            spec=SimSpec(strategy=strategy))
+        w = monte_carlo_fim(paper_compiled, flows, seeds,
+                            spec=SimSpec(strategy=strategy,
+                                         demand_mode=DEMAND_BYTES))
         assert not np.allclose(u.aggregate, w.aggregate), strategy
 
 
@@ -398,6 +411,6 @@ def test_multipod_llm_scenario_splits_ici_and_dcn(multipod_small):
     assert stats.inter_pod_dcn == len(flows) > 0
     assert stats.ici_bytes > stats.dcn_bytes
     res = simulate_paths(compile_fabric(fab), flows, [0, 1],
-                         demand_mode=DEMAND_BYTES)
+                         spec=SimSpec(demand_mode=DEMAND_BYTES))
     assert res.link_ids.shape[1] == len(flows)
     assert (res.flow_demand > 0).all()

@@ -16,7 +16,6 @@ Three layers of coverage, mirroring the module's anchors:
 """
 
 import dataclasses
-import warnings
 
 import numpy as np
 import pytest
@@ -200,11 +199,14 @@ def test_one_step_uniform_bytes_event_matches_static(paper_compiled,
     uniform = [dataclasses.replace(f, bytes=10_000_000) for f in flows]
     one = [merged_step(schedule)]
     seeds = [0, 7, 1234567]
-    kw = dict(demand_mode="bytes", transport="roce-nack", strategy=strategy)
-    static = simulate_timeline(paper_compiled, uniform, one, seeds,
-                               timing=TIMING_STATIC, **kw)
-    event = simulate_timeline(paper_compiled, uniform, one, seeds,
-                              timing=TIMING_EVENT, **kw)
+    spec = SimSpec(demand_mode="bytes", transport="roce-nack",
+                   strategy=strategy)
+    static = simulate_timeline(
+        paper_compiled, uniform, one, seeds,
+        spec=dataclasses.replace(spec, timing=TIMING_STATIC))
+    event = simulate_timeline(
+        paper_compiled, uniform, one, seeds,
+        spec=dataclasses.replace(spec, timing=TIMING_EVENT))
     np.testing.assert_array_equal(event.fim, static.fim)
     np.testing.assert_array_equal(event.rates, static.rates)
     np.testing.assert_array_equal(event.goodput, static.goodput)
@@ -356,25 +358,8 @@ def test_event_adaptive_sub_rtt_step_cannot_adapt(paper_compiled):
 
 
 # ---------------------------------------------------------------------------
-# satellite: weight alias + strict channel validation
+# strict channel validation
 # ---------------------------------------------------------------------------
-
-
-def test_timeline_step_weight_alias_deprecated_once():
-    timeline_mod._WEIGHT_ALIAS_WARNED = False
-    with pytest.warns(DeprecationWarning, match="duration"):
-        s = TimelineStep("x", (1,), weight=2.5)
-    assert s.duration == 2.5
-    assert s.weight == 2.5                  # read-side alias
-    # warned once per process: the second use stays silent
-    with warnings.catch_warnings():
-        warnings.simplefilter("error")
-        s2 = TimelineStep("y", (2,), weight=1.5)
-    assert s2.duration == 1.5
-    with pytest.raises(TypeError, match="alias"):
-        TimelineStep("z", (1,), duration=1.0, weight=1.0)
-    # no silent behavior change: replace() round-trips the real field
-    assert dataclasses.replace(s, duration=3.0).duration == 3.0
 
 
 def test_unknown_channel_error_names_registered_vocabulary():

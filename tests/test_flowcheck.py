@@ -246,95 +246,6 @@ def test_registry_loop_and_ctor_names_resolve(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# FT-API: SimSpec surface consistency
-# ---------------------------------------------------------------------------
-
-_SPEC_PRELUDE = """\
-    _UNSET = object()
-
-
-    class SimSpec:
-        strategy: object = None
-        demand_mode: str = "uniform"
-        engine: str = "numpy"
-        hash_backend: object = None
-        transport: object = None
-        fields: str = "5tuple"
-        max_hops: int = 16
-        timing: str = "static"
-
-
-    def resolve_spec(spec, kwargs):
-        return spec
-    """
-
-
-def test_api_family_trips_each_rule_once(tmp_path):
-    root = make_repo(tmp_path, {
-        "src/repro/core/jax_engine.py": """\
-            def fused_monte_carlo_fim(comp, workload, seeds, *, fields=None,
-                                      hash_backend=None, demand_mode=None,
-                                      max_hops=16):
-                pass
-            """,
-        "src/repro/core/vector_sim.py": _SPEC_PRELUDE + """\
-
-
-        def simulate_paths(fabric, flows, seeds, *, spec=None,
-                           fields=_UNSET, hash_backend=_UNSET,
-                           strategy=_UNSET, demand_mode=_UNSET,
-                           engine=_UNSET, max_hops=_UNSET,
-                           bogus=_UNSET):
-            # bogus: FT-API-KWARGS (not a SimSpec field)
-            # max_hops: FT-API-KWARGS (never forwarded to resolve_spec)
-            s = resolve_spec(spec, dict(
-                fields=fields, hash_backend=hash_backend,
-                strategy=strategy, demand_mode=demand_mode,
-                engine=engine, bogus=bogus))
-            return s
-
-
-        def monte_carlo_fim(fabric, workload, seeds, *, spec=None,
-                            fields=_UNSET, hash_backend=_UNSET,
-                            strategy=_UNSET, demand_mode=_UNSET,
-                            engine=_UNSET):
-            # max_hops: FT-API-MISSING (neither kwarg nor excluded)
-            s = resolve_spec(spec, dict(
-                fields=fields, hash_backend=hash_backend,
-                strategy=strategy, demand_mode=demand_mode,
-                engine=engine))
-            from .jax_engine import fused_monte_carlo_fim
-            # FT-API-FUSED: max_hops not forwarded
-            return fused_monte_carlo_fim(
-                fabric, workload, seeds, fields=s.fields,
-                hash_backend=s.hash_backend, demand_mode=s.demand_mode)
-        """,
-    })
-    rules = rules_of(root)
-    assert rules.count("FT-API-KWARGS") == 2, rules
-    assert rules.count("FT-API-MISSING") == 1, rules
-    assert rules.count("FT-API-FUSED") == 1, rules
-
-
-def test_api_consistent_surface_stays_clean(tmp_path):
-    root = make_repo(tmp_path, {
-        "src/repro/core/vector_sim.py": _SPEC_PRELUDE + """\
-
-
-        def simulate_paths(fabric, flows, seeds, *, spec=None,
-                           fields=_UNSET, hash_backend=_UNSET,
-                           strategy=_UNSET, demand_mode=_UNSET,
-                           engine=_UNSET, max_hops=_UNSET):
-            return resolve_spec(spec, dict(
-                fields=fields, hash_backend=hash_backend,
-                strategy=strategy, demand_mode=demand_mode,
-                engine=engine, max_hops=max_hops))
-        """,
-    })
-    assert [r for r in rules_of(root) if r.startswith("FT-API")] == []
-
-
-# ---------------------------------------------------------------------------
 # FT-BENCH: bench rows vs the smoke baseline
 # ---------------------------------------------------------------------------
 
@@ -478,9 +389,9 @@ def contracts_on(monkeypatch):
     monkeypatch.setenv("FLOWTRACER_CONTRACTS", "1")
 
 
-def _routed(strategy=None, **kw):
+def _routed(strategy=None):
     from repro.core import (
-        bipartite_pairs, build_paper_testbed, compile_fabric, nic_ip,
+        SimSpec, bipartite_pairs, build_paper_testbed, compile_fabric, nic_ip,
         server_name, simulate_paths, synthesize_flows,
     )
     comp = compile_fabric(build_paper_testbed())
@@ -488,7 +399,8 @@ def _routed(strategy=None, **kw):
                          [server_name(8 + i) for i in range(4)],
                          flows_per_pair=2)
     flows = synthesize_flows(wl, nic_ip=nic_ip, nics_per_server=2)
-    return simulate_paths(comp, flows, [0, 1], strategy=strategy, **kw)
+    return simulate_paths(comp, flows, [0, 1],
+                          spec=SimSpec(strategy=strategy))
 
 
 def test_contracts_off_by_default(monkeypatch):

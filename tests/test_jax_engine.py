@@ -19,13 +19,12 @@ import pytest
 from _propcheck import given, settings, strategies as st
 
 from repro.core import (
-    AdaptiveSpraying, PrimeSpraying, RoutingStrategy,
-    TimelineStep, batched_max_min, bipartite_pairs, build_multipod_fabric,
+    AdaptiveSpraying, PrimeSpraying, RoutingStrategy, SimSpec, TimelineStep,
+    batched_max_min, bipartite_pairs, build_multipod_fabric,
     build_paper_testbed, build_three_tier_clos, compile_fabric,
-    flowlet_exposure, max_min_rates, monte_carlo_fim,
-    monte_carlo_throughput, nic_ip, resolve_flows, server_name,
-    simulate_paths, simulate_timeline, synthesize_flows,
-    throughput_from_result,
+    flowlet_exposure, max_min_rates, monte_carlo_fim, monte_carlo_throughput,
+    nic_ip, resolve_flows, server_name, simulate_paths, simulate_timeline,
+    synthesize_flows, throughput_from_result,
 )
 from repro.core.ecmp import FIELDS_5TUPLE, flow_fields_matrix
 from repro.core.fabric import SERVER
@@ -101,17 +100,18 @@ def test_legacy_strategy_rejects_engine_loudly(paper8):
 
         def route(self, comp, flows, seeds, *, fields, hash_backend,
                   max_hops, field_matrix):
-            return simulate_paths(comp, flows, seeds, fields=fields,
-                                  hash_backend=hash_backend,
-                                  max_hops=max_hops,
+            return simulate_paths(comp, flows, seeds,
+                                  spec=SimSpec(fields=fields,
+                                               hash_backend=hash_backend,
+                                               max_hops=max_hops),
                                   field_matrix=field_matrix)
 
     comp, flows = paper8
-    res = simulate_paths(comp, flows, [0, 1], strategy=Legacy())
+    res = simulate_paths(comp, flows, [0, 1], spec=SimSpec(strategy=Legacy()))
     assert res.num_seeds == 2
     with pytest.raises(TypeError):
-        simulate_paths(comp, flows, [0, 1], strategy=Legacy(),
-                       engine=ENGINE_JAX)
+        simulate_paths(comp, flows, [0, 1],
+                       spec=SimSpec(strategy=Legacy(), engine=ENGINE_JAX))
 
 
 # ---------------------------------------------------------------------------
@@ -125,11 +125,12 @@ def test_engine_parity_per_strategy(paper8, strategy):
     seeds = [0, 7, 1234567, 2**40 + 17]
     for demand_mode in ("uniform", "bytes"):
         r_np = simulate_paths(comp, flows, seeds,
-                              strategy=STRATEGIES[strategy],
-                              demand_mode=demand_mode)
+                              spec=SimSpec(strategy=STRATEGIES[strategy],
+                                           demand_mode=demand_mode))
         r_jx = simulate_paths(comp, flows, seeds,
-                              strategy=STRATEGIES[strategy],
-                              demand_mode=demand_mode, engine=ENGINE_JAX)
+                              spec=SimSpec(strategy=STRATEGIES[strategy],
+                                           demand_mode=demand_mode,
+                                           engine=ENGINE_JAX))
         # exact backend on both engines: the walk is bit-identical
         assert np.array_equal(r_np.link_ids, r_jx.link_ids)
         assert np.array_equal(r_np.flow_demand, r_jx.flow_demand)
@@ -145,9 +146,10 @@ def test_murmur_walk_bit_identical(paper8):
     """Both engines evaluate the ONE murmur definition (seed-as-init,
     fold, fmix) — same uint32 formulas, so bit-identical too."""
     comp, flows = paper8
-    r_np = simulate_paths(comp, flows, [0, 3, 99], hash_backend=MURMUR)
-    r_jx = simulate_paths(comp, flows, [0, 3, 99], hash_backend=MURMUR,
-                          engine=ENGINE_JAX)
+    r_np = simulate_paths(comp, flows, [0, 3, 99],
+                          spec=SimSpec(hash_backend=MURMUR))
+    r_jx = simulate_paths(comp, flows, [0, 3, 99],
+                          spec=SimSpec(hash_backend=MURMUR, engine=ENGINE_JAX))
     assert np.array_equal(r_np.link_ids, r_jx.link_ids)
     # and murmur actually routes differently than exact (distinct hash)
     r_ex = simulate_paths(comp, flows, [0, 3, 99])
@@ -164,7 +166,7 @@ def test_randomized_fabric_walk_parity(spines, links_per, seed):
     flows = _workload(fab, flows_per_pair=2, servers=8, hetero=False)
     seeds = [seed, seed + 1]
     r_np = simulate_paths(comp, flows, seeds)
-    r_jx = simulate_paths(comp, flows, seeds, engine=ENGINE_JAX)
+    r_jx = simulate_paths(comp, flows, seeds, spec=SimSpec(engine=ENGINE_JAX))
     assert np.array_equal(r_np.link_ids, r_jx.link_ids)
     a = max_min_rates(r_np)
     b = max_min_rates(r_jx, engine=ENGINE_JAX)
@@ -329,7 +331,7 @@ def test_fill_edge_cases_match_numpy():
 def test_exposure_parity_under_spray(paper8):
     comp, flows = paper8
     res = simulate_paths(comp, flows, [0, 5],
-                         strategy=PrimeSpraying(flowlets=4))
+                         spec=SimSpec(strategy=PrimeSpraying(flowlets=4)))
     rates = max_min_rates(res)
     a = flowlet_exposure(res, rates)
     b = flowlet_exposure(res, rates, engine=ENGINE_JAX)
@@ -347,9 +349,14 @@ def test_exposure_parity_under_spray(paper8):
 def test_fused_fim_parity(paper8):
     comp, flows = paper8
     seeds = np.arange(16)
-    for kw in ({}, {"demand_mode": "bytes", "only_used_leaves": True}):
-        a = monte_carlo_fim(comp, flows, seeds, **kw)
-        b = monte_carlo_fim(comp, flows, seeds, engine=ENGINE_JAX, **kw)
+    for demand_mode, used in (("uniform", False), ("bytes", True)):
+        a = monte_carlo_fim(comp, flows, seeds,
+                            spec=SimSpec(demand_mode=demand_mode),
+                            only_used_leaves=used)
+        b = monte_carlo_fim(comp, flows, seeds,
+                            spec=SimSpec(demand_mode=demand_mode,
+                                         engine=ENGINE_JAX),
+                            only_used_leaves=used)
         assert np.abs(a.aggregate - b.aggregate).max() < 1e-6
         assert sorted(a.per_layer) == sorted(b.per_layer)
         for layer in a.per_layer:
@@ -360,10 +367,13 @@ def test_fused_fim_parity(paper8):
 def test_fused_throughput_parity(paper8):
     comp, flows = paper8
     seeds = np.arange(16)
-    a = monte_carlo_throughput(comp, flows, seeds, demand_mode="bytes",
-                               transport="strack")
-    b = monte_carlo_throughput(comp, flows, seeds, demand_mode="bytes",
-                               transport="strack", engine=ENGINE_JAX)
+    a = monte_carlo_throughput(comp, flows, seeds,
+                               spec=SimSpec(demand_mode="bytes",
+                                            transport="strack"))
+    b = monte_carlo_throughput(comp, flows, seeds,
+                               spec=SimSpec(demand_mode="bytes",
+                                            transport="strack",
+                                            engine=ENGINE_JAX))
     assert np.abs(a.rates - b.rates).max() < 1e-6
     assert np.abs(a.goodput - b.goodput).max() < 1e-6
     assert np.abs(a.per_pair - b.per_pair).max() < 1e-6
@@ -392,16 +402,23 @@ def test_fused_padded_last_chunk_parity(paper8, monkeypatch):
     assert seed_chunk(len(flows), 16, len(seeds)) == 128
     walk0 = jax_engine._walk_fn()._cache_size()
     fill0 = jax_engine._fill_fn()._cache_size()
-    a = monte_carlo_throughput(comp, flows, seeds, demand_mode="bytes")
-    b = monte_carlo_throughput(comp, flows, seeds, demand_mode="bytes",
-                               engine=ENGINE_JAX)
+    a = monte_carlo_throughput(comp, flows, seeds,
+                               spec=SimSpec(demand_mode="bytes"))
+    b = monte_carlo_throughput(comp, flows, seeds,
+                               spec=SimSpec(demand_mode="bytes",
+                                            engine=ENGINE_JAX))
     assert np.abs(a.rates - b.rates).max() < 1e-6
     assert jax_engine._walk_fn()._cache_size() - walk0 <= 1
     assert jax_engine._fill_fn()._cache_size() - fill0 <= 1
-    for kw in ({}, {"demand_mode": "bytes", "only_used_leaves": True}):
+    for demand_mode, used in (("uniform", False), ("bytes", True)):
         fim0 = jax_engine._fim_fn()._cache_size()
-        fa = monte_carlo_fim(comp, flows, seeds, **kw)
-        fb = monte_carlo_fim(comp, flows, seeds, engine=ENGINE_JAX, **kw)
+        fa = monte_carlo_fim(comp, flows, seeds,
+                             spec=SimSpec(demand_mode=demand_mode),
+                             only_used_leaves=used)
+        fb = monte_carlo_fim(comp, flows, seeds,
+                             spec=SimSpec(demand_mode=demand_mode,
+                                          engine=ENGINE_JAX),
+                             only_used_leaves=used)
         assert jax_engine._fim_fn()._cache_size() - fim0 <= 1
         assert np.abs(fa.aggregate - fb.aggregate).max() < 1e-6
         assert sorted(fa.per_layer) == sorted(fb.per_layer)
@@ -472,7 +489,7 @@ def test_fused_walk_names_the_flow_that_did_not_arrive(monkeypatch):
     assert _strands(comp, flows, seeds) == want
     for fn in (monte_carlo_fim, monte_carlo_throughput):
         with pytest.raises(RuntimeError) as err:
-            fn(comp, flows, seeds, engine=ENGINE_JAX)
+            fn(comp, flows, seeds, spec=SimSpec(engine=ENGINE_JAX))
         assert str(err.value) == want
 
 
@@ -506,10 +523,10 @@ def test_fused_path_only_for_plain_ecmp(paper8):
     comp, flows = paper8
     seeds = np.arange(4)
     spray = PrimeSpraying(flowlets=4)
-    a = monte_carlo_throughput(comp, flows, seeds, strategy=spray,
-                               transport="roce-nack")
-    b = monte_carlo_throughput(comp, flows, seeds, strategy=spray,
-                               transport="roce-nack", engine=ENGINE_JAX)
+    spec = SimSpec(strategy=spray, transport="roce-nack")
+    a = monte_carlo_throughput(comp, flows, seeds, spec=spec)
+    b = monte_carlo_throughput(
+        comp, flows, seeds, spec=dataclasses.replace(spec, engine=ENGINE_JAX))
     assert np.abs(a.goodput - b.goodput).max() < 1e-6
 
 
@@ -519,10 +536,12 @@ def test_timeline_engine_parity(paper8):
                for i, f in enumerate(flows)]
     sched = [TimelineStep("a", (0,)), TimelineStep("b", (1,), duration=2.0)]
     a = simulate_timeline(comp, labeled, sched, [0, 1, 2],
-                          demand_mode="bytes", transport="roce-nack")
+                          spec=SimSpec(demand_mode="bytes",
+                                       transport="roce-nack"))
     b = simulate_timeline(comp, labeled, sched, [0, 1, 2],
-                          demand_mode="bytes", transport="roce-nack",
-                          engine=ENGINE_JAX)
+                          spec=SimSpec(demand_mode="bytes",
+                                       transport="roce-nack",
+                                       engine=ENGINE_JAX))
     assert np.abs(a.fim - b.fim).max() < 1e-6
     assert np.abs(a.goodput - b.goodput).max() < 1e-6
     for sa, sb in zip(a.steps, b.steps):
@@ -545,11 +564,13 @@ def test_large_scale_sweep_parity():
                          flows_per_pair=max(1, n_flows // 16))
     comp = compile_fabric(fab)
     seeds = np.arange(n_seeds)
-    jx = monte_carlo_throughput(comp, wl, seeds, transport="roce-nack",
-                                engine=ENGINE_JAX)
+    jx = monte_carlo_throughput(comp, wl, seeds,
+                                spec=SimSpec(transport="roce-nack",
+                                             engine=ENGINE_JAX))
     assert jx.rates.shape[1] == n_seeds
     # numpy reference on a seed subsample keeps the differential check
     # affordable at acceptance scale
     sub = np.arange(min(n_seeds, 64))
-    ref = monte_carlo_throughput(comp, wl, sub, transport="roce-nack")
+    ref = monte_carlo_throughput(comp, wl, sub,
+                                 spec=SimSpec(transport="roce-nack"))
     assert np.abs(ref.goodput - jx.goodput[:, :len(sub)]).max() < 1e-6

@@ -17,11 +17,11 @@ import pytest
 from _propcheck import given, settings, strategies as st
 
 from repro.core import (
-    ELEPHANT_MIN_BYTES, IDEAL, ROCE_NACK, STRACK, PrimeSpraying,
+    ELEPHANT_MIN_BYTES, IDEAL, ROCE_NACK, STRACK, PrimeSpraying, SimSpec,
     TransportProfile, available_transports, fim_vector, flowlet_exposure,
-    monte_carlo_throughput, paper_testbed_llm_workload,
-    reordering_efficiency, resolve_strategy, resolve_transport,
-    simulate_paths, throughput_from_result,
+    monte_carlo_throughput, paper_testbed_llm_workload, reordering_efficiency,
+    resolve_strategy, resolve_transport, simulate_paths,
+    throughput_from_result,
 )
 from repro.core.vector_sim import VectorTraceResult
 
@@ -171,7 +171,7 @@ def test_single_path_strategies_zero_exposure(paper_compiled,
     _, _, flows = paper_setup_small
     for strategy in (None, "ecmp", "congestion-aware"):
         res = simulate_paths(paper_compiled, flows, [0, 5],
-                             strategy=strategy)
+                             spec=SimSpec(strategy=strategy))
         np.testing.assert_array_equal(flowlet_exposure(res), 0.0)
 
 
@@ -267,7 +267,8 @@ def test_degenerate_spray_bit_identical_to_ecmp(paper_compiled, paper_setup,
     _, _, flows = paper_setup
     seeds = [0, 42, 2**33]
     base = simulate_paths(paper_compiled, flows, seeds)
-    res = simulate_paths(paper_compiled, flows, seeds, strategy=strategy)
+    res = simulate_paths(paper_compiled, flows, seeds,
+                         spec=SimSpec(strategy=strategy))
     np.testing.assert_array_equal(res.link_ids, base.link_ids)
     assert not res.is_multipath
     assert (res.demand == 1.0).all()
@@ -285,7 +286,7 @@ def test_ideal_transport_goodput_is_rates_even_when_sprayed(paper_compiled,
                                                             paper_setup_small):
     _, _, flows = paper_setup_small
     res = simulate_paths(paper_compiled, flows, [0, 3],
-                         strategy=PrimeSpraying(flowlets=4))
+                         spec=SimSpec(strategy=PrimeSpraying(flowlets=4)))
     tp = throughput_from_result(res)            # default: ideal
     assert tp.transport == "ideal"
     np.testing.assert_array_equal(tp.goodput, tp.rates)
@@ -349,15 +350,15 @@ def test_elephant_spray_warns_on_volume_less_workload(paper_compiled,
     _, _, flows = paper_setup_small        # bipartite flows: bytes == 0
     with pytest.warns(UserWarning, match="no flow\\s+sprays"):
         res = simulate_paths(paper_compiled, flows[:8], [0],
-                             strategy="prime-spray-elephant")
+                             spec=SimSpec(strategy="prime-spray-elephant"))
     assert not res.is_multipath
     # min_bytes=inf is the *intentional* ECMP degenerate: no warning
     import warnings as _w
     with _w.catch_warnings():
         _w.simplefilter("error")
         simulate_paths(paper_compiled, flows[:8], [0],
-                       strategy=PrimeSpraying(flowlets=8,
-                                              min_bytes=float("inf")))
+                       spec=SimSpec(strategy=PrimeSpraying(
+                           flowlets=8, min_bytes=float("inf"))))
 
 
 def test_elephant_registry_entry():
@@ -371,7 +372,8 @@ def test_mixed_spray_demand_fractions_and_layout(paper_compiled):
     wl, flows, _ = paper_testbed_llm_workload()
     strat = PrimeSpraying(flowlets=8, min_bytes=ELEPHANT_MIN_BYTES,
                           volume_k=True)
-    res = simulate_paths(paper_compiled, flows, [3], strategy=strat)
+    res = simulate_paths(paper_compiled, flows, [3],
+                         spec=SimSpec(strategy=strat))
     k_f = strat.flowlet_counts(flows)
     assert res.num_flowlets == int(k_f.sum())
     assert (k_f == 1).any() and (k_f > 1).any()   # genuinely mixed
@@ -388,7 +390,8 @@ def test_mixed_spray_mice_keep_exact_ecmp_paths(paper_compiled):
     wl, flows, _ = paper_testbed_llm_workload()
     seeds = [0, 17]
     strat = PrimeSpraying(flowlets=8, min_bytes=ELEPHANT_MIN_BYTES)
-    res = simulate_paths(paper_compiled, flows, seeds, strategy=strat)
+    res = simulate_paths(paper_compiled, flows, seeds,
+                         spec=SimSpec(strategy=strat))
     base = simulate_paths(paper_compiled, flows, seeds)
     k_f = strat.flowlet_counts(flows)
     mice = np.flatnonzero(k_f == 1)
@@ -419,10 +422,12 @@ def test_spray_tax_and_elephant_recovery(paper_compiled):
     for tag, strat in (("ecmp", None), ("spray", PrimeSpraying(flowlets=8)),
                        ("elephant", elephant)):
         byte_fim[tag] = fim_vector(
-            simulate_paths(paper_compiled, flows, seeds, strategy=strat,
-                           demand_mode="bytes")).mean()
+            simulate_paths(paper_compiled, flows, seeds,
+                           spec=SimSpec(strategy=strat,
+                                        demand_mode="bytes"))).mean()
         tp[tag] = throughput_from_result(
-            simulate_paths(paper_compiled, flows, seeds, strategy=strat),
+            simulate_paths(paper_compiled, flows, seeds,
+                           spec=SimSpec(strategy=strat)),
             transport="roce-nack")
     g = {tag: t.goodput.mean() for tag, t in tp.items()}
     # ECMP pays nothing; spraying keeps its byte-FIM win...
@@ -441,8 +446,8 @@ def test_spray_tax_and_elephant_recovery(paper_compiled):
 def test_monte_carlo_front_end_threads_transport(paper_compiled):
     wl, flows, _ = paper_testbed_llm_workload()
     mc = monte_carlo_throughput(paper_compiled, flows, np.arange(4),
-                                strategy="prime-spray-elephant",
-                                transport="strack")
+                                spec=SimSpec(strategy="prime-spray-elephant",
+                                             transport="strack"))
     assert mc.transport == "strack"
     assert mc.goodput.shape == mc.rates.shape == (len(flows), 4)
     assert (mc.goodput <= mc.rates + 1e-12).all()

@@ -17,13 +17,14 @@ import numpy as np
 import pytest
 
 from repro.core import (
-    bipartite_pairs, build_paper_testbed, build_three_tier_clos,
+    SimSpec, bipartite_pairs, build_paper_testbed, build_three_tier_clos,
     compile_fabric, monte_carlo_fim, monte_carlo_throughput, nic_ip,
     resolve_flows, server_name, spans, synthesize_flows,
 )
 from repro.core import jax_engine
 
 SEEDS = np.arange(64)
+JAX = SimSpec(engine="jax")
 FIELDS = 5                          # FIELDS_5TUPLE hash columns per flow
 #: route-table bytes a flow of the testbed: hop t's (W_t, C_t) are
 #: (1, 2), (1, 16), (4, 4), (1, 2); a uint32 salt and an int32 count per
@@ -50,7 +51,7 @@ def sweep():
                          flows_per_pair=2)
     flows = resolve_flows(comp, wl)
     for fn in FRONT.values():       # compile outside the traces
-        fn(comp, flows, SEEDS, engine="jax")
+        fn(comp, flows, SEEDS, spec=JAX)
     return comp, flows
 
 
@@ -65,7 +66,7 @@ def test_profiler_off_records_nothing(sweep):
     before = spans.snapshot()
     assert not spans.enabled()
     for fn in FRONT.values():
-        fn(comp, flows, SEEDS, engine="jax")
+        fn(comp, flows, SEEDS, spec=JAX)
     with spans.span("outside") as s:
         spans.count("bytes", 1)
     assert s is None
@@ -77,7 +78,7 @@ def test_traced_call_records_every_span_nested_on_calling_thread(
         sweep, tmp_path, front):
     comp, flows = sweep
     _, table = traced(tmp_path, FRONT[front], comp, flows, SEEDS,
-                      engine="jax")
+                      spec=JAX)
     assert set(table) == {front, *STAGES[front]}
     assert all(row["n"] == 1 for row in table.values())
     assert table[front]["flows"] == len(flows)
@@ -105,7 +106,7 @@ def test_traced_call_records_every_span_nested_on_calling_thread(
 def test_self_times_add_up_to_the_front_end_span(sweep, tmp_path, front):
     comp, flows = sweep
     _, table = traced(tmp_path, FRONT[front], comp, flows, SEEDS,
-                      engine="jax")
+                      spec=JAX)
     assert sum(r["self_s"] for r in table.values()) == pytest.approx(
         table[front]["total_s"], rel=1e-9)
     for name in STAGES[front]:
@@ -123,7 +124,7 @@ def test_copy_spans_count_the_bytes_of_their_shapes(sweep, tmp_path):
     walk = {"walk.to_device": N * 4 + N * FIELDS * 8 + S * 8,
             "walk.to_host": 1}
     _, table = traced(tmp_path / "tp", monte_carlo_throughput, comp, flows,
-                      SEEDS, engine="jax")
+                      SEEDS, spec=JAX)
     got = {k: v["bytes"] for k, v in table.items() if "bytes" in v}
     assert got == {**walk, "fill.to_host": N * S * 8}
 
@@ -132,12 +133,12 @@ def test_copy_spans_count_the_bytes_of_their_shapes(sweep, tmp_path):
     fim_out = {"fim.to_host": (1 + NL) * S * 8 + NL}
     fresh = compile_fabric(build_paper_testbed())
     _, table = traced(tmp_path / "fim0", monte_carlo_fim, fresh, flows,
-                      SEEDS, engine="jax")
+                      SEEDS, spec=JAX)
     got = {k: v["bytes"] for k, v in table.items() if "bytes" in v}
     assert got == {**walk, **fim_out, "fim.to_device": NL * L + 2 * L * 4,
                    "walk.tables": N * TESTBED_ROUTE_BYTES}
     _, table = traced(tmp_path / "fim1", monte_carlo_fim, fresh, flows,
-                      SEEDS, engine="jax")
+                      SEEDS, spec=JAX)
     got = {k: v["bytes"] for k, v in table.items() if "bytes" in v}
     assert got == {**walk, **fim_out}
 
@@ -161,7 +162,7 @@ def test_fill_counts_its_rounds(ids, cap, rates, rounds):
 def test_traced_fill_counts_its_rounds(sweep, tmp_path):
     comp, flows = sweep
     _, table = traced(tmp_path, monte_carlo_throughput, comp, flows, SEEDS,
-                      engine="jax")
+                      spec=JAX)
     assert table["fill.to_host"]["rounds"] >= 1
     assert "rounds" not in table["monte_carlo_throughput"]
 
@@ -169,9 +170,9 @@ def test_traced_fill_counts_its_rounds(sweep, tmp_path):
 def test_table_starts_afresh_in_the_next_session(sweep, tmp_path):
     comp, flows = sweep
     traced(tmp_path / "a", monte_carlo_throughput, comp, flows, SEEDS,
-           engine="jax")
+           spec=JAX)
     _, table = traced(tmp_path / "b", monte_carlo_fim, comp, flows, SEEDS,
-                      engine="jax")
+                      spec=JAX)
     assert "monte_carlo_throughput" not in table
     assert "fill.run" not in table
     assert table["monte_carlo_fim"]["n"] == 1
@@ -190,7 +191,7 @@ def test_compiles_land_in_the_span_that_triggered_them(sweep, tmp_path):
     jax.monitoring.register_event_duration_secs_listener(listener)
     try:
         _, table = traced(tmp_path, monte_carlo_fim, comp, flows,
-                          np.arange(37), engine="jax")
+                          np.arange(37), spec=JAX)
     finally:
         jax.monitoring.unregister_event_duration_listener(listener)
     assert table["walk.run"]["compiles"] >= 1
@@ -225,9 +226,9 @@ def test_walk_counts_its_hops_per_pass(sweep, tmp_path, monkeypatch, shape,
                                   + jax_engine._FILL_BYTES_PER_CELL)
     monkeypatch.setattr(jax_engine, "_CHUNK_BYTES", per_seed * 128)
     seeds = np.arange(256)
-    monte_carlo_fim(comp, flows, seeds, engine="jax")
+    monte_carlo_fim(comp, flows, seeds, spec=JAX)
     _, table = traced(tmp_path, monte_carlo_fim, comp, flows, seeds,
-                      engine="jax")
+                      spec=JAX)
     assert table["walk.run"]["n"] == 2
     assert table["walk.run"]["hops"] == 2 * hops
     # select_width: the route-table entries a (flow, seed) selects among
@@ -249,14 +250,14 @@ def test_route_tables_are_built_once_per_fabric_and_flows(sweep, tmp_path):
     comp, flows = sweep
     comp = dataclasses.replace(comp)
     _, first = traced(tmp_path / "a", monte_carlo_fim, comp, flows, SEEDS,
-                      engine="jax")
+                      spec=JAX)
     _, again = traced(tmp_path / "b", monte_carlo_fim, comp, flows, SEEDS,
-                      engine="jax")
+                      spec=JAX)
     _, other = traced(tmp_path / "c", monte_carlo_fim, comp, flows[:8],
-                      SEEDS, engine="jax")
+                      SEEDS, spec=JAX)
     _, replaced = traced(tmp_path / "d", monte_carlo_fim,
                          dataclasses.replace(comp), flows, SEEDS,
-                         engine="jax")
+                         spec=JAX)
     # 32 flows: 8 server pairs each way, on both NICs of a server
     assert first["walk.tables"] == {
         **first["walk.tables"], "n": 1, "triples": 32,

@@ -14,7 +14,7 @@ from _propcheck import given, settings, strategies as st
 from conftest import weighted_max_min_ref
 
 from repro.core import (
-    CongestionAware, EcmpStrategy, PrimeSpraying, RoutingStrategy,
+    CongestionAware, EcmpStrategy, PrimeSpraying, RoutingStrategy, SimSpec,
     available_strategies, batched_max_min, fim_vector,
     flow_rates_from_flowlets, monte_carlo_fim, monte_carlo_throughput,
     register_strategy, resolve_strategy, simulate_paths,
@@ -35,7 +35,8 @@ def test_ecmp_strategy_bit_identical(paper_compiled, paper_setup):
     seeds = [0, 7, 1234567, 2**40 + 17]
     base = simulate_paths(paper_compiled, flows, seeds)
     for strategy in ("ecmp", EcmpStrategy()):
-        res = simulate_paths(paper_compiled, flows, seeds, strategy=strategy)
+        res = simulate_paths(paper_compiled, flows, seeds,
+                             spec=SimSpec(strategy=strategy))
         np.testing.assert_array_equal(res.link_ids, base.link_ids)
         np.testing.assert_array_equal(res.flow_index, np.arange(len(flows)))
         assert (res.demand == 1.0).all()
@@ -45,9 +46,10 @@ def test_ecmp_strategy_bit_identical(paper_compiled, paper_setup):
 def test_strategy_kwarg_threads_fields_and_backend(paper_compiled,
                                                    paper_setup):
     _, _, flows = paper_setup
-    base = simulate_paths(paper_compiled, flows, [3, 9], fields="ip-pair")
-    res = simulate_paths(paper_compiled, flows, [3, 9], fields="ip-pair",
-                         strategy="ecmp")
+    base = simulate_paths(paper_compiled, flows, [3, 9],
+                          spec=SimSpec(fields="ip-pair"))
+    res = simulate_paths(paper_compiled, flows, [3, 9],
+                         spec=SimSpec(fields="ip-pair", strategy="ecmp"))
     np.testing.assert_array_equal(res.link_ids, base.link_ids)
 
 
@@ -61,7 +63,7 @@ def test_prime_k1_degenerates_to_ecmp(paper_compiled, paper_setup):
     seeds = [0, 42, 2**33]
     base = simulate_paths(paper_compiled, flows, seeds)
     res = simulate_paths(paper_compiled, flows, seeds,
-                         strategy=PrimeSpraying(flowlets=1))
+                         spec=SimSpec(strategy=PrimeSpraying(flowlets=1)))
     np.testing.assert_array_equal(res.link_ids, base.link_ids)
     assert (res.demand == 1.0).all()
     assert not res.is_multipath
@@ -78,7 +80,8 @@ def test_prime_demand_fractions_sum_to_one(k):
     wl = bipartite_pairs([server_name(0), server_name(1)],
                          [server_name(2), server_name(3)], flows_per_pair=3)
     flows = synthesize_flows(wl, nic_ip=nic_ip, nics_per_server=2)
-    res = simulate_paths(fab, flows, [5], strategy=PrimeSpraying(flowlets=k))
+    res = simulate_paths(fab, flows, [5],
+                         spec=SimSpec(strategy=PrimeSpraying(flowlets=k)))
     assert res.num_flowlets == len(flows) * k
     np.testing.assert_allclose(res.demand, 1.0 / k)
     per_flow = np.bincount(res.flow_index, weights=res.demand,
@@ -92,7 +95,7 @@ def test_prime_demand_fractions_sum_to_one(k):
 def test_prime_flowlet_paths_topologically_valid(paper_compiled, paper_setup):
     _, _, flows = paper_setup
     res = simulate_paths(paper_compiled, flows[:16], [0, 11],
-                         strategy=PrimeSpraying(flowlets=4))
+                         spec=SimSpec(strategy=PrimeSpraying(flowlets=4)))
     by_id = {f.flow_id: f for f in flows[:16]}
     for seed_index in range(2):
         flowlet_paths = res.flowlet_paths_for_seed(seed_index)
@@ -113,8 +116,9 @@ def test_prime_lower_fim_than_ecmp(paper_compiled, paper_setup):
     _, _, flows = paper_setup
     seeds = np.arange(64)
     ecmp = fim_vector(simulate_paths(paper_compiled, flows, seeds))
-    spray = fim_vector(simulate_paths(paper_compiled, flows, seeds,
-                                      strategy=PrimeSpraying(flowlets=8)))
+    spray = fim_vector(simulate_paths(
+        paper_compiled, flows, seeds,
+        spec=SimSpec(strategy=PrimeSpraying(flowlets=8))))
     assert spray.mean() < ecmp.mean() - 10.0
     assert (spray >= 0).all()
 
@@ -138,7 +142,7 @@ def test_prime_parts_validation():
 def test_multipath_result_guards_paths_for_seed(paper_compiled, paper_setup):
     _, _, flows = paper_setup
     res = simulate_paths(paper_compiled, flows[:4], [0],
-                         strategy=PrimeSpraying(flowlets=2))
+                         spec=SimSpec(strategy=PrimeSpraying(flowlets=2)))
     with pytest.raises(ValueError):
         res.paths_for_seed(0)
 
@@ -151,7 +155,7 @@ def test_multipath_result_guards_paths_for_seed(paper_compiled, paper_setup):
 def test_congestion_aware_valid_paths(paper_compiled, paper_setup):
     _, _, flows = paper_setup
     res = simulate_paths(paper_compiled, flows, [0, 3],
-                         strategy=CongestionAware())
+                         spec=SimSpec(strategy=CongestionAware()))
     by_id = {f.flow_id: f for f in flows}
     for fid, path in res.paths_for_seed(0).items():
         assert path[0].src == by_id[fid].src
@@ -164,15 +168,16 @@ def test_congestion_aware_lower_fim_than_ecmp(paper_compiled, paper_setup):
     _, _, flows = paper_setup
     seeds = np.arange(16)
     ecmp = fim_vector(simulate_paths(paper_compiled, flows, seeds))
-    cong = fim_vector(simulate_paths(paper_compiled, flows, seeds,
-                                     strategy="congestion-aware"))
+    cong = fim_vector(simulate_paths(
+        paper_compiled, flows, seeds,
+        spec=SimSpec(strategy="congestion-aware")))
     assert cong.mean() < ecmp.mean() - 10.0
 
 
 def test_congestion_aware_throughput_sane(paper_compiled, paper_setup):
     _, _, flows = paper_setup
     res = simulate_paths(paper_compiled, flows, np.arange(4),
-                         strategy=CongestionAware())
+                         spec=SimSpec(strategy=CongestionAware()))
     tp = throughput_from_result(res)
     assert tp.rates.shape == (len(flows), 4)
     assert (tp.rates > 0).all()
@@ -262,15 +267,15 @@ def test_adaptive_rounds1_is_static_spray(paper_compiled, paper_setup_small):
     _, _, flows = paper_setup_small
     seeds = [0, 7, 1234567]
     static = simulate_paths(paper_compiled, flows, seeds,
-                            strategy=PrimeSpraying(8))
+                            spec=SimSpec(strategy=PrimeSpraying(8)))
     deg = simulate_paths(paper_compiled, flows, seeds,
-                         strategy=AdaptiveSpraying(8, rounds=1))
+                         spec=SimSpec(strategy=AdaptiveSpraying(8, rounds=1)))
     np.testing.assert_array_equal(static.link_ids, deg.link_ids)
     assert deg.extra_exposure is None
     ecmp = simulate_paths(paper_compiled, flows, seeds)
     off = simulate_paths(paper_compiled, flows, seeds,
-                         strategy=AdaptiveSpraying(8, min_bytes=np.inf,
-                                                   rounds=4))
+                         spec=SimSpec(strategy=AdaptiveSpraying(
+                             8, min_bytes=np.inf, rounds=4)))
     np.testing.assert_array_equal(ecmp.link_ids, off.link_ids)
 
 
@@ -285,16 +290,16 @@ def test_adaptive_beats_static_spray_goodput(paper_compiled, paper_setup):
     seeds = np.arange(8)
     static = throughput_from_result(
         simulate_paths(paper_compiled, flows, seeds,
-                       strategy=PrimeSpraying(8)),
+                       spec=SimSpec(strategy=PrimeSpraying(8))),
         transport="roce-nack")
     adaptive = throughput_from_result(
         simulate_paths(paper_compiled, flows, seeds,
-                       strategy=AdaptiveSpraying(8)),
+                       spec=SimSpec(strategy=AdaptiveSpraying(8))),
         transport="roce-nack")
     assert adaptive.goodput.mean() > static.goodput.mean()
     # the adaptation really moved flowlets and really paid for it
     res = simulate_paths(paper_compiled, flows, seeds,
-                         strategy=AdaptiveSpraying(8))
+                         spec=SimSpec(strategy=AdaptiveSpraying(8)))
     assert res.extra_exposure is not None and res.extra_exposure.max() > 0
 
 
@@ -307,9 +312,11 @@ def test_adaptive_charges_respray_exposure(paper_compiled,
     _, _, flows = paper_setup_small
     seeds = np.arange(4)
     cheap = simulate_paths(paper_compiled, flows, seeds,
-                           strategy=AdaptiveSpraying(8, respray_cost=0.05))
+                           spec=SimSpec(strategy=AdaptiveSpraying(
+                               8, respray_cost=0.05)))
     dear = simulate_paths(paper_compiled, flows, seeds,
-                          strategy=AdaptiveSpraying(8, respray_cost=0.10))
+                          spec=SimSpec(strategy=AdaptiveSpraying(
+                              8, respray_cost=0.10)))
     np.testing.assert_array_equal(cheap.link_ids, dear.link_ids)
     np.testing.assert_allclose(dear.extra_exposure,
                                2.0 * cheap.extra_exposure)
@@ -405,7 +412,7 @@ def test_flow_rates_from_flowlets_unsorted_fallback(paper_compiled,
                                                     paper_setup):
     _, _, flows = paper_setup
     res = simulate_paths(paper_compiled, flows[:8], [0, 1],
-                         strategy=PrimeSpraying(flowlets=2))
+                         spec=SimSpec(strategy=PrimeSpraying(flowlets=2)))
     rates = np.arange(res.num_flowlets * 2, dtype=np.float64).reshape(
         res.num_flowlets, 2)
     sorted_sum = flow_rates_from_flowlets(res, rates)      # reduceat path
@@ -418,7 +425,7 @@ def test_flow_rates_from_flowlets_unsorted_fallback(paper_compiled,
 def test_throughput_from_result_multipath(paper_compiled, paper_setup):
     _, _, flows = paper_setup
     res = simulate_paths(paper_compiled, flows, np.arange(8),
-                         strategy=PrimeSpraying(flowlets=4))
+                         spec=SimSpec(strategy=PrimeSpraying(flowlets=4)))
     tp = throughput_from_result(res)
     assert tp.rates.shape == (len(flows), 8)
     assert tp.per_pair.shape == (16, 8)
@@ -429,11 +436,11 @@ def test_throughput_from_result_multipath(paper_compiled, paper_setup):
 def test_monte_carlo_front_ends_accept_strategy(paper_compiled, paper_setup):
     _, wl, _ = paper_setup
     mc = monte_carlo_fim(paper_compiled, wl, np.arange(8),
-                         strategy="prime-spray")
+                         spec=SimSpec(strategy="prime-spray"))
     assert mc.aggregate.shape == (8,)
     assert (mc.aggregate >= 0).all()
     tp = monte_carlo_throughput(paper_compiled, wl, np.arange(4),
-                                strategy="congestion-aware")
+                                spec=SimSpec(strategy="congestion-aware"))
     assert tp.rates.shape == (256, 4)
 
 
@@ -445,7 +452,7 @@ def test_weighted_fim_counts_comparable_across_strategies(paper_compiled,
     seeds = [0, 1]
     a = simulate_paths(paper_compiled, flows, seeds)
     b = simulate_paths(paper_compiled, flows, seeds,
-                       strategy=PrimeSpraying(flowlets=8))
+                       spec=SimSpec(strategy=PrimeSpraying(flowlets=8)))
     ca, cb = a.link_flow_counts(), b.link_flow_counts()
     lid = paper_compiled.link_layer
     for layer in range(len(paper_compiled.layer_names)):

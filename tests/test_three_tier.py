@@ -15,7 +15,7 @@ import pytest
 
 from repro.core import (
     AGG_TO_SPINE, SPINE_TO_AGG, EcmpRouting, FiveTuple, Flow, FlowTracer,
-    Forwarder, build_multipod_fabric, build_paper_testbed,
+    Forwarder, SimSpec, build_multipod_fabric, build_paper_testbed,
     build_three_tier_clos, compile_fabric, fim, monte_carlo_fim, nic_ip,
     per_layer_fim, server_name, simulate_paths, workload_from_flows,
 )
@@ -208,8 +208,8 @@ def test_fim_across_pods_matches_tracer_and_reference(seed):
     seeds = np.random.default_rng(seed).integers(
         0, 2**62, 6, dtype=np.int64).astype(np.uint64)
 
-    exact = {e: monte_carlo_fim(comp, flows, seeds, engine=e,
-                                hash_backend="exact")
+    exact = {e: monte_carlo_fim(comp, flows, seeds,
+                                spec=SimSpec(engine=e, hash_backend="exact"))
              for e in ("numpy", "jax")}
     wl = workload_from_flows(flows)
     for i, s in enumerate(seeds):
@@ -225,8 +225,8 @@ def test_fim_across_pods_matches_tracer_and_reference(seed):
     agg, per_layer = ref.fim_sweep(ref.Topology(fab.to_json()),
                                    _flow_dicts(flows), seeds)
     for e in ("numpy", "jax"):
-        mc = monte_carlo_fim(comp, flows, seeds, engine=e,
-                             hash_backend="murmur")
+        mc = monte_carlo_fim(comp, flows, seeds,
+                             spec=SimSpec(engine=e, hash_backend="murmur"))
         np.testing.assert_allclose(mc.aggregate, agg, rtol=1e-12)
         assert set(mc.per_layer) == set(per_layer)
         for layer, want in per_layer.items():

@@ -15,7 +15,7 @@ import pytest
 
 from repro.core import (
     CH_BARRIER, CH_FSDP_AG, CH_FSDP_RS, CH_GRAD_AR, CH_MOE_A2A, LlmJobSpec,
-    SCHEDULE_DP_OVERLAP, SCHEDULE_SEQUENTIAL, TimelineStep,
+    SCHEDULE_DP_OVERLAP, SCHEDULE_SEQUENTIAL, SimSpec, TimelineStep,
     build_multipod_fabric, build_paper_testbed, channel_name, compile_fabric,
     flow_channel, known_channels, llm_collective_phases, merged_step,
     monte_carlo_fim, monte_carlo_throughput, multipod_llm_schedule,
@@ -43,12 +43,16 @@ def test_one_step_schedule_is_merged_snapshot(testbed_llm_schedule, strategy):
     comp, flows, schedule = testbed_llm_schedule
     seeds = [0, 7, 1234567]
     one = [merged_step(schedule)]
-    tl = simulate_timeline(comp, flows, one, seeds, demand_mode="bytes",
-                           transport="roce-nack", strategy=strategy)
-    mf = monte_carlo_fim(comp, flows, seeds, demand_mode="bytes",
-                         strategy=strategy)
-    mt = monte_carlo_throughput(comp, flows, seeds, demand_mode="bytes",
-                                transport="roce-nack", strategy=strategy)
+    tl = simulate_timeline(comp, flows, one, seeds,
+                           spec=SimSpec(demand_mode="bytes",
+                                        transport="roce-nack",
+                                        strategy=strategy))
+    mf = monte_carlo_fim(comp, flows, seeds,
+                         spec=SimSpec(demand_mode="bytes", strategy=strategy))
+    mt = monte_carlo_throughput(comp, flows, seeds,
+                                spec=SimSpec(demand_mode="bytes",
+                                             transport="roce-nack",
+                                             strategy=strategy))
     assert tl.num_steps == 1 and tl.weights[0] == 1.0
     np.testing.assert_array_equal(tl.fim, mf.aggregate)
     for layer, series in mf.per_layer.items():
@@ -80,9 +84,10 @@ def test_merged_overstates_disjoint_elephants():
     sched = [TimelineStep("grad-all-reduce", (CH_GRAD_AR,)),
              TimelineStep("moe-all-to-all", (CH_MOE_A2A,))]
     seeds = np.arange(16)
-    phased = simulate_timeline(comp, sub, sched, seeds, demand_mode="bytes")
+    phased = simulate_timeline(comp, sub, sched, seeds,
+                               spec=SimSpec(demand_mode="bytes"))
     merged = simulate_timeline(comp, sub, [merged_step(sched)], seeds,
-                               demand_mode="bytes")
+                               spec=SimSpec(demand_mode="bytes"))
     assert phased.num_steps == 2
     assert (merged.fim > phased.fim).all()
     # and the gap is the elephant's FIM edge, not float noise
@@ -91,7 +96,8 @@ def test_merged_overstates_disjoint_elephants():
     # a fully-overlapped schedule (both collectives in one step) IS the
     # merged snapshot, bit for bit
     overlap = [TimelineStep("overlapped", (CH_GRAD_AR, CH_MOE_A2A))]
-    tl = simulate_timeline(comp, sub, overlap, seeds, demand_mode="bytes")
+    tl = simulate_timeline(comp, sub, overlap, seeds,
+                           spec=SimSpec(demand_mode="bytes"))
     np.testing.assert_array_equal(tl.fim, merged.fim)
     np.testing.assert_array_equal(tl.goodput, merged.goodput)
 
@@ -100,7 +106,7 @@ def test_phased_series_and_weights(testbed_llm_schedule):
     comp, flows, schedule = testbed_llm_schedule
     seeds = np.arange(4)
     tl = simulate_timeline(comp, flows, schedule, seeds,
-                           demand_mode="bytes")
+                           spec=SimSpec(demand_mode="bytes"))
     assert tl.num_steps == len(schedule)
     np.testing.assert_allclose(tl.weights.sum(), 1.0)
     # equal default durations
@@ -211,13 +217,15 @@ def test_timeline_strategy_sweep_slow():
     for strategy in ("ecmp", "prime-spray-elephant",
                      "adaptive-spray-elephant"):
         tl = simulate_timeline(comp, flows, schedule, seeds,
-                               demand_mode="bytes", transport="roce-nack",
-                               strategy=strategy)
+                               spec=SimSpec(demand_mode="bytes",
+                                            transport="roce-nack",
+                                            strategy=strategy))
         assert np.isfinite(tl.fim).all() and np.isfinite(tl.goodput).all()
         results[strategy] = tl
     again = simulate_timeline(comp, flows, schedule, seeds,
-                              demand_mode="bytes", transport="roce-nack",
-                              strategy="adaptive-spray-elephant")
+                              spec=SimSpec(demand_mode="bytes",
+                                           transport="roce-nack",
+                                           strategy="adaptive-spray-elephant"))
     np.testing.assert_array_equal(
         results["adaptive-spray-elephant"].goodput, again.goodput)
     # spraying the elephants must cut the phased byte-FIM vs ECMP

@@ -10,7 +10,7 @@ from _propcheck import given, settings, strategies as st
 
 from repro.core import (
     FIELDS_5TUPLE, FIELDS_IP_PAIR, FIELDS_VXLAN, EcmpRouting, FlowTracer,
-    bipartite_pairs, build_multipod_fabric, build_paper_testbed,
+    SimSpec, bipartite_pairs, build_multipod_fabric, build_paper_testbed,
     compile_fabric, ecmp_hash, fim, fim_from_counts, fim_vector,
     flow_fields_matrix, flow_hash_fields, link_flow_counts, monte_carlo_fim,
     nic_ip, per_layer_fim, server_name, simulate_paths, synthesize_flows,
@@ -63,7 +63,8 @@ def test_flow_fields_matrix_matches_scalar(paper_setup):
 def test_paths_identical_paper_testbed(paper_setup, paper_compiled, mode):
     fab, wl, flows = paper_setup
     seeds = [0, 7, 1234567, 2**40 + 17]
-    res = simulate_paths(paper_compiled, flows, seeds, fields=mode)
+    res = simulate_paths(paper_compiled, flows, seeds,
+                         spec=SimSpec(fields=mode))
     for i, seed in enumerate(seeds):
         assert _vector_paths(res, i) == _tracer_paths(fab, wl, flows, seed, mode)
 
@@ -120,7 +121,7 @@ def test_random_shapes_identical(spines, links_per, flows_per_pair, seed, mode):
     rack1 = [server_name(4 + i) for i in range(4)]
     wl = bipartite_pairs(rack0, rack1, flows_per_pair=flows_per_pair)
     flows = synthesize_flows(wl, nic_ip=nic_ip, nics_per_server=2)
-    res = simulate_paths(fab, flows, [seed], fields=mode)
+    res = simulate_paths(fab, flows, [seed], spec=SimSpec(fields=mode))
     assert _vector_paths(res, 0) == _tracer_paths(fab, wl, flows, seed, mode)
 
 
@@ -163,7 +164,7 @@ def test_murmur_backend_valid_and_statistically_similar(paper_compiled,
                                                         paper_setup):
     fab, wl, flows = paper_setup
     res = simulate_paths(paper_compiled, flows, np.arange(16),
-                         hash_backend="murmur")
+                         spec=SimSpec(hash_backend="murmur"))
     # topologically valid chains ending at the right host
     paths = res.paths_for_seed(0)
     by_id = {f.flow_id: f for f in flows}
@@ -181,7 +182,8 @@ def test_murmur_backend_valid_and_statistically_similar(paper_compiled,
 def test_unknown_backend_raises(paper_compiled, paper_setup):
     _, _, flows = paper_setup
     with pytest.raises(ValueError):
-        simulate_paths(paper_compiled, flows[:4], [0], hash_backend="xxh3")
+        simulate_paths(paper_compiled, flows[:4], [0],
+                       spec=SimSpec(hash_backend="xxh3"))
 
 
 def test_sparse_nic_numbering_resolves_flows():
@@ -222,41 +224,62 @@ def test_sparse_nic_numbering_resolves_flows():
 # ---------------------------------------------------------------------------
 
 
-def test_simspec_equals_legacy_kwargs(paper_compiled, paper_setup):
-    from repro.core import SimSpec
-    _, _, flows = paper_setup
-    seeds = [0, 7, 1234567]
-    legacy = simulate_paths(paper_compiled, flows, seeds,
-                            fields=FIELDS_IP_PAIR, demand_mode="bytes")
-    spec = simulate_paths(paper_compiled, flows, seeds,
-                          spec=SimSpec(fields=FIELDS_IP_PAIR,
-                                       demand_mode="bytes"))
-    np.testing.assert_array_equal(spec.link_ids, legacy.link_ids)
-    np.testing.assert_array_equal(spec.flow_demand, legacy.flow_demand)
-    # passing explicit kwargs that merely repeat the defaults is the
-    # legacy path too, bit for bit
-    dflt = simulate_paths(paper_compiled, flows, seeds,
-                          strategy=None, demand_mode="uniform",
-                          engine="numpy")
-    base = simulate_paths(paper_compiled, flows, seeds)
-    np.testing.assert_array_equal(dflt.link_ids, base.link_ids)
+LEGACY_KWARGS = ("fields", "hash_backend", "strategy", "demand_mode",
+                 "engine", "transport", "timing", "max_hops")
 
 
-def test_simspec_and_kwargs_together_raise(paper_compiled, paper_setup):
-    from repro.core import SimSpec
+def _front_end(name, comp, flows, seeds):
+    """(front-end function, call taking only keyword arguments) for one
+    of the four front ends on the testbed; the timeline runs the LLM
+    schedule's labelled flows."""
+    from repro.core import (
+        monte_carlo_throughput, paper_testbed_llm_schedule,
+        simulate_timeline,
+    )
+    if name == "simulate_timeline":
+        _, lflows, _, sched = paper_testbed_llm_schedule()
+        return simulate_timeline, lambda **kw: simulate_timeline(
+            comp, lflows, sched, seeds, **kw)
+    fn = {"simulate_paths": simulate_paths,
+          "monte_carlo_fim": monte_carlo_fim,
+          "monte_carlo_throughput": monte_carlo_throughput}[name]
+    return fn, lambda **kw: fn(comp, flows, seeds, **kw)
+
+
+def _answers(out) -> dict[str, np.ndarray]:
+    """Every array a front end's result carries, per-layer dicts
+    flattened, by name."""
+    got = {}
+    for k, v in vars(out).items():
+        if isinstance(v, np.ndarray):
+            got[k] = v
+        elif isinstance(v, dict):
+            got.update({f"{k}:{n}": a for n, a in v.items()
+                        if isinstance(a, np.ndarray)})
+    return got
+
+
+@pytest.mark.parametrize("front", ["simulate_paths", "monte_carlo_fim",
+                                   "monte_carlo_throughput",
+                                   "simulate_timeline"])
+def test_front_end_takes_only_a_spec(paper_compiled, paper_setup, front):
+    import inspect
     _, _, flows = paper_setup
-    with pytest.raises(ValueError, match="not both.*demand_mode"):
-        simulate_paths(paper_compiled, flows[:4], [0], spec=SimSpec(),
-                       demand_mode="bytes")
-    with pytest.raises(ValueError, match="not both"):
-        monte_carlo_fim(paper_compiled, flows[:4], [0], spec=SimSpec(),
-                        engine="numpy")
+    fn, call = _front_end(front, paper_compiled, flows, [0, 7])
+    assert not set(inspect.signature(fn).parameters) & set(LEGACY_KWARGS)
+    with pytest.raises(TypeError, match="engine"):
+        call(engine="jax")
     with pytest.raises(TypeError, match="SimSpec"):
-        simulate_paths(paper_compiled, flows[:4], [0], spec="jax")
+        call(spec="jax")
+    default = _answers(call(spec=None))
+    explicit = _answers(call(spec=SimSpec()))
+    assert default and sorted(default) == sorted(explicit)
+    for k in default:
+        np.testing.assert_array_equal(explicit[k], default[k], err_msg=k)
 
 
 def test_simspec_resolve_validates_and_is_idempotent():
-    from repro.core import SimSpec, WaveCongestionAware
+    from repro.core import WaveCongestionAware
     from repro.core.reordering import TransportProfile
     s = SimSpec(strategy="wave-congestion-aware", transport="roce-nack",
                 engine="jax").resolve()
@@ -271,51 +294,66 @@ def test_simspec_resolve_validates_and_is_idempotent():
             bad.resolve()
 
 
-def test_simspec_spans_all_front_ends(paper_compiled, paper_setup):
-    from repro.core import (
-        SimSpec, monte_carlo_throughput, paper_testbed_llm_schedule,
-        simulate_timeline,
-    )
-    _, wl, flows = paper_setup
-    seeds = [0, 3]
-    s = SimSpec(strategy="prime-spray", transport="roce-nack")
-    tp_legacy = monte_carlo_throughput(paper_compiled, flows, seeds,
-                                       strategy="prime-spray",
-                                       transport="roce-nack")
-    tp_spec = monte_carlo_throughput(paper_compiled, flows, seeds, spec=s)
-    np.testing.assert_array_equal(tp_spec.goodput, tp_legacy.goodput)
-    # simulate_timeline resolves strategy names through the same spec —
-    # the name form works uniformly across all four front ends
-    _, lflows, _, sched = paper_testbed_llm_schedule()
-    tl_legacy = simulate_timeline(paper_compiled, lflows, sched, seeds,
-                                  strategy="prime-spray",
-                                  transport="roce-nack")
-    tl_spec = simulate_timeline(paper_compiled, lflows, sched, seeds, spec=s)
-    np.testing.assert_array_equal(tl_spec.fim, tl_legacy.fim)
-    np.testing.assert_array_equal(tl_spec.goodput, tl_legacy.goodput)
+@pytest.mark.parametrize("front", ["monte_carlo_throughput",
+                                   "simulate_timeline"])
+def test_simspec_spans_all_front_ends(paper_compiled, paper_setup, front):
+    # a strategy named in the spec resolves through the registry to the
+    # same strategy an instance gives, in every front end
+    from repro.core import PrimeSpraying
+    _, _, flows = paper_setup
+    _, call = _front_end(front, paper_compiled, flows, [0, 3])
+    by_name = _answers(call(spec=SimSpec(strategy="prime-spray",
+                                         transport="roce-nack")))
+    by_instance = _answers(call(spec=SimSpec(strategy=PrimeSpraying(),
+                                             transport="roce-nack")))
+    assert by_name and sorted(by_name) == sorted(by_instance)
+    for k in by_name:
+        np.testing.assert_array_equal(by_instance[k], by_name[k], err_msg=k)
 
 
-def test_max_hops_spans_all_front_ends(paper_compiled, paper_setup):
-    # regression (flowcheck FT-API-MISSING / FT-API-FUSED): max_hops was
-    # absent from the aggregate front ends' legacy-kwarg surface, and the
-    # fused jax delegations silently rebuilt the default instead of
-    # forwarding spec.max_hops
+@pytest.mark.parametrize("field", [{"max_hops": 1},
+                                   {"fields": FIELDS_IP_PAIR},
+                                   {"demand_mode": "bytes"},
+                                   {"hash_backend": "murmur"}],
+                         ids=lambda f: next(iter(f)))
+def test_fused_path_honours_each_spec_field(paper_compiled, paper_setup,
+                                            field):
+    # the fused device pipelines read the resolved spec whole: each field
+    # moves their answers as it moves the numpy engine's, to float64
+    # rounding
+    import dataclasses
     from repro.core import (
-        SimSpec, monte_carlo_throughput, paper_testbed_llm_schedule,
-        simulate_timeline,
+        monte_carlo_throughput, paper_testbed_llm_schedule, simulate_timeline,
     )
-    _, wl, flows = paper_setup
-    seeds = [0, 1]
-    # testbed paths take >1 hop: an insufficient budget must fail loudly
-    with pytest.raises(RuntimeError, match="did not terminate"):
-        monte_carlo_fim(paper_compiled, flows, seeds, max_hops=1)
-    with pytest.raises(RuntimeError, match="did not terminate"):
-        monte_carlo_throughput(paper_compiled, flows, seeds, max_hops=1)
-    _, lflows, _, sched = paper_testbed_llm_schedule()
-    with pytest.raises(RuntimeError, match="did not terminate"):
-        simulate_timeline(paper_compiled, lflows, sched, seeds, max_hops=1)
-    # the fused device pipelines must honor the budget too
-    for front in (monte_carlo_fim, monte_carlo_throughput):
+    _, _, flows = paper_setup
+    # heterogeneous bytes, so demand_mode="bytes" weighs flows unequally
+    flows = [dataclasses.replace(f, bytes=(1 + f.flow_id % 5) * 1_000_000)
+             for f in flows]
+    seeds = [0, 7]
+    if "max_hops" in field:
+        # testbed paths take >1 hop: an insufficient budget fails loudly
+        # on both engines
+        _, lflows, _, sched = paper_testbed_llm_schedule()
         with pytest.raises(RuntimeError, match="did not terminate"):
-            front(paper_compiled, flows, seeds,
-                  spec=SimSpec(engine="jax", max_hops=1))
+            simulate_timeline(paper_compiled, lflows, sched, seeds,
+                              spec=SimSpec(**field))
+        for front in (monte_carlo_fim, monte_carlo_throughput):
+            for engine in ("numpy", "jax"):
+                with pytest.raises(RuntimeError, match="did not terminate"):
+                    front(paper_compiled, flows, seeds,
+                          spec=SimSpec(engine=engine, **field))
+        return
+    for front, answer in ((monte_carlo_fim, "aggregate"),
+                          (monte_carlo_throughput, "rates")):
+        want = _answers(front(paper_compiled, flows, seeds,
+                              spec=SimSpec(**field)))
+        got = _answers(front(paper_compiled, flows, seeds,
+                             spec=SimSpec(engine="jax", **field)))
+        plain = _answers(front(paper_compiled, flows, seeds))
+        # the field changes the answer, so a fused path that dropped it
+        # could not match
+        assert not np.allclose(want[answer], plain[answer])
+        assert sorted(got) == sorted(want)
+        for k in want:
+            np.testing.assert_allclose(got[k], want[k], rtol=1e-12,
+                                       err_msg=k)

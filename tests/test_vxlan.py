@@ -14,12 +14,13 @@ fraction of the cost.
 import numpy as np
 
 from repro.core import (
-    FIELDS_5TUPLE, FIELDS_IP_PAIR, FIELDS_VXLAN, monte_carlo_fim,
+    FIELDS_5TUPLE, FIELDS_IP_PAIR, FIELDS_VXLAN, SimSpec, monte_carlo_fim,
 )
 
 
 def _mean_fim(compiled, flows, mode, seeds=8):
-    mc = monte_carlo_fim(compiled, flows, np.arange(seeds), fields=mode)
+    mc = monte_carlo_fim(compiled, flows, np.arange(seeds),
+                         spec=SimSpec(fields=mode))
     return float(mc.aggregate.mean())
 
 

@@ -19,9 +19,9 @@ import numpy as np
 import pytest
 
 from repro.core import (
-    CongestionAware, LEAF_TO_SPINE, WaveCongestionAware, bipartite_pairs,
-    build_paper_testbed, compile_fabric, fim_vector, nic_ip, server_name,
-    simulate_paths, synthesize_flows,
+    CongestionAware, LEAF_TO_SPINE, SimSpec, WaveCongestionAware,
+    bipartite_pairs, build_paper_testbed, compile_fabric, fim_vector, nic_ip,
+    server_name, simulate_paths, synthesize_flows,
 )
 from repro.core.vector_sim import ENGINE_JAX, ENGINE_NUMPY
 
@@ -58,11 +58,11 @@ def paper_comp():
 def test_wave_below_cutover_bit_identical(paper_comp, demand_mode, engine):
     flows = _flows(flows_per_pair=16, hetero=True)      # 256 < 7 * 256 links
     seq = simulate_paths(paper_comp, flows, SEEDS,
-                         strategy=CongestionAware(),
-                         demand_mode=demand_mode, engine=engine)
+                         spec=SimSpec(strategy=CongestionAware(),
+                                      demand_mode=demand_mode, engine=engine))
     wav = simulate_paths(paper_comp, flows, SEEDS,
-                         strategy=WaveCongestionAware(),
-                         demand_mode=demand_mode, engine=engine)
+                         spec=SimSpec(strategy=WaveCongestionAware(),
+                                      demand_mode=demand_mode, engine=engine))
     np.testing.assert_array_equal(wav.link_ids, seq.link_ids)
     # the delegated result still reports the wave strategy's name
     assert wav.strategy == "wave-congestion-aware"
@@ -82,12 +82,14 @@ def test_wave_randomized_fabrics_match_sequential(shape, demand_mode):
     servers = 2 * shape.get("servers_per_rack", 8)
     flows = _flows(flows_per_pair=2, servers=servers, hetero=True,
                    rngseed=sum(shape.values()))
-    seq = simulate_paths(comp, flows, SEEDS, strategy=CongestionAware(),
-                         demand_mode=demand_mode)
+    seq = simulate_paths(comp, flows, SEEDS,
+                         spec=SimSpec(strategy=CongestionAware(),
+                                      demand_mode=demand_mode))
     for engine in (ENGINE_NUMPY, ENGINE_JAX):
         wav = simulate_paths(comp, flows, SEEDS,
-                             strategy=WaveCongestionAware(),
-                             demand_mode=demand_mode, engine=engine)
+                             spec=SimSpec(strategy=WaveCongestionAware(),
+                                          demand_mode=demand_mode,
+                                          engine=engine))
         np.testing.assert_array_equal(wav.link_ids, seq.link_ids)
 
 
@@ -106,16 +108,17 @@ def test_wave_above_cutover_fim_no_worse(paper_comp, demand_mode):
     assert len(flows) / paper_comp.num_links >= 7.0   # wave path engaged
     seeds = np.arange(4)
     seq = simulate_paths(paper_comp, flows, seeds,
-                         strategy=CongestionAware(),
-                         demand_mode=demand_mode)
+                         spec=SimSpec(strategy=CongestionAware(),
+                                      demand_mode=demand_mode))
     wav = simulate_paths(paper_comp, flows, seeds,
-                         strategy=WaveCongestionAware(),
-                         demand_mode=demand_mode)
+                         spec=SimSpec(strategy=WaveCongestionAware(),
+                                      demand_mode=demand_mode))
     assert fim_vector(wav).mean() <= fim_vector(seq).mean() + 1e-9
     # the jax wave is the same wave, bit for bit
     jx = simulate_paths(paper_comp, flows, seeds,
-                        strategy=WaveCongestionAware(),
-                        demand_mode=demand_mode, engine=ENGINE_JAX)
+                        spec=SimSpec(strategy=WaveCongestionAware(),
+                                     demand_mode=demand_mode,
+                                     engine=ENGINE_JAX))
     np.testing.assert_array_equal(jx.link_ids, wav.link_ids)
 
 
@@ -130,11 +133,11 @@ def test_wave_hetero_demand_delegates_exactly(paper_comp, engine):
     assert len(flows) / paper_comp.num_links >= 7.0
     seeds = np.arange(4)
     seq = simulate_paths(paper_comp, flows, seeds,
-                         strategy=CongestionAware(),
-                         demand_mode="bytes", engine=engine)
+                         spec=SimSpec(strategy=CongestionAware(),
+                                      demand_mode="bytes", engine=engine))
     wav = simulate_paths(paper_comp, flows, seeds,
-                         strategy=WaveCongestionAware(),
-                         demand_mode="bytes", engine=engine)
+                         spec=SimSpec(strategy=WaveCongestionAware(),
+                                      demand_mode="bytes", engine=engine))
     np.testing.assert_array_equal(wav.link_ids, seq.link_ids)
 
 
@@ -162,7 +165,8 @@ def test_wave_two_flow_symmetric_conflict_converges(paper_comp):
     assert len(flows) == 2
     strategy = WaveCongestionAware(tolerance=1.0, min_wave_load=0.0)
     seeds = list(range(64))
-    res = simulate_paths(paper_comp, flows, seeds, strategy=strategy)
+    res = simulate_paths(paper_comp, flows, seeds,
+                         spec=SimSpec(strategy=strategy))
     ecmp = simulate_paths(paper_comp, flows, seeds)
     for k in range(len(seeds)):
         assert _spine_loads(paper_comp, res, k).max() <= 1, (
@@ -181,7 +185,8 @@ def test_wave_round_cap_residue_falls_back_sequential(paper_comp):
     flows = _flows(flows_per_pair=16)
     strategy = WaveCongestionAware(max_rounds=1, min_wave_load=0.0)
     seeds = np.arange(4)
-    res = simulate_paths(paper_comp, flows, seeds, strategy=strategy)
+    res = simulate_paths(paper_comp, flows, seeds,
+                         spec=SimSpec(strategy=strategy))
     by_id = {f.flow_id: f for f in flows}
     paths = res.paths_for_seed(0)
     assert set(paths) == set(by_id)
@@ -218,8 +223,9 @@ def test_wave_flow_sweep_no_worse_than_sequential(paper_comp):
     flows = _flows(flows_per_pair=max(1, n_flows // 16))
     seeds = np.arange(8)
     seq = simulate_paths(paper_comp, flows, seeds,
-                         strategy=CongestionAware())
+                         spec=SimSpec(strategy=CongestionAware()))
     for engine in (ENGINE_NUMPY, ENGINE_JAX):
         wav = simulate_paths(paper_comp, flows, seeds,
-                             strategy=WaveCongestionAware(), engine=engine)
+                             spec=SimSpec(strategy=WaveCongestionAware(),
+                                          engine=engine))
         assert fim_vector(wav).mean() <= fim_vector(seq).mean() + 1e-9
