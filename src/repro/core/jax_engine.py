@@ -10,9 +10,10 @@ round-trips between stages:
   over the (N, S) grid, not with gathers from the fabric's tables —
   bit-identical to ``vector_sim.ecmp_walk`` under both hash backends
   (uint64 wraparound is exact under x64);
-* link counts are one masked reduction per (seed, link) cell, and the
-  per-layer FIM (MAPE vs per-layer ideal) is a handful of masked
-  reductions per layer;
+* link counts under unit demand are factored one-hot matmuls on the
+  MXU (``_unit_counts``), under any other demand one masked reduction
+  per (seed, link) cell; the per-layer FIM (MAPE vs per-layer ideal) is
+  a handful of masked reductions per layer;
 * the weighted progressive max-min fill keeps the numpy engine's
   parallel local-bottleneck formulation, as a ``lax.while_loop`` over
   static-shape (seed, link) tables and an (N, S) active mask;
@@ -584,24 +585,80 @@ def jax_wave_walk(
 def _cell_reduce(ids, v, L: int, reduce, empty):
     """Reduce an (N, S) per-flow value over each (seed, link) cell:
     ``out[s, l] = reduce(v[n, s] for every (h, n) with ids[h, n, s] == l)``,
-    ``empty`` where no flow crosses the cell.  Traced inside the jitted
-    stages.  The compare against ``arange(L)`` sits inside the reduction,
-    where XLA fuses it, so no (H, N, S, L) tensor is built.  It replaces a
-    scatter and a sort on purpose: on a TPU v5e a float64 ``segment_sum``
-    over the 4 x 4096 x 1024 cells of a paper-testbed sweep took 1.75 s
-    against 0.034 s for this reduction, and a sort over the flattened
-    cells compiles for minutes."""
+    ``empty`` where no flow crosses the cell.  Traced inside the fill
+    (``_fill_jit``: float64 sums and mins of values that change every
+    round) and the weighted counts (``_counts_jit`` under unequal
+    demand); unit demand counts with ``_unit_counts``.  The compare
+    against ``arange(L)`` sits inside the reduction, where XLA fuses it,
+    so no (H, N, S, L) tensor is built.  It replaces a scatter and a sort
+    on purpose: on a TPU v5e a float64 ``segment_sum`` over the
+    4 x 4096 x 1024 cells of a paper-testbed sweep took 1.75 s against
+    0.034 s for this reduction, and a sort over the flattened cells
+    compiles for minutes."""
     _, jnp, _ = _jx()
     hit = ids[..., None] == jnp.arange(L, dtype=ids.dtype)
     return reduce(jnp.where(hit, v[None, :, :, None], empty), axis=(0, 1))
 
 
+# Unit-demand counts (``_unit_counts``): a link id l = hi * B + lo is one
+# row of a (A, K) one-hot of hi times one of a (B, K) one-hot of lo, so a
+# seed's (A, B) hit table is one bfloat16 matmul over its K = H * N
+# (hop, flow) entries.  Operands are 0/1 and every count is an integer of
+# at most H * N, which float32 accumulation keeps exactly up to 2**24.
+# XLA:TPU fuses the one-hot compares into the matmul, so no one-hot
+# reaches HBM (v5e compile of a 6 x 21504 x 128 pass: 87 MB of temps);
+# the seed blocks bound what a backend that builds them (XLA:CPU) holds.
+_ONEHOT_LANES = 128                # widest factor, one lane tile
+_ONEHOT_BYTES = 256 << 20          # bfloat16 one-hots per seed block
+_EXACT_F32 = 1 << 24
+
+
+def onehot_split(L: int) -> tuple[int, int]:
+    """Widths ``(A, B)`` of the factored one-hots that count ``L`` links:
+    ``B`` the power of two at or above sqrt(L), at most one lane tile,
+    and ``A = ceil(L / B)``."""
+    B = 1
+    while B * B < L and B < _ONEHOT_LANES:
+        B *= 2
+    return -(-L // B), B
+
+
+def _unit_counts(ids, L: int):
+    """(S, L) float64 hit counts of an (H, N, S) link-id tensor, every
+    entry of weight 1: per seed, the (A, K) one-hot of ``ids >> log2(B)``
+    contracted with the (B, K) one-hot of ``ids & (B - 1)`` on the MXU.
+    An id of -1 (past a path's end) gives all-zero columns in both.
+    Seeds go in blocks of at most ``_ONEHOT_BYTES`` of one-hots.  Traced
+    inside ``counts_fn``; bit-identical to a float64 sum of ones while
+    H * N <= 2**24 (``jax_link_flow_counts`` checks)."""
+    _, jnp, lax = _jx()
+    H, N, S = ids.shape
+    A, B = onehot_split(L)
+    shift = B.bit_length() - 1
+    block = max(1, min(S, _ONEHOT_BYTES // max(1, H * N * (A + B) * 2)))
+    hi_of = jnp.arange(A, dtype=ids.dtype)[:, None]
+    lo_of = jnp.arange(B, dtype=ids.dtype)[:, None]
+
+    def seed(row):                 # (K,) link ids of one seed
+        hi = (row >> shift)[None, :] == hi_of        # -1 >> shift is -1
+        lo = jnp.where(row >= 0, row & (B - 1), B)[None, :] == lo_of
+        return lax.dot_general(
+            hi.astype(jnp.bfloat16), lo.astype(jnp.bfloat16),
+            (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+
+    hits = lax.map(seed, ids.reshape(H * N, S).T, batch_size=block)
+    return hits.reshape(S, A * B)[:, :L].astype(jnp.float64)
+
+
 def _counts_jit():
     jax, jnp, _ = _jx()
 
-    @functools.partial(jax.jit, static_argnames=("L",))
-    def counts_fn(ids, weights, *, L: int):
-        # ids: (H, Nf, S) device link ids; weights: (Nf,) demand weights
+    @functools.partial(jax.jit, static_argnames=("L", "unit"))
+    def counts_fn(ids, weights, *, L: int, unit: bool):
+        # ids: (H, Nf, S) device link ids; weights: (Nf,) demand
+        # weights, None under unit demand
+        if unit:
+            return _unit_counts(ids, L)
         v = jnp.broadcast_to(weights[:, None], ids.shape[1:])
         return _cell_reduce(ids, v, L, jnp.sum, 0.0)
 
@@ -615,10 +672,21 @@ def _counts_fn():
 
 def jax_link_flow_counts(ids, weights, L: int):
     """(S, L) demand-weighted link loads from a device (H, Nf, S) link-id
-    tensor — twin of ``VectorTraceResult.link_flow_counts``."""
+    tensor — twin of ``VectorTraceResult.link_flow_counts``.
+
+    All-ones weights (the numpy twin's own test) count on the MXU
+    (``_unit_counts``) while H * Nf <= 2**24 keeps float32 exact; the
+    innermost span then counts ``unit_counts`` 1 and ``onehot_width``
+    A + B.  Any other weights sum with ``_cell_reduce``."""
     _, jnp, _ = _jx()
-    return _counts_fn()(ids, jnp.asarray(np.asarray(weights, np.float64)),
-                        L=L)
+    w = np.asarray(weights, np.float64)
+    H, N = ids.shape[:2]
+    unit = bool((w == 1.0).all()) and H * N <= _EXACT_F32
+    if unit:
+        count("unit_counts", 1)
+        count("onehot_width", sum(onehot_split(L)))
+        return _counts_fn()(ids, None, L=L, unit=True)
+    return _counts_fn()(ids, jnp.asarray(w), L=L, unit=False)
 
 
 def _fim_jit():

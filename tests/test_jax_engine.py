@@ -458,6 +458,88 @@ def test_fim_keeps_layers_by_real_seeds_only(paper8):
         assert np.abs(agg - ref_agg).max() < 1e-12
 
 
+def _bincount_counts(ids, L, weights=None):
+    """The numpy twin's rule (``VectorTraceResult.link_flow_counts``)."""
+    S = ids.shape[2]
+    keep = ids >= 0
+    flat = (ids.astype(np.int64) + np.arange(S) * L)[keep]
+    w = (None if weights is None else
+         np.broadcast_to(weights[None, :, None], ids.shape)[keep])
+    return np.bincount(flat, weights=w, minlength=S * L).reshape(S, L)
+
+
+@pytest.mark.parametrize("H, N, S, L, block_bytes", [
+    (4, 256, 64, 256, None),          # the testbed's links, one block
+    (6, 2048, 9, 5760, 9 << 20),      # the three-tier's, blocks of 2
+    (3, 700, 13, 300, 1 << 20),       # L not a multiple of B, of 5
+    (2, 3000, 5, 40, 1 << 19),        # L below B's cap, of 3
+    (5, 120, 3, 1, None),             # one link
+], ids=["testbed", "three-tier", "ragged-L", "small-L", "one-link"])
+def test_link_flow_counts_match_bincount(monkeypatch, H, N, S, L,
+                                         block_bytes):
+    """Unit weights count on the factored one-hots, bit-identical to the
+    numpy bincount, also when -1 tails end paths early and the seed
+    blocks do not divide S; unequal weights take the weighted path."""
+    import jax
+
+    A, B = jax_engine.onehot_split(L)
+    assert B & (B - 1) == 0 and B <= 128 and (A - 1) * B < L <= A * B
+    if block_bytes is not None:
+        per_seed = H * N * (A + B) * 2
+        assert 1 < block_bytes // per_seed < S
+        assert S % (block_bytes // per_seed) != 0
+        monkeypatch.setattr(jax_engine, "_ONEHOT_BYTES", block_bytes)
+    rng = np.random.default_rng(L)
+    ids = rng.integers(0, L, (H, N, S)).astype(np.int32)
+    ends = rng.integers(1, H + 1, (N, S))        # hops each cell walked
+    ids[np.arange(H)[:, None, None] >= ends[None]] = -1
+    w = rng.uniform(0.05, 8.0, N)
+    with jax.enable_x64(True):
+        dev = jax.numpy.asarray(ids)
+        unit = np.asarray(jax_engine.jax_link_flow_counts(dev, np.ones(N), L))
+        weighted = np.asarray(jax_engine.jax_link_flow_counts(dev, w, L))
+    assert unit.dtype == np.float64 and unit.shape == (S, L)
+    np.testing.assert_array_equal(unit, _bincount_counts(ids, L))
+    np.testing.assert_allclose(weighted, _bincount_counts(ids, L, w),
+                               rtol=1e-12, atol=0)
+
+
+@pytest.mark.parametrize("demand_mode, exact_f32, unit", [
+    ("uniform", None, True),
+    ("bytes", None, False),           # unequal bytes: weighted sums
+    ("uniform", 1, False),            # H * N past float32's exact range
+], ids=["uniform", "bytes", "uniform-past-float32"])
+def test_fused_fim_counts_by_demand(paper8, tmp_path, monkeypatch,
+                                    demand_mode, exact_f32, unit):
+    """The fused FIM counts unit demand on the one-hots (counters
+    ``unit_counts`` and ``onehot_width`` of ``counts.run``) and anything
+    else with the weighted sums; both give the numpy engine's FIM."""
+    import jax
+
+    from repro.core import spans
+
+    comp, flows = paper8
+    if exact_f32 is not None:
+        monkeypatch.setattr(jax_engine, "_EXACT_F32", exact_f32)
+    seeds = np.arange(24)
+    spec = SimSpec(demand_mode=demand_mode)
+    a = monte_carlo_fim(comp, flows, seeds, spec=spec)
+    with jax.profiler.trace(str(tmp_path)):
+        b = monte_carlo_fim(comp, flows, seeds,
+                            spec=dataclasses.replace(spec, engine=ENGINE_JAX))
+    row = spans.snapshot()["counts.run"]
+    if unit:
+        assert row["unit_counts"] == row["n"] == 1
+        assert row["onehot_width"] == sum(
+            jax_engine.onehot_split(comp.num_links))
+    else:
+        assert "unit_counts" not in row and "onehot_width" not in row
+    assert np.abs(a.aggregate - b.aggregate).max() < 1e-9
+    assert sorted(a.per_layer) == sorted(b.per_layer)
+    for layer in a.per_layer:
+        assert np.abs(a.per_layer[layer] - b.per_layer[layer]).max() < 1e-9
+
+
 def test_fused_walk_names_the_flow_that_did_not_arrive(monkeypatch):
     """With spine-0's candidate sets toward every NIC on leaf-2 emptied
     in the compiled table, a flow into leaf-2 that hashes onto spine-0

@@ -1,5 +1,6 @@
 """The engine's jitted stages and the flowhash kernel compile for one
-TPU v5e chip at the sizes ``chip_smoke.py`` runs.
+TPU v5e chip at the sizes ``chip_smoke.py`` and the benchmark's cells
+run.
 
 The chip is described, not attached: XLA:TPU compiles here and refuses
 what the chip's compiler would refuse (an unsupported op or dtype, a
@@ -105,11 +106,22 @@ def test_wave_walk_compiles_for_v5e(chip, comp):
             cool=False, near=False))
 
 
-def test_counts_compiles_for_v5e(chip, comp):
+@pytest.mark.parametrize("unit", [True, False], ids=["unit", "weighted"])
+@pytest.mark.parametrize("hops, flows, seeds, links", [
+    (4, 256, 16384, 256),               # a paper-ecmp-fim pass
+    (6, 21504, 128, 5760),              # a meta-3tier-2pod-fim pass
+], ids=["paper-fim", "meta-fim"])
+def test_counts_compiles_for_v5e(chip, unit, hops, flows, seeds, links):
+    """Both counting paths at the FIM cells' pass shapes, each within
+    1 GB of device memory."""
+    weights = None if unit else chip((flows,), jnp.float64)
     with jax.enable_x64(True):
-        _compiles(je._counts_fn().lower(
-            chip((HOPS, FLOWS, SEEDS), jnp.int32),
-            chip((FLOWS,), jnp.float64), L=comp.num_links))
+        compiled = _compiles(je._counts_fn().lower(
+            chip((hops, flows, seeds), jnp.int32), weights, L=links,
+            unit=unit))
+    ma = compiled.memory_analysis()
+    assert (ma.temp_size_in_bytes + ma.argument_size_in_bytes
+            + ma.output_size_in_bytes) < 1e9
 
 
 @pytest.mark.parametrize("only_used_leaves", [False, True])
