@@ -74,6 +74,7 @@ from .llm_workload import (
     paper_testbed_llm_schedule, multipod_llm_schedule,
     SCHEDULE_SEQUENTIAL, SCHEDULE_DP_OVERLAP,
     CH_GRAD_AR, CH_FSDP_AG, CH_FSDP_RS, CH_MOE_A2A, CH_BARRIER,
+    MoeDispatchSpec, moe_dispatch_flows,
 )
 from .placement import (
     static_route_assignment, topology_aware_ring, ring_edge_stats,
@@ -125,6 +126,7 @@ __all__ = [
     "paper_testbed_llm_schedule", "multipod_llm_schedule",
     "SCHEDULE_SEQUENTIAL", "SCHEDULE_DP_OVERLAP",
     "CH_GRAD_AR", "CH_FSDP_AG", "CH_FSDP_RS", "CH_MOE_A2A", "CH_BARRIER",
+    "MoeDispatchSpec", "moe_dispatch_flows",
     "static_route_assignment", "topology_aware_ring", "ring_edge_stats",
     "balanced_port_spread",
     "analyze_paths", "PathReport",

@@ -677,15 +677,18 @@ def jax_link_flow_counts(ids, weights, L: int):
     All-ones weights (the numpy twin's own test) count on the MXU
     (``_unit_counts``) while H * Nf <= 2**24 keeps float32 exact; the
     innermost span then counts ``unit_counts`` 1 and ``onehot_width``
-    A + B.  Any other weights sum with ``_cell_reduce``."""
+    A + B.  Any other weights sum with ``_cell_reduce``; the span then
+    counts ``weighted_counts`` 1 and ``link_compares`` H * Nf * S * L."""
     _, jnp, _ = _jx()
     w = np.asarray(weights, np.float64)
-    H, N = ids.shape[:2]
+    H, N, S = ids.shape
     unit = bool((w == 1.0).all()) and H * N <= _EXACT_F32
     if unit:
         count("unit_counts", 1)
         count("onehot_width", sum(onehot_split(L)))
         return _counts_fn()(ids, None, L=L, unit=True)
+    count("weighted_counts", 1)
+    count("link_compares", H * N * S * L)
     return _counts_fn()(ids, jnp.asarray(w), L=L, unit=False)
 
 

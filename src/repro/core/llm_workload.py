@@ -24,17 +24,29 @@ Two committed scenarios anchor benchmarks and tests:
 Feed the flows to ``simulate_paths(..., spec=SimSpec(demand_mode="bytes"))``
 (or the Monte-Carlo front ends) to weight FIM and max-min throughput by
 volume.
+
+The uniform MoE slab of ``llm_collective_ops`` stays for the TPU
+multi-pod scenario.  Skewed expert traffic comes from
+``moe_dispatch_flows``: DeepSeek-V3's own group-limited router over
+seeded, domain-skewed token affinities, and its expert-parallel dispatch
+as RoCE flows from each GPU's own NIC.
 """
 
 from __future__ import annotations
 
 import dataclasses
 
-from .fabric import server_name
-from .flows import Flow, WorkloadDescription, workload_from_flows
+import numpy as np
+
+from .fabric import nic_ip, server_name
+from .flows import (
+    PROTO_UDP, ROCE_UDP_DPORT, FiveTuple, Flow, WorkloadDescription,
+    workload_from_flows,
+)
 from .hlo_flows import (
     CollectiveOp, EdgeClassCounts, collectives_to_flows, wire_and_operand,
 )
+from .spans import count, span
 from .timeline import TimelineStep, flow_channel, register_channel
 
 
@@ -301,3 +313,186 @@ def multipod_llm_schedule(
                          "hosts_per_pod": 8, "ep_group_hosts": 16,
                          **overrides})
     return llm_schedule(spec, mode)
+
+
+# ---------------------------------------------------------------------------
+# DeepSeek-V3 expert-parallel dispatch (arXiv 2412.19437 §2.1.2, §3.2)
+# ---------------------------------------------------------------------------
+
+#: UDP source port of a GPU pair's first queue pair; the pair's
+#: ``qps`` flows take consecutive ports from here
+QP_BASE_PORT = 49152
+#: values per FP8 quantization tile; each tile carries one float32 scale
+FP8_TILE = 128
+
+
+@dataclasses.dataclass(frozen=True, slots=True)
+class MoeDispatchSpec:
+    """One MoE layer's forward dispatch of an expert-parallel job.
+
+    ``ep_groups`` groups of ``n_group`` nodes, each node a server of
+    ``gpus_per_node`` GPUs with one single-port NIC per GPU; group ``e``
+    holds servers ``e * n_group`` to ``e * n_group + n_group - 1``.  The
+    router's group ``i`` is node ``i`` of the EP group, so its
+    ``n_routed_experts / n_group`` experts live on that node's GPUs.
+    Each GPU routes ``tokens_per_gpu`` tokens of one data domain.
+
+    Router (DeepSeek-V3 ``noaux_tc``, sigmoid scoring, bias ``b = 0``):
+    affinities ``s = sigmoid(logits)``; a group scores the sum of its
+    top-2 ``s + b``; the top ``topk_group`` groups are kept and the top
+    ``num_experts_per_tok`` experts by ``s + b`` among them are chosen.
+    The logits of a token of domain ``d`` are ``log p_d(e) + N(0, 1)``,
+    ``p_d`` Zipf with exponent ``zipf_skew`` over domain ``d``'s own
+    permutation of the experts (``zipf_skew = 0`` is uniform); each GPU's
+    domain is drawn uniformly from ``domains``.  Everything is drawn
+    from ``seed``.
+
+    Dispatch (DeepEP, node-limited): a token is sent once to every other
+    node holding one of its experts, over the network to the GPU with
+    the sender's in-node index, then over NVLink.  GPU ``g`` of node
+    ``a`` sends ``c`` tokens to node ``b`` as ``qps`` RoCEv2 flows from
+    NIC ``g`` of ``a`` to NIC ``g`` of ``b``, each of
+    ``c * token_bytes / qps`` bytes.
+    """
+
+    ep_groups: int = 1
+    n_group: int = 8
+    gpus_per_node: int = 8
+    tokens_per_gpu: int = 4096
+    n_routed_experts: int = 256
+    num_experts_per_tok: int = 8
+    topk_group: int = 4
+    hidden_size: int = 7168
+    domains: int = 8
+    zipf_skew: float = 0.99
+    qps: int = 16
+    seed: int = 0
+
+    def __post_init__(self):
+        if self.n_routed_experts % self.n_group:
+            raise ValueError(f"{self.n_routed_experts} experts do not "
+                             f"split into {self.n_group} groups")
+        per_group = self.n_routed_experts // self.n_group
+        if per_group < 2:
+            raise ValueError("a group scores its top 2 experts")
+        if not (1 <= self.topk_group <= self.n_group
+                and 1 <= self.num_experts_per_tok
+                <= self.topk_group * per_group):
+            raise ValueError(
+                f"top {self.num_experts_per_tok} experts of top "
+                f"{self.topk_group} groups of {self.n_group} x "
+                f"{per_group} cannot be chosen")
+        if self.token_bytes % self.qps:
+            raise ValueError(f"{self.token_bytes} bytes a token do not "
+                             f"split over {self.qps} queue pairs")
+
+    @property
+    def token_bytes(self) -> int:
+        """Wire bytes of one dispatched token: ``hidden_size`` FP8
+        values and one float32 scale per ``FP8_TILE`` values."""
+        return self.hidden_size + -(-self.hidden_size // FP8_TILE) * 4
+
+
+def _rng(seed: int, *key: int) -> np.random.Generator:
+    return np.random.default_rng(np.random.SeedSequence([seed, *key]))
+
+
+def expert_popularity(spec: MoeDispatchSpec) -> np.ndarray:
+    """(domains, n_routed_experts) ``p_d(e)``: Zipf(``zipf_skew``) over
+    each domain's own permutation of the experts."""
+    rng = _rng(spec.seed, 0)
+    E = spec.n_routed_experts
+    zipf = np.arange(1, E + 1, dtype=np.float64) ** -spec.zipf_skew
+    zipf /= zipf.sum()
+    out = np.empty((spec.domains, E))
+    for d in range(spec.domains):
+        out[d, rng.permutation(E)] = zipf
+    return out
+
+
+def source_domains(spec: MoeDispatchSpec) -> np.ndarray:
+    """(ep_groups, n_group, gpus_per_node) domain of each GPU's tokens."""
+    return _rng(spec.seed, 1).integers(
+        0, spec.domains, (spec.ep_groups, spec.n_group, spec.gpus_per_node))
+
+
+def router_logits(spec: MoeDispatchSpec, group: int, node: int) -> np.ndarray:
+    """(gpus_per_node, tokens_per_gpu, n_routed_experts) float64 router
+    logits of one node's tokens, ``log p_d(e) + N(0, 1)``."""
+    p = expert_popularity(spec)[source_domains(spec)[group, node]]
+    noise = _rng(spec.seed, 2, group, node).standard_normal(
+        (spec.gpus_per_node, spec.tokens_per_gpu, spec.n_routed_experts))
+    return np.log(p)[:, None, :] + noise
+
+
+def route_tokens(logits: np.ndarray, *, n_group: int, topk_group: int,
+                 top_k: int) -> tuple[np.ndarray, np.ndarray]:
+    """DeepSeek-V3's group-limited top-k over the last axis of
+    ``logits``, bias 0: ``(experts, groups)``, the ``top_k`` chosen
+    experts and the ``topk_group`` kept groups of each token, each in
+    ascending order.  The gate values are not computed."""
+    score = 1.0 / (1.0 + np.exp(-logits))
+    E = score.shape[-1]
+    grouped = score.reshape(*score.shape[:-1], n_group, E // n_group)
+    per = grouped.shape[-1]
+    group_score = np.partition(grouped, per - 2, axis=-1)[..., -2:].sum(-1)
+    groups = np.argpartition(-group_score, topk_group - 1,
+                             axis=-1)[..., :topk_group]
+    kept = np.take_along_axis(grouped, groups[..., None], axis=-2)
+    kept = kept.reshape(*kept.shape[:-2], topk_group * per)
+    pick = np.argpartition(-kept, top_k - 1, axis=-1)[..., :top_k]
+    experts = np.take_along_axis(groups, pick // per, axis=-1) * per + (
+        pick % per)
+    return np.sort(experts, axis=-1), np.sort(groups, axis=-1)
+
+
+def dispatch_counts(spec: MoeDispatchSpec) -> np.ndarray:
+    """(ep_groups, n_group, gpus_per_node, n_group) int64 tokens GPU
+    ``g`` of node ``a`` dispatches over the network to node ``b``
+    (``[e, a, g, b]``); 0 where ``b == a``, which NVLink serves.  A
+    token counts once for every node holding one of its experts."""
+    per_node = spec.n_routed_experts // spec.n_group
+    out = np.zeros((spec.ep_groups, spec.n_group, spec.gpus_per_node,
+                    spec.n_group), np.int64)
+    for e in range(spec.ep_groups):
+        for a in range(spec.n_group):
+            experts, _ = route_tokens(
+                router_logits(spec, e, a), n_group=spec.n_group,
+                topk_group=spec.topk_group, top_k=spec.num_experts_per_tok)
+            hit = np.zeros((*experts.shape[:-1], spec.n_group), bool)
+            np.put_along_axis(hit, experts // per_node, True, axis=-1)
+            out[e, a] = hit.sum(axis=1)
+            out[e, a, :, a] = 0
+    return out
+
+
+def moe_dispatch_flows(spec: MoeDispatchSpec,
+                       counts: np.ndarray | None = None) -> list[Flow]:
+    """The dispatch as RoCEv2 flows between ``srv-<i>`` servers, in
+    ``(group, node, gpu, node, queue pair)`` order, inside the
+    ``moe.dispatch`` span (counters ``tokens`` routed, ``pairs`` of
+    (GPU, node) with traffic and ``flows``).  ``counts`` defaults to
+    ``dispatch_counts(spec)``; a pair with no tokens emits no flow."""
+    with span("moe.dispatch"):
+        c = dispatch_counts(spec) if counts is None else np.asarray(counts)
+        per_token = spec.token_bytes // spec.qps
+        label = f"moe-dispatch#ch{CH_MOE_A2A}"
+        out: list[Flow] = []
+        for e, a, g, b in zip(*np.nonzero(c)):
+            if a == b:
+                raise ValueError(f"group {e}: node {a} dispatches to itself")
+            src = server_name(int(e * spec.n_group + a))
+            dst = server_name(int(e * spec.n_group + b))
+            src_ip, dst_ip = nic_ip(src, int(g)), nic_ip(dst, int(g))
+            size, first = int(c[e, a, g, b]) * per_token, len(out)
+            out.extend(
+                Flow(flow_id=first + q, src=src, dst=dst,
+                     tuple5=FiveTuple(src_ip, dst_ip, QP_BASE_PORT + q,
+                                      ROCE_UDP_DPORT, PROTO_UDP),
+                     bytes=size, label=label)
+                for q in range(spec.qps))
+        count("tokens", spec.ep_groups * spec.n_group * spec.gpus_per_node
+              * spec.tokens_per_gpu)
+        count("pairs", int(np.count_nonzero(c)))
+        count("flows", len(out))
+    return out

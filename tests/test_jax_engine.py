@@ -513,7 +513,8 @@ def test_fused_fim_counts_by_demand(paper8, tmp_path, monkeypatch,
                                     demand_mode, exact_f32, unit):
     """The fused FIM counts unit demand on the one-hots (counters
     ``unit_counts`` and ``onehot_width`` of ``counts.run``) and anything
-    else with the weighted sums; both give the numpy engine's FIM."""
+    else with the weighted sums (counters ``weighted_counts`` and
+    ``link_compares``); both give the numpy engine's FIM."""
     import jax
 
     from repro.core import spans
@@ -527,13 +528,19 @@ def test_fused_fim_counts_by_demand(paper8, tmp_path, monkeypatch,
     with jax.profiler.trace(str(tmp_path)):
         b = monte_carlo_fim(comp, flows, seeds,
                             spec=dataclasses.replace(spec, engine=ENGINE_JAX))
-    row = spans.snapshot()["counts.run"]
+    table = spans.snapshot()
+    row = table["counts.run"]
     if unit:
         assert row["unit_counts"] == row["n"] == 1
         assert row["onehot_width"] == sum(
             jax_engine.onehot_split(comp.num_links))
+        assert "weighted_counts" not in row and "link_compares" not in row
     else:
         assert "unit_counts" not in row and "onehot_width" not in row
+        assert row["weighted_counts"] == row["n"] == 1
+        assert row["link_compares"] == (table["walk.run"]["hops"]
+                                         * len(flows) * len(seeds)
+                                         * comp.num_links)
     assert np.abs(a.aggregate - b.aggregate).max() < 1e-9
     assert sorted(a.per_layer) == sorted(b.per_layer)
     for layer in a.per_layer:

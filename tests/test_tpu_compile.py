@@ -110,7 +110,8 @@ def test_wave_walk_compiles_for_v5e(chip, comp):
 @pytest.mark.parametrize("hops, flows, seeds, links", [
     (4, 256, 16384, 256),               # a paper-ecmp-fim pass
     (6, 21504, 128, 5760),              # a meta-3tier-2pod-fim pass
-], ids=["paper-fim", "meta-fim"])
+    (4, 86016, 128, 3264),              # a dsv3-ep-dispatch-fim pass
+], ids=["paper-fim", "meta-fim", "dsv3-fim"])
 def test_counts_compiles_for_v5e(chip, unit, hops, flows, seeds, links):
     """Both counting paths at the FIM cells' pass shapes, each within
     1 GB of device memory."""
